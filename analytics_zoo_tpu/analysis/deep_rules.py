@@ -28,19 +28,19 @@ TPU-serving-throughput lesson. The finalize seam is exempt (that stage
 exists to absorb the sync), as is anything in jit context (a host sync
 inside a trace is a *trace* hazard, reported by the jit family).
 
-**Version-fragile collective API.** The repo runs on two jax lines
-(the 0.4.x rigs and >=0.5 drivers); ``jax.shard_map`` and
-``lax.axis_size`` exist only on the newer one, so a direct use is a
-crash half the fleet never sees until dispatch. ``shard-map-direct``
-flags any ``jax.shard_map`` use outside the one compat wrapper
-(``parallel/mesh.py``). ``collective-version-api`` flags
-``lax.axis_size`` in **propagated collective context** -- the
-interprocedural part: the pipeline/ring-attention local bodies are
-plain module functions whose collective-ness is only provable by
+**Version-fragile collective API.** ``jax.shard_map`` and
+``lax.axis_size`` have moved and changed keywords across jax lines,
+so each is spelled in exactly ONE wrapper and the next move is a
+one-line change. ``shard-map-direct`` flags any ``jax.shard_map`` use
+outside its wrapper (``parallel/mesh.py``). ``collective-version-api``
+flags ``lax.axis_size`` outside its wrapper
+(``parallel/collectives.py``) in **propagated collective context** --
+the interprocedural part: the pipeline/ring-attention local bodies
+are plain module functions whose collective-ness is only provable by
 resolving ``shard_map(partial(body, ...), ...)`` through the call
 graph. Dogfooding this pair on the pre-deepcheck tree found 10 real
-crashes-in-waiting (7 direct ``jax.shard_map`` uses, 3
-``lax.axis_size`` bodies) -- see docs/zoolint.md.
+direct uses (7 ``jax.shard_map``, 3 ``lax.axis_size`` bodies) -- see
+docs/zoolint.md.
 
 **Dtype drift.** ``dtype-upcast-f32`` flags an argument with a
 provable float32/float64 dtype flowing into a parameter whose
@@ -245,13 +245,13 @@ class DeepChecker(Checker):
                                    "finalize seam (stalls the decode/"
                                    "dispatch overlap)",
         "shard-map-direct": "direct jax.shard_map use outside the "
-                            "parallel/mesh.py compat wrapper (absent "
-                            "on jax 0.4.x: crashes at dispatch; use "
+                            "parallel/mesh.py wrapper (its location "
+                            "and keywords move across jax lines; use "
                             "parallel.mesh.shard_map)",
         "collective-version-api": "lax.axis_size in propagated "
-                                  "collective context (jax>=0.5-only; "
-                                  "use parallel.collectives.axis_size "
-                                  "-- psum(1, axis) on 0.4.x)",
+                                  "collective context outside its one "
+                                  "wrapper (use "
+                                  "parallel.collectives.axis_size)",
         "dtype-upcast-f32": "f32/f64 value flowing into a parameter "
                             "declared/defaulted bf16 or f16 (the "
                             "convert-fusion upcast pattern behind the "
@@ -437,6 +437,8 @@ class DeepChecker(Checker):
     def _check_version_api(self, fn: FnNode) -> Iterable[Finding]:
         if CTX_COLLECTIVE not in fn.contexts:
             return  # axis_size outside a mapped body is its own error
+        if fn.src.rel.endswith("parallel/collectives.py"):
+            return  # the one wrapper, by contract
         caller = fn.via.get(CTX_COLLECTIVE, (fn.qname, fn.qname))[1]
         for node in own_nodes(fn):
                 if not isinstance(node, ast.Call):
@@ -450,9 +452,8 @@ class DeepChecker(Checker):
                         node.lineno,
                         f"'{fn.name}' (collective body, traced via "
                         f"'{_short(caller)}') calls lax.axis_size -- "
-                        "jax>=0.5-only, crashes the 0.4.x rigs at "
-                        "dispatch; use parallel.collectives.axis_size "
-                        "(psum(1, axis) there)")
+                        "spelled in one wrapper only; use "
+                        "parallel.collectives.axis_size")
 
     def _check_shard_map_direct(self, src) -> Iterable[Finding]:
         if src.rel.endswith("parallel/mesh.py"):
@@ -473,9 +474,8 @@ class DeepChecker(Checker):
                 seen_lines.add(node.lineno)
                 yield Finding(
                     "shard-map-direct", "error", src.rel, node.lineno,
-                    f"{hit}: absent on jax 0.4.x (and renamed across "
-                    "lines) -- route through parallel.mesh.shard_map, "
-                    "the one version-compat wrapper")
+                    f"{hit}: spelled in one wrapper only -- route "
+                    "through parallel.mesh.shard_map")
 
     # ------------------------------------------------- dtype drift --
     def _check_dtype_edges(self, fn: FnNode) -> Iterable[Finding]:

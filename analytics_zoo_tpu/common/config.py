@@ -288,10 +288,6 @@ _DEFAULTS: Dict[str, Any] = {
     "zoo.serving.tenant.strict": False,
     # inference
     "zoo.inference.default_dtype": "bfloat16",
-    # XLA persistent compilation cache (see common.context.
-    # enable_compilation_cache); "" disables
-    "zoo.compile_cache.dir": "~/.cache/analytics-zoo-tpu/xla-cache",
-    "zoo.compile_cache.min_compile_secs": 2.0,
 }
 
 # Per-key type/range metadata (the glossary's machine-readable half,
@@ -421,8 +417,6 @@ _SPECS: Dict[str, tuple] = {
     "zoo.obs.recompile.window_s": ("float", 0, None),
     "zoo.obs.recompile.threshold": ("int", 1, None),
     "zoo.inference.default_dtype": ("str",),
-    "zoo.compile_cache.dir": ("str",),
-    "zoo.compile_cache.min_compile_secs": ("float", 0, None),
 }
 
 

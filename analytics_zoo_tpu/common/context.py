@@ -21,6 +21,7 @@ There is exactly ONE runtime to initialize -- JAX SPMD -- instead of five
 from __future__ import annotations
 
 import atexit
+import os
 import threading
 from typing import Any, Dict, Optional, Sequence
 
@@ -32,53 +33,39 @@ from analytics_zoo_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
 
-_cache_dir_applied: Optional[str] = None
-_cache_lock = threading.Lock()
+# the one fixed persistent-cache location when the environment names
+# none: inside the checkout (git-ignored), derived from the package
+# location so every entry point -- tests, benches, chip_smoke.py, the
+# launcher's replicas -- lands in the same place from any cwd. The
+# directory is part of XLA's cache key, so a path that moved (a temp
+# name, a pid, ~ of another user) would never hit.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
+
+def backend_initialized() -> bool:
+    """Whether this process already holds a JAX backend -- WITHOUT
+    initializing one. ``jax.devices()`` / ``jax.default_backend()``
+    would take the chip; processes meant to stay off the device (a
+    fleet controller, a router, a debug endpoint) ask here instead.
+    jax has no public spelling of this, hence the one private import."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> None:
-    """Point XLA's persistent compilation cache at a durable directory so
-    the first-compile tax (200 s for BERT-base, ~30 s for NCF on v5e) is
-    paid once per machine, not once per process. Serving restarts and
-    preemption-resumes then start at steady-state speed.
+def enable_compilation_cache() -> None:
+    """Turn on XLA's persistent compilation cache so the first-compile
+    tax is paid once per machine, not once per process; serving
+    restarts and preemption-resumes then start at steady-state speed.
 
-    Idempotent per directory; called automatically by
-    ``init_zoo_context``, the Estimator, and ``InferenceModel``. A later
-    call with a DIFFERENT directory (explicit argument or a changed
-    ``zoo.compile_cache.dir``) re-points the cache -- entries compiled
-    from then on land there. Configure with ``zoo.compile_cache.dir``
-    ("" disables) and ``zoo.compile_cache.min_compile_secs``. The dir
-    accepts any fileio URI (``gs://...`` via fsspec) -- on a pod, point
-    every host at the same bucket."""
-    global _cache_dir_applied
-    with _cache_lock:
-        import os
-
-        cfg = get_config()
-        cache_dir = cache_dir or cfg.get("zoo.compile_cache.dir")
-        if not cache_dir:
-            return
-        cache_dir = os.path.expanduser(str(cache_dir))
-        if cache_dir == _cache_dir_applied:
-            return
-        try:
-            if "://" not in cache_dir:
-                os.makedirs(cache_dir, exist_ok=True)
-            if _cache_dir_applied is not None:
-                # jax memoizes the cache object at first use; re-pointing
-                # the dir requires dropping it or the update is silent
-                from jax.experimental.compilation_cache import (
-                    compilation_cache)
-
-                compilation_cache.reset_cache()
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs",
-                float(cfg.get("zoo.compile_cache.min_compile_secs", 2.0)))
-            _cache_dir_applied = cache_dir
-            logger.info("XLA persistent compilation cache: %s", cache_dir)
-        except Exception as e:  # cache is an optimization, never fatal
-            logger.warning("compilation cache unavailable: %s", e)
+    Placement belongs to the environment: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and no
+    directory is set in code. Only when it is unset does the cache go
+    to :data:`COMPILE_CACHE_DIR`. Idempotent; called automatically by
+    ``init_zoo_context``, the Estimator, and ``InferenceModel``."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
 
 
 class ZooContext:

@@ -54,6 +54,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from analytics_zoo_tpu.common.config import get_config
 from analytics_zoo_tpu.common.log import get_logger
 from analytics_zoo_tpu.obs.metrics import get_registry
+from analytics_zoo_tpu.parallel.mesh import shard_map
 
 logger = get_logger(__name__)
 
@@ -77,19 +78,9 @@ def _set_mesh_gauge(active_label: Optional[str], n: int) -> None:
 
 _MODES = ("off", "tp", "dp", "auto")
 _RECIPES = ("transformer_tp", "embedding_tp")
-# conservative per-chip HBM guess when the backend exposes no
-# memory_stats (CPU meshes, some remote runtimes): one v5e chip
-_FALLBACK_HBM_BYTES = 16 << 30
-
-
-def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map (kept as the module's historical name;
-    the one implementation lives in ``parallel.mesh.shard_map`` and is
-    shared with ``parallel/`` so the whole tree runs on both jax
-    lines)."""
-    from analytics_zoo_tpu.parallel.mesh import shard_map
-
-    return shard_map(f, mesh, in_specs, out_specs)
+# what "auto" plans against on the CPU backend, which reports no
+# memory_stats (the virtual-device test meshes): one v5e chip
+_CPU_MESH_HBM_BYTES = 16 << 30
 
 
 def _spec_fn_for(recipe: str, axis: str) -> Callable:
@@ -129,13 +120,14 @@ def _per_chip_bytes(device, cfg_get=None) -> int:
     override = int(cfg_get("zoo.serving.shard.auto_hbm_bytes", 0))
     if override:
         return override
-    try:
-        stats = device.memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception as e:
-        logger.debug("shard auto: no memory_stats on %s: %s", device, e)
-    return _FALLBACK_HBM_BYTES
+    if device.platform == "cpu":
+        return _CPU_MESH_HBM_BYTES
+    stats = device.memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        raise RuntimeError(
+            f"shard auto: {device} reports no memory_stats bytes_limit; "
+            "set zoo.serving.shard.auto_hbm_bytes to plan without it")
+    return int(stats["bytes_limit"])
 
 
 class ShardPlan:
@@ -229,9 +221,9 @@ class ShardPlan:
                                           spec_leaves)
             return apply_fn(full, x_local)
 
-        fn = _shard_map(body, self.mesh,
-                        (self._spec_tree, self.batch_spec()),
-                        self.batch_spec())
+        fn = shard_map(body, self.mesh,
+                       (self._spec_tree, self.batch_spec()),
+                       self.batch_spec())
         return jax.jit(fn)
 
     # -------------------------------------------------------- surface --

@@ -62,11 +62,8 @@ def training_prng_key(seed: int):
     stream so CPU runs stay bit-reproducible across jax versions."""
     impl = get_config().get("zoo.train.prng_impl")
     if impl == "auto":
-        try:
-            on_tpu = jax.devices()[0].platform == "tpu"
-        except Exception:
-            on_tpu = False
-        impl = "rbg" if on_tpu else "threefry2x32"
+        impl = ("rbg" if jax.devices()[0].platform == "tpu"
+                else "threefry2x32")
     if impl in (None, "", "threefry2x32", "default"):
         return jax.random.PRNGKey(seed)
     return jax.random.key(seed, impl=impl)
@@ -385,9 +382,8 @@ class Estimator:
             variables, opt_state, loss = self._step_math(
                 variables, opt_state, x, y, rng)
             # the epoch loss accumulates ON DEVICE: pulling per-step
-            # scalars to host costs a full round-trip each (catastrophic
-            # over remote dispatch links); the epoch mean is one
-            # transfer of this resident scalar
+            # scalars to host would sync the dispatch queue every step;
+            # the epoch mean is one transfer of this resident scalar
             return variables, opt_state, loss_sum + loss, loss
 
         # compile-boundary instrumentation (obs.events): the first call
@@ -597,7 +593,11 @@ class Estimator:
 
         while self.epoch < epochs:
             epoch_start = time.time()
-            loss_sum = jnp.zeros((), jnp.float32)
+            # placed like the step's own loss_sum output: an unplaced
+            # scalar has another type than the mesh-resident one that
+            # comes back, and step 2 would trace and compile again
+            loss_sum = jax.device_put(jnp.zeros((), jnp.float32),
+                                      replicated(self.mesh))
             n_steps = 0
             last_val: Optional[Dict[str, float]] = None
             try:

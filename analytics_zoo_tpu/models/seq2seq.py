@@ -93,9 +93,8 @@ class Seq2seq(ZooModel):
         Default: the whole greedy loop runs on-device inside ONE
         jitted ``lax.fori_loop`` -- one dispatch per call instead of
         one per emitted token (the ISSUE-10 satellite fix: the old
-        host loop paid ``max_len`` python->device round trips, which
-        dominated wall time on remote-device runtimes). One compile
-        per (batch, max_len) shape, cached on the model.
+        host loop paid ``max_len`` python->device round trips). One
+        compile per (batch, max_len) shape, cached on the model.
 
         ``host_loop=True`` keeps the original per-token host loop --
         the parity reference of ``tests/test_generation.py`` and the

@@ -1,4 +1,4 @@
-"""Attention dispatch: Pallas flash kernels on TPU, jnp reference on CPU.
+"""Attention dispatch: Pallas flash kernels on TPU, jnp einsum on CPU.
 
 Replaces the reference's O(L^2)-materialized attention
 (ref: zoo/.../keras/layers/TransformerLayer.scala attn -- builds the full
@@ -66,11 +66,11 @@ def _einsum_attention(q, k, v, mask=None, causal: bool = False,
 
 
 def _platform(q) -> str:
-    try:
-        dev = q.devices() if hasattr(q, "devices") else None
-        return list(dev)[0].platform if dev else jax.default_backend()
-    except Exception:
-        return jax.default_backend()
+    """Platform the attention will run on: a concrete array's own
+    device, else (tracers under jit, host arrays) the default backend."""
+    if isinstance(q, jax.Array) and not isinstance(q, jax.core.Tracer):
+        return next(iter(q.devices())).platform
+    return jax.default_backend()
 
 
 def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
@@ -99,9 +99,11 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
         # XLA's fused batched-matmul attention beats the blockwise
         # kernels (measured ~2x on v5e at BERT-base L=384/d=64)
         impl = "einsum"
+    # the einsum path is the CPU's; any other platform compiles the
+    # kernels (or fails loudly), whatever it calls itself
     flash_ok = (impl != "einsum"
                 and mask is None and dropout_rate == 0.0
-                and _platform(q) == "tpu"
+                and _platform(q) != "cpu"
                 and l % 128 == 0 and lk % 128 == 0
                 and not (causal and l > lk))
     if flash_ok and d % 64 == 0:

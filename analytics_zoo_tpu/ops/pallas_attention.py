@@ -190,8 +190,10 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
 
 
 def _interpret() -> bool:
-    # interpret mode runs the kernel logic on CPU (tests); compiled on TPU
-    return jax.default_backend() != "tpu"
+    """Interpret mode is for the CPU backend only (where the tests run
+    the kernel logic); every other backend compiles the kernel, so one
+    that cannot says so instead of silently interpreting."""
+    return jax.default_backend() == "cpu"
 
 
 def _grid_semantics():

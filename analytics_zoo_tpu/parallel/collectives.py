@@ -48,13 +48,8 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis from inside a mapped body.
-    jax 0.4.x has no ``lax.axis_size``; ``psum(1, axis)`` is the
-    classic spelling and constant-folds to a python int either way."""
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a named mesh axis from inside a mapped body."""
+    return lax.axis_size(axis_name)
 
 
 def _q8(t: jnp.ndarray):

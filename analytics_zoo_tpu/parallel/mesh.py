@@ -121,29 +121,12 @@ def mesh_axis_size(mesh: Mesh, name: str) -> int:
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax >= 0.5 exposes ``jax.shard_map``
-    (``check_vma``), 0.4.x ships ``jax.experimental.shard_map``
-    (``check_rep``). Replication checking is off either way -- bodies
-    with per-shard divergent values (dropout keys, quantization
-    scales) are the norm in this package. Every ``parallel/`` and
-    serving shard_map routes through here so the whole tree runs on
-    both jax lines."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as esm
-
-    try:
-        return esm(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return esm(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs)
+    """``jax.shard_map`` with replication checking off -- bodies with
+    per-shard divergent values (dropout keys, quantization scales) are
+    the norm in this package. Every ``parallel/`` and serving
+    shard_map routes through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def config_axis(role: str, fallback: Optional[str] = None) -> str:

@@ -830,15 +830,17 @@ class HttpFrontend:
         out["serving_shard"] = (shard_plan.describe()
                                 if shard_plan is not None
                                 else {"mode": "off"})
-        try:
-            import jax
+        import jax
 
-            out["build"]["jax"] = jax.__version__
-            out["build"]["backend"] = jax.default_backend()
-        except Exception as e:
-            # jax-free frontend processes stay served; the debug page
-            # just omits the backend block (but says why in the log)
-            logger.debug("debug endpoint: jax info unavailable: %s", e)
+        from analytics_zoo_tpu.common.context import backend_initialized
+
+        out["build"]["jax"] = jax.__version__
+        # report the backend only if this process already holds one:
+        # asking jax for it would INITIALIZE it, and a router/front-end
+        # process that was meant to stay off the device would take the
+        # chip away from the replica it fronts (one process per chip)
+        out["build"]["backend"] = (jax.default_backend()
+                                   if backend_initialized() else None)
         return out
 
     def set_draining(self) -> None:

@@ -1,6 +1,6 @@
 """bench.py must always end stdout with one parseable JSON line, even
-when the accelerator backend cannot initialize (ISSUE-1 satellite:
-bounded retry around backend init + a guaranteed final line)."""
+when the accelerator backend cannot initialize -- and must then exit
+non-zero, so a missing device can never read as a finished run."""
 
 import json
 import os
@@ -10,17 +10,29 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_backend_unavailable_still_emits_final_json_line():
+def _run_bench(platforms: str):
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "bogus"       # force backend init failure
-    env["BENCH_RETRY_DELAY_S"] = "0.05"  # keep the 3x backoff fast
+    env["JAX_PLATFORMS"] = platforms
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")],
         capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
     lines = [ln for ln in out.stdout.splitlines() if ln.strip()]
     assert lines, f"no stdout at all; stderr: {out.stderr[-500:]}"
-    final = json.loads(lines[-1])  # the driver's parse contract
+    return out, json.loads(lines[-1])  # the driver's parse contract
+
+
+def test_backend_unavailable_still_emits_final_json_line():
+    out, final = _run_bench("bogus")     # force backend init failure
     assert final == {"value": None, "error": "backend_unavailable"}
-    # the bounded retry actually ran: three attempts logged
-    assert out.stderr.count("backend init attempt") == 3
+    assert out.returncode != 0
+    assert "backend unavailable" in out.stderr
+
+
+def test_unknown_device_kind_is_an_error_not_a_default_peak():
+    """A device the peaks table does not know (here: the CPU) stops
+    the bench before any phase runs; no assumed peak, no exit 0."""
+    out, final = _run_bench("cpu")
+    assert final["value"] is None
+    assert "no peak FLOP/s recorded for device kind" in final["error"]
+    assert out.returncode != 0

@@ -1,5 +1,5 @@
 """Smoke the multichip harness: scaling efficiency (north-star #3),
-the crash-proof final-JSON contract (the r5 zeroed run's fix), and the
+the final-JSON-line + non-zero-exit failure contract, and the
 sharded-serving A/B on the CPU host-device mesh (ISSUE-7)."""
 
 import json
@@ -32,19 +32,18 @@ def test_scaling_harness_outputs_json():
 
 
 def test_backend_unavailable_still_emits_final_json_line():
-    """The TPU-backend UNAVAILABLE failure that zeroed r5's run: a
-    bounded backend-init retry, then a guaranteed parseable final
-    line (bench.py's established convention)."""
+    """A backend that cannot initialize: a guaranteed parseable final
+    line AND a non-zero exit (bench.py's established convention)."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench_scaling.py")],
         capture_output=True, text=True, timeout=300, cwd=REPO,
-        env=_clean_env(JAX_PLATFORMS="bogus",
-                       BENCH_RETRY_DELAY_S="0.05"))
+        env=_clean_env(JAX_PLATFORMS="bogus"))
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
     assert lines, f"no stdout at all; stderr: {proc.stderr[-500:]}"
     assert json.loads(lines[-1]) == {"value": None,
                                      "error": "backend_unavailable"}
-    assert proc.stderr.count("backend init attempt") == 3
+    assert proc.returncode != 0
+    assert "backend unavailable" in proc.stderr
 
 
 def test_serving_shard_smoke_on_host_device_mesh():

@@ -75,6 +75,43 @@ class TestContext:
         stop_orca_context()
 
 
+class TestCompilationCachePlacement:
+    """The cache is placed from outside: JAX_COMPILATION_CACHE_DIR
+    wins untouched, else one fixed in-checkout path from any cwd."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_cache_dir(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_var_set_leaves_jax_config_untouched(self, monkeypatch,
+                                                     tmp_path):
+        from analytics_zoo_tpu.common.context import (
+            enable_compilation_cache)
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        # a sentinel stands in for what jax read from the variable at
+        # import: any directory set in code would overwrite it
+        jax.config.update("jax_compilation_cache_dir", "sentinel-dir")
+        enable_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir == "sentinel-dir"
+
+    def test_env_var_unset_resolves_fixed_checkout_path(
+            self, monkeypatch, tmp_path):
+        from analytics_zoo_tpu.common.context import (
+            COMPILE_CACHE_DIR, enable_compilation_cache)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.chdir(tmp_path)
+        jax.config.update("jax_compilation_cache_dir", None)
+        enable_compilation_cache()
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        assert COMPILE_CACHE_DIR == os.path.join(repo, ".xla_cache")
+        assert jax.config.jax_compilation_cache_dir == COMPILE_CACHE_DIR
+
+
 class TestTriggers:
     def test_every_epoch(self):
         t = EveryEpoch()

@@ -65,8 +65,7 @@ class TestCollectives:
     def test_allreduce_matches_sum(self):
         mesh = create_mesh()
         x = jnp.arange(8.0)
-        # parallel's shard_map: the version-compat wrapper (jax 0.4.x
-        # has no jax.shard_map; the driver's jax does)
+        # parallel's shard_map: jax.shard_map with check_vma off
         f = shard_map(
             lambda t: collectives.all_reduce_sum(t, "data"),
             mesh, in_specs=P("data"), out_specs=P("data"))

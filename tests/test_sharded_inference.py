@@ -348,9 +348,9 @@ class TestQuantizedCollectives:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from analytics_zoo_tpu.inference.sharded import _shard_map
         from analytics_zoo_tpu.parallel.collectives import (
             quantized_psum)
+        from analytics_zoo_tpu.parallel.mesh import shard_map
 
         mesh = self._mesh()
         x = np.random.RandomState(0).randn(16, 12).astype(np.float32)
@@ -362,8 +362,8 @@ class TestQuantizedCollectives:
             return quantized_psum(v, "data")
 
         spec = P("data")
-        ref = _shard_map(exact, mesh, (spec,), spec)(x)
-        got = _shard_map(approx, mesh, (spec,), spec)(x)
+        ref = shard_map(exact, mesh, (spec,), spec)(x)
+        got = shard_map(approx, mesh, (spec,), spec)(x)
         denom = max(np.abs(np.asarray(ref)).max(), 1e-6)
         rel = np.max(np.abs(np.asarray(got) - np.asarray(ref))) / denom
         # 8 shards x <=1/254 quantization step each, relative to the
@@ -373,22 +373,22 @@ class TestQuantizedCollectives:
     def test_quantized_psum_exact_on_zeros(self):
         from jax.sharding import PartitionSpec as P
 
-        from analytics_zoo_tpu.inference.sharded import _shard_map
         from analytics_zoo_tpu.parallel.collectives import (
             quantized_psum)
+        from analytics_zoo_tpu.parallel.mesh import shard_map
 
         mesh = self._mesh()
         x = np.zeros((8, 4), np.float32)
-        out = _shard_map(lambda v: quantized_psum(v, "data"), mesh,
-                         (P("data"),), P("data"))(x)
+        out = shard_map(lambda v: quantized_psum(v, "data"), mesh,
+                        (P("data"),), P("data"))(x)
         assert np.all(np.asarray(out) == 0.0)
 
     def test_quantized_all_gather_concatenates_in_shard_order(self):
         from jax.sharding import PartitionSpec as P
 
-        from analytics_zoo_tpu.inference.sharded import _shard_map
         from analytics_zoo_tpu.parallel.collectives import (
             quantized_all_gather)
+        from analytics_zoo_tpu.parallel.mesh import shard_map
 
         mesh = self._mesh()
         x = np.random.RandomState(1).randn(16, 4).astype(np.float32)
@@ -396,8 +396,8 @@ class TestQuantizedCollectives:
         def gather(v):
             return quantized_all_gather(v, "data", axis=0)
 
-        out = np.asarray(_shard_map(gather, mesh, (P("data"),),
-                                    P("data"))(x))
+        out = np.asarray(shard_map(gather, mesh, (P("data"),),
+                                   P("data"))(x))
         # every shard reconstructs the full [16, 4] array; out_specs
         # stacks the 8 copies -> [128, 4]. Each copy must match the
         # input in shard order within one int8 quantization step.
