@@ -286,7 +286,15 @@ class Estimator:
     def _step_math(self, variables, opt_state, x, y, rng):
         """One SGD update; shared by the per-step and the device-cached
         whole-epoch paths. With ``grad_accum_steps`` k > 1 the batch is
-        split into k microbatches scanned inside this one update."""
+        split into k microbatches scanned inside this one update.
+
+        Every op answers to a name in a device trace: flax gives each
+        module's path, JAX marks forward ops ``jvp(...)`` and backward
+        ops ``transpose(jvp(...))``, and the scopes here name what no
+        module does -- ``loss``, ``grad_accum`` and ``optimizer``
+        (docs/observability.md, "Reading a training trace by scope").
+        A scope is ``op_name`` metadata only: the compiled program is
+        the same with and without it."""
         import optax
 
         adapter, loss_fn, tx = self.adapter, self.loss_fn, self.tx
@@ -298,12 +306,13 @@ class Estimator:
             preds, new_extra = adapter.apply(
                 {"params": p, **extra}, xb, training=True,
                 rng=step_rng)
-            loss = loss_fn(preds, yb)
-            for coll in aux_colls:
-                if coll in new_extra:
-                    for leaf in jax.tree_util.tree_leaves(
-                            new_extra[coll]):
-                        loss = loss + jnp.sum(leaf)
+            with jax.named_scope("loss"):
+                loss = loss_fn(preds, yb)
+                for coll in aux_colls:
+                    if coll in new_extra:
+                        for leaf in jax.tree_util.tree_leaves(
+                                new_extra[coll]):
+                            loss = loss + jnp.sum(leaf)
             # sown collections are per-step scalars, not model state
             new_extra = {k: v for k, v in new_extra.items()
                          if k not in aux_colls
@@ -317,8 +326,9 @@ class Estimator:
         else:
             loss, new_extra, grads = self._accum_grads(
                 compute_loss, params, x, y, rng, k)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return {"params": params, **new_extra}, opt_state, loss
 
     @staticmethod
@@ -359,11 +369,12 @@ class Estimator:
             g_acc = jax.tree_util.tree_map(jnp.add, g_acc, grads)
             return (g_acc, loss_acc + loss), new_extra
 
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-        (g_sum, loss_sum), extras = jax.lax.scan(
-            body, (zeros, jnp.zeros((), jnp.float32)),
-            (jnp.arange(k), xs, ys))
-        grads = jax.tree_util.tree_map(lambda g: g / k, g_sum)
+        with jax.named_scope("grad_accum"):
+            zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
+            (g_sum, loss_sum), extras = jax.lax.scan(
+                body, (zeros, jnp.zeros((), jnp.float32)),
+                (jnp.arange(k), xs, ys))
+            grads = jax.tree_util.tree_map(lambda g: g / k, g_sum)
         # mutable state (e.g. batch stats): each microbatch updated from
         # the same PRE-STEP collections, so taking [-1] keeps one
         # single-microbatch update -- NOT the compounded k updates a

@@ -13,15 +13,15 @@ torch_runner.py:308-316). Here training profiling has two layers:
   ISSUE-2 every stage duration also lands in the process-wide obs
   registry (``zoo_learn_stage_duration_seconds{stage=...}``), so
   training and serving share one scrape vocabulary.
-- XLA device tracing: ``jax.profiler`` traces written to a TensorBoard
-  -loadable directory when ``trace_dir`` is set -- answers "what is the
-  chip doing?" (the reference has no analog; BigDL had no device
+- XLA device tracing: a device-only ``jax.profiler`` trace written to
+  a TensorBoard-loadable directory when ``trace_dir`` is set -- answers
+  "what is the chip doing?", op by op, each named by its module path
+  and scope (the reference has no analog; BigDL had no device
   profiler).
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Dict, Optional
 
 from analytics_zoo_tpu.common.log import Timer
@@ -42,23 +42,27 @@ class TrainingProfiler:
         self._tracing = False
 
     # ------------------------------------------------------ stage timing --
-    @contextlib.contextmanager
     def timing(self, stage: str):
-        """Host timer for the stage; while a device trace is active the
-        stage also appears as a named region on the trace timeline."""
-        with self.timer.timing(stage):
-            if self._tracing:
-                with self.step_annotation(stage):
-                    yield
-            else:
-                yield
+        """Host timer for the stage (a context manager)."""
+        return self.timer.timing(stage)
 
     # ------------------------------------------------------- device trace --
     def start_trace(self) -> None:
+        """Device planes only. With the host tracer on (at any level)
+        the runtime records one event per row of every batch it
+        re-tiles for the device: 16 steps of an image model wrote 16.5
+        million host events, a 500 MB trace that stalled the steps it
+        was meant to show (PERF.md section 6, PR 22). The device planes
+        carry every op with its module path and scope, which is what a
+        training trace is read by (docs/observability.md)."""
         if self.trace_dir and not self._tracing:
             import jax
 
-            jax.profiler.start_trace(self.trace_dir)
+            options = jax.profiler.ProfileOptions()
+            options.host_tracer_level = 0
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=options)
             self._tracing = True
 
     def stop_trace(self) -> None:
@@ -67,14 +71,6 @@ class TrainingProfiler:
 
             jax.profiler.stop_trace()
             self._tracing = False
-
-    @contextlib.contextmanager
-    def step_annotation(self, name: str):
-        """Named region visible in the device trace timeline."""
-        import jax
-
-        with jax.profiler.TraceAnnotation(name):
-            yield
 
     # ----------------------------------------------------------- results --
     def summary(self) -> Dict[str, Dict[str, Any]]:

@@ -80,7 +80,12 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
     """q,k,v: [B, H, L, D]. ``mask``: arbitrary [B, H, Lq, Lk]-broadcastable
     (1 = attend; forces the jnp path). ``key_padding_mask``: [B, Lk] with
     1 = real token -- flash-compatible (lowered to segment ids).
-    Returns [B, H, Lq, D]."""
+    Returns [B, H, Lq, D].
+
+    The path taken names itself in every op's ``op_name`` (and so in a
+    device trace): ``attention_flash`` (this repo's Pallas kernel),
+    ``attention_stock_pallas``, ``attention_einsum`` or
+    ``attention_reference``."""
     d = q.shape[-1]
     l, lk = q.shape[2], k.shape[2]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -111,7 +116,8 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
             pallas_flash_attention_fwd)
 
         if key_padding_mask is None:
-            return pallas_flash_attention_fwd(q, k, v, causal, scale)
+            with jax.named_scope("attention_flash"):
+                return pallas_flash_attention_fwd(q, k, v, causal, scale)
         # padding masks fall through to the stock kernel's segment ids
     # the stock kernel's causal mask is top-left aligned (no cross-length
     # offset), so it only agrees with reference_attention when lq == lk
@@ -125,27 +131,31 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
             q_seg = (kv_seg if lk == l
                      else jnp.ones((q.shape[0], l), jnp.int32))
             seg = SegmentIds(q=q_seg, kv=kv_seg)
-        return flash_attention(q, k, v, segment_ids=seg, causal=causal,
-                               sm_scale=scale)
+        with jax.named_scope("attention_stock_pallas"):
+            return flash_attention(q, k, v, segment_ids=seg, causal=causal,
+                                   sm_scale=scale)
 
     if key_padding_mask is not None:
         pm = key_padding_mask[:, None, None, :].astype(bool)
         mask = pm if mask is None else (mask.astype(bool) & pm)
     if dropout_rate == 0.0:
-        return _einsum_attention(q, k, v, mask=mask, causal=causal,
-                                 scale=scale)
-    if dropout_rate > 0.0 and dropout_rng is not None:
-        # dropout needs the materialized probs; inline the reference math
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-        if causal:
-            cm = jnp.tril(jnp.ones((l, lk), bool), k=lk - l)
-            logits = jnp.where(cm[None, None], logits, NEG_INF)
-        if mask is not None:
-            logits = jnp.where(mask.astype(bool), logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
-                                    probs.shape)
-        probs = probs * keep / (1.0 - dropout_rate)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-    return reference_attention(q, k, v, mask=mask, causal=causal,
-                               scale=scale)
+        with jax.named_scope("attention_einsum"):
+            return _einsum_attention(q, k, v, mask=mask, causal=causal,
+                                     scale=scale)
+    with jax.named_scope("attention_reference"):
+        if dropout_rate > 0.0 and dropout_rng is not None:
+            # dropout needs the materialized probs; inline the reference
+            # math
+            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+            if causal:
+                cm = jnp.tril(jnp.ones((l, lk), bool), k=lk - l)
+                logits = jnp.where(cm[None, None], logits, NEG_INF)
+            if mask is not None:
+                logits = jnp.where(mask.astype(bool), logits, NEG_INF)
+            probs = jax.nn.softmax(logits, axis=-1)
+            keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
+                                        probs.shape)
+            probs = probs * keep / (1.0 - dropout_rate)
+            return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        return reference_attention(q, k, v, mask=mask, causal=causal,
+                                   scale=scale)

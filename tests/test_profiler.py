@@ -111,3 +111,71 @@ class TestSummary:
         with p.timing("profiler_test_stage"):
             pass
         assert child.snapshot()["count"] == before + 1
+
+
+class TestDeviceTrace:
+    """``fit(trace_dir=...)`` (ISSUE 24): a device-only trace. With the
+    host tracer on at any level the runtime records one event per row
+    of every batch it re-tiles (16.5 million for 16 steps of an image
+    model, PERF.md section 6), and the names a training trace is read
+    by are on the device planes."""
+
+    @staticmethod
+    def _estimator():
+        import flax.linen as nn
+        import jax.numpy as jnp
+        import optax
+
+        from analytics_zoo_tpu.learn.estimator import Estimator
+
+        return Estimator(nn.Dense(2),
+                         loss=lambda p, t: jnp.mean((p - t) ** 2),
+                         optimizer=optax.sgd(0.1))
+
+    def test_fit_trace_dir_takes_a_device_only_trace(self, tmp_path,
+                                                     monkeypatch):
+        import glob
+
+        import jax
+        import numpy as np
+
+        started = []
+        real_start = jax.profiler.start_trace
+
+        def start_trace(log_dir, *args, **kwargs):
+            started.append((log_dir, kwargs.get("profiler_options")))
+            return real_start(log_dir, *args, **kwargs)
+
+        monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+        x = np.random.default_rng(0).random((16, 4), np.float32)
+        y = x[:, :2]
+        est = self._estimator()
+        trace_dir = str(tmp_path / "trace")
+        est.fit((x, y), batch_size=8, epochs=1, trace_dir=trace_dir)
+
+        (log_dir, options), = started
+        assert log_dir == trace_dir
+        assert options.host_tracer_level == 0
+        assert options.python_tracer_level == 0
+        assert glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+        assert not est.last_profile._tracing
+        # the stage timers run beside the trace, as with profile=True
+        assert est.last_profile.summary()["train_step"]["count"] == 2
+        # the trace belongs to that one call: the next fit starts none
+        est.fit((x, y), batch_size=8, epochs=2)
+        assert len(started) == 1
+
+    def test_no_host_annotation_left_in_learn(self):
+        """A ``TraceAnnotation`` is recorded by the host tracer only;
+        with the trace device-only it would be dead code."""
+        import os
+        import re
+
+        import analytics_zoo_tpu.learn as learn
+
+        root = os.path.dirname(learn.__file__)
+        for name in sorted(os.listdir(root)):
+            if name.endswith(".py"):
+                with open(os.path.join(root, name)) as f:
+                    assert not re.search(
+                        r"TraceMe|TraceAnnotation", f.read()), name
