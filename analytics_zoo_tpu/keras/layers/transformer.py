@@ -4,7 +4,11 @@ The analog of ``TransformerLayer.scala`` (GPT-style decoder stack) and
 ``BERT.scala`` (ref: zoo/.../keras/layers/{TransformerLayer,BERT}.scala),
 re-designed TPU-first: attention goes through ``ops.attention`` (Pallas
 flash kernel on TPU, never materializing the [L, L] score matrix the
-reference builds), all matmuls MXU-shaped, gelu fused by XLA.
+reference builds), all matmuls MXU-shaped. BERT's erf GELU is
+``ops.activations.gelu_exact``: evaluated once a layer with its
+derivative stored for the backward, where XLA left alone re-derives erf
+inside each of ``ffn_out``'s three matmul fusions; the tanh ``"gelu"``
+and ``relu`` are stock and fused by XLA.
 
 North-star workload #4 (BERT-base fine-tune) builds on BERT here.
 """
@@ -21,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from analytics_zoo_tpu.keras.layers.base import KerasLayer
+from analytics_zoo_tpu.ops.activations import gelu_exact
 from analytics_zoo_tpu.ops.attention import dot_product_attention
 
 _zigzag_shape_warned = False
@@ -159,7 +164,7 @@ class TransformerBlock(nn.Module):
         # checkpoints); "gelu_exact" is the erf form BERT/torch use --
         # the two diverge ~1e-3, so each model family pins its own
         if self.activation == "gelu_exact":
-            act = lambda t: jax.nn.gelu(t, approximate=False)  # noqa: E731
+            act = gelu_exact
         elif self.activation == "gelu":
             act = jax.nn.gelu
         else:
