@@ -1,12 +1,13 @@
-"""Mixture-of-experts FFN with expert parallelism.
+"""Mixture-of-experts layers. Two modules, two routers:
 
-New capability relative to the reference (data-parallel only, SURVEY.md
-section 2.3: "no tensor/pipeline/sequence/expert/context parallelism
-anywhere"). Completes the parallelism alphabet next to dp/tp/sp/pp:
+``MoEFFN`` -- softmax top-k router, two-matrix experts with biases and
+an activation (GELU by default), a sown load-balance loss. Its paths:
 
 - **Dense path** (no mesh axis): every expert runs on every token and
-  the top-k gate weights select -- the exact "dense MoE" computation,
-  used as the numeric reference and the small-scale fallback.
+  the top-k gate weights select -- exact, E/k times the needed work;
+  the numeric reference for ``MoEFFN``'s two other paths (and for
+  nothing else: ``DroplessExperts`` below is another layer, whose plain
+  reference is ``benchmark/reference/trinity.py``).
 - **Expert-parallel, broadcast layout** (``layout="broadcast"``):
   expert parameters shard over a mesh axis (one slice of experts per
   device). Each device computes ONLY its resident experts on the
@@ -26,10 +27,27 @@ anywhere"). Completes the parallelism alphabet next to dp/tp/sp/pp:
   memory scale 1/ep; kept tokens match the dense path exactly, dropped
   tokens contribute zero (the residual path carries them).
 
-The router is a standard softmax top-k with renormalized gates and the
-switch-transformer load-balance auxiliary loss, sown into the
-``losses`` collection as ``moe_aux_loss`` (fetch with
+``MoEFFN``'s router is a standard softmax top-k with renormalized
+gates and the switch-transformer load-balance auxiliary loss, sown into
+the ``losses`` collection as ``moe_aux_loss`` (fetch with
 ``mutable=["losses"]`` and add it to the objective).
+
+``DroplessExperts`` -- sigmoid router with a selection bias that no
+gradient trains, normalised and scaled weights, SwiGLU experts without
+biases, an optional shared expert; *told which experts it holds*. One
+path: route over all ``n_routed`` experts, keep the assignments that
+fall on the ``n_held`` held here, sort them by expert, run the three
+SwiGLU products as grouped matrix products (``grouped_dot``), sum them
+back per token. No capacity and no dropped token, at static
+shapes: the sorted buffer has one row for every assignment that could
+fall on a held expert (``tokens * min(top_k, n_held)``), and the
+grouped products touch only the rows that did. (A smaller buffer for
+the usual step behind ``lax.cond`` was tried and is not used: a traced
+``cond`` is counted beside its own body by the benchmark's reduction,
+PERF.md section 6.) With ``n_held ==
+n_routed`` it is the whole layer; with fewer it is what one chip of an
+expert-parallel group computes between the two exchanges, which are
+not wired here.
 """
 
 from __future__ import annotations
@@ -54,7 +72,213 @@ def resolve_expert_axis(value: Optional[str]) -> Optional[str]:
         return config_axis("expert")
     return value
 
-__all__ = ["MoEFFN", "MoE", "MoETransformerBlock"]
+__all__ = ["MoEFFN", "MoE", "MoETransformerBlock", "DroplessExperts",
+           "SwiGLU"]
+
+
+class SwiGLU(nn.Module):
+    """``(silu(x W1) * (x W3)) W2`` without biases."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(n, name):
+            return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
+
+        h = nn.silu(dense(self.width, "w1")(x)) * dense(self.width, "w3")(x)
+        return dense(x.shape[-1], "w2")(h)
+
+
+# (rows, contraction, columns) tile of the grouped product's kernel
+GROUPED_DOT_TILING = (512, 1024, 1024)
+
+
+def grouped_dot(x, w, sizes):
+    """``x[rows of group e] @ w[e]`` for rows sorted by group: x [m, k],
+    w [groups, k, n], ``sizes`` [groups] int32. Rows past ``sum(sizes)``
+    are left undefined. Off the CPU the product is JAX's Pallas grouped
+    matmul (``megablox.gmm``: it visits only the row tiles that hold a
+    group's rows, and its ``pallas_call`` keeps the caller's scope in
+    ``op_name``); on the CPU, and for a row count its tile does not
+    divide, ``jax.lax.ragged_dot``. On the TPU ``ragged_dot`` compiles
+    to a kernel of XLA's own that is named ``ragged-dot-none`` whatever
+    scope it was called under, so a device trace cannot attribute it
+    (docs/kernels.md has both timings)."""
+    from analytics_zoo_tpu.ops.attention import _platform
+
+    tile_m = GROUPED_DOT_TILING[0]
+    if _platform(x) == "cpu" or x.shape[0] % tile_m:
+        return jax.lax.ragged_dot(x, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    # positional: the custom_vjp marks arguments 3, 4, 7, 8 static
+    return gmm(x, w, sizes, x.dtype, GROUPED_DOT_TILING, None, None,
+               False, False)
+
+
+def _gather_rows(x, plan):
+    token_of_row, row_held, _, _ = plan
+    return jnp.where(row_held[:, None], x[token_of_row], 0)
+
+
+def _sum_slots(y, plan):
+    _, _, row_of_slot, slot_held = plan
+    return jnp.sum(jnp.where(slot_held[..., None], y[row_of_slot], 0),
+                   axis=1)
+
+
+@jax.custom_vjp
+def _rows_out(x, plan):
+    """[n, d] -> [rows, d]: the token of every sorted assignment, zeros
+    in the rows no held assignment fills. Its transpose is
+    ``_rows_back``, so both directions are gathers (a scatter-add of
+    tens of thousands of rows serialises on the TPU)."""
+    return _gather_rows(x, plan)
+
+
+@jax.custom_vjp
+def _rows_back(y, plan):
+    """[rows, d] -> [n, d]: each token's held assignments summed; rows
+    that no held assignment fills are never read."""
+    return _sum_slots(y, plan)
+
+
+_rows_out.defvjp(lambda x, plan: (_gather_rows(x, plan), plan),
+                 lambda plan, g: (_rows_back(g, plan), None))
+_rows_back.defvjp(lambda y, plan: (_sum_slots(y, plan), plan),
+                  lambda plan, g: (_rows_out(g, plan), None))
+
+
+class DroplessExperts(nn.Module):
+    """Sigmoid-routed SwiGLU experts, the share held here: x [B, L, d]
+    -> [B, L, d] (module docstring).
+
+    Args:
+      width: each routed expert's SwiGLU width.
+      n_routed: the router's width -- every expert of the layer.
+      n_held / first_held: this chip's experts are
+        ``first_held .. first_held + n_held - 1``; what the others would
+        add is left out (an expert-parallel caller sums the shares).
+      top_k: experts per token, chosen by ``sigmoid score + bias``.
+      route_scale: the weights are ``score / sum of the chosen scores``
+        times this.
+      shared_width: width of the shared expert every token passes
+        through (0 = none).
+      bias_step: after each training step ``bias += bias_step *
+        sign(mean(count) - count)`` over the step's per-expert
+        assignment counts; no gradient reaches ``bias`` (collection
+        ``router_state``).
+
+    Collection ``counters`` holds cumulative int32 counts, updated on
+    training applies and published by the Estimator at each epoch's
+    host sync (docs/observability.md): ``moe_assignments``,
+    ``moe_assignments_held``, ``moe_assignments_dropped`` (always 0:
+    the buffer covers the worst case), ``moe_bias_steps`` and
+    ``moe_expert_assignments`` [n_held]."""
+
+    width: int
+    n_routed: int
+    n_held: int
+    first_held: int = 0
+    top_k: int = 8
+    route_scale: float = 1.0
+    shared_width: int = 0
+    bias_step: float = 0.001
+    dtype: Any = jnp.float32
+
+    def _route(self, m, train: bool):
+        """Weights [n, k] (float32), expert ids [n, k], counts [E]."""
+        e = self.n_routed
+        scores = jax.nn.sigmoid(nn.Dense(
+            e, use_bias=False, dtype=jnp.float32, name="router")(
+                m.astype(jnp.float32)))                      # [n, E]
+        bias = self.variable("router_state", "bias",
+                             lambda: jnp.zeros((e,), jnp.float32))
+        _, idx = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.value), self.top_k)
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)
+        weights = chosen / (jnp.sum(chosen, -1, keepdims=True)
+                            + 1e-20) * self.route_scale
+        counts = jnp.zeros((e,), jnp.int32).at[idx.ravel()].add(1)
+        if train and self.is_mutable_collection("router_state"):
+            load = counts.astype(jnp.float32)
+            bias.value = bias.value + self.bias_step * jnp.sign(
+                jnp.mean(load) - load)
+        return weights, idx, counts
+
+    def _count(self, counts, held, train: bool):
+        adds = {"moe_assignments": jnp.sum(counts),
+                "moe_assignments_held": jnp.sum(held),
+                "moe_assignments_dropped": jnp.zeros((), jnp.int32),
+                "moe_bias_steps": jnp.ones((), jnp.int32),
+                "moe_expert_assignments": held}
+        for name, add in adds.items():
+            counter = self.variable(
+                "counters", name,
+                lambda a=add: jnp.zeros(a.shape, jnp.int32))
+            if train and self.is_mutable_collection("counters"):
+                counter.value = counter.value + add
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        if not 0 <= self.first_held <= self.n_routed - self.n_held:
+            raise ValueError(
+                f"experts {self.first_held}..{self.first_held + self.n_held}"
+                f" are not among the {self.n_routed} routed over")
+        d, k, held_n = x.shape[-1], self.top_k, self.n_held
+        m = x.reshape(-1, d).astype(self.dtype)
+        n = m.shape[0]
+        with jax.named_scope("moe_route"):
+            weights, idx, counts = self._route(m, train)
+            # assignments per held expert: the grouped products' groups
+            sizes = counts[self.first_held:self.first_held + held_n]
+            self._count(counts, sizes, train)
+
+        def expert_param(name, shape):
+            return self.param(name, nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1, batch_axis=(0,)),
+                (held_n,) + shape).astype(self.dtype)
+
+        w1 = expert_param("w1", (d, self.width))
+        w3 = expert_param("w3", (d, self.width))
+        w2 = expert_param("w2", (self.width, d))
+
+        with jax.named_scope("moe_dispatch"):
+            # assignments on absent experts sort behind the held ones
+            local = idx.ravel() - self.first_held
+            local = jnp.where((local >= 0) & (local < held_n), local,
+                              held_n)
+            order = jnp.argsort(local, stable=True)           # [n * k]
+            row_of_slot = jnp.zeros((n * k,), jnp.int32).at[order].set(
+                jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+            # a token's held assignments are at most min(k, held): the
+            # rows past that bound can only be absent experts', so the
+            # buffer covers the worst case and nothing is ever dropped
+            rows = n * min(k, held_n)
+            first = order[:rows]
+            plan = (first // k, local[first] < held_n,
+                    jnp.minimum(row_of_slot, rows - 1).reshape(n, k),
+                    (local < held_n).reshape(n, k))
+            xs = _rows_out(m, plan)
+        with jax.named_scope("moe_experts"):
+            h = (nn.silu(grouped_dot(xs, w1, sizes))
+                 * grouped_dot(xs, w3, sizes))
+            ys = grouped_dot(h, w2, sizes)
+        with jax.named_scope("moe_combine"):
+            # whatever the grouped product left in the unfilled rows is
+            # replaced BEFORE it meets a weight: masked after the
+            # product, its NaNs would reach the weights' gradient as
+            # 0 * NaN
+            w_row = weights.ravel()[first][:, None].astype(self.dtype)
+            out = _rows_back(jnp.where(plan[1][:, None], ys, 0) * w_row,
+                             plan)
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                out = out + SwiGLU(self.shared_width, dtype=self.dtype,
+                                   name="shared")(m)
+        return out.reshape(x.shape).astype(x.dtype)
 
 
 class MoEFFN(nn.Module):

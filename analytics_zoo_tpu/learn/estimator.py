@@ -220,6 +220,8 @@ class Estimator:
         self._epoch_fns: Dict[Any, Callable] = {}
         self._predict_fns: Dict[Any, Callable] = {}
         self.last_profile = None  # set by fit(profile=True)
+        # what _publish_counters has published of each device counter
+        self._counters_seen: Dict[Any, np.ndarray] = {}
         self._rng = training_prng_key(seed)
         from analytics_zoo_tpu.common.context import (
             enable_compilation_cache)
@@ -281,6 +283,34 @@ class Estimator:
                                           self.param_spec_fn)
             self.opt_state = shard_pytree(self.opt_state, self.mesh,
                                           self.param_spec_fn)
+
+    def _publish_counters(self) -> None:
+        """Collection ``counters``: cumulative int32 counts that modules
+        keep on the device and ``_step_math`` threads like any other
+        non-parameter state (e.g. ``DroplessExperts``' assignments).
+        Called where an epoch has just synced with the host anyway; the
+        growth since the last call goes to the registry as
+        ``zoo_model_<leaf name>_total{module=<module path>, index=<i>}``
+        (``index`` counts a vector's elements; a scalar has ``0``).
+        The difference is taken modulo 2**32, so a device counter may
+        wrap as long as one epoch adds less than that."""
+        counters = (self.variables or {}).get("counters")
+        if not counters:
+            return
+        leaves = jax.tree_util.tree_flatten_with_path(
+            jax.device_get(counters))[0]
+        for path, value in leaves:
+            *module, name = [str(getattr(p, "key", p)) for p in path]
+            now = np.atleast_1d(np.asarray(value)).astype(np.uint32)
+            seen = self._counters_seen.get(tuple(path), np.zeros_like(now))
+            self._counters_seen[tuple(path)] = now
+            family = _REG.counter(
+                f"zoo_model_{name}_total",
+                "Growth of the model's device counter of that name",
+                ("module", "index"))
+            for i, grown in enumerate(now - seen):
+                family.labels(module="/".join(module), index=i).inc(
+                    int(grown))
 
     # -------------------------------------------------------- train step --
     def _step_math(self, variables, opt_state, x, y, rng):
@@ -674,6 +704,7 @@ class Estimator:
                              else float("nan")),
                     "seconds": time.time() - epoch_start,
                 }
+                self._publish_counters()
                 if last_val is not None:
                     entry.update({f"val_{k}": v for k, v in last_val.items()})
                 history.append(entry)
@@ -796,6 +827,7 @@ class Estimator:
                 self.global_step += n_steps
                 _M_EPOCHS.inc()
                 _M_STEPS.inc(n_steps)
+                self._publish_counters()
                 entry: Dict[str, float] = {
                     "epoch": self.epoch, "loss": lf,
                     "seconds": time.time() - t0}
@@ -923,6 +955,7 @@ def recompiled(old: Optional["Estimator"], model, **kwargs) -> "Estimator":
     if old is not None:
         est.global_step = old.global_step
         est.epoch = old.epoch
+        est._counters_seen = old._counters_seen
     return est
 
 

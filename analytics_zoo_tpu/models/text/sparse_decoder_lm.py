@@ -1,0 +1,129 @@
+"""SparseDecoderLM: a causal language model of ``SparseDecoderLayer``
+blocks -- window and full attention mixed by layer, leading dense
+layers and then routed experts -- trained on the next token.
+
+    h0 = Embed[ids] * sqrt(d)     (``scale_embedding``)
+    h  = layers(h0)
+    logits = RMSNorm(h) W_head    (untied, float32)
+
+The vocabulary and the experts may be one chip's share of a larger
+deployment: ``vocab`` rows of the table and the head, ``n_held`` of
+``n_routed`` experts (``keras/layers/moe.DroplessExperts``). Each layer
+is rematerialised for the backward pass: only its input is kept.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from analytics_zoo_tpu.keras.layers.sparse_decoder import (
+    RMSNorm, SLIDING, SparseDecoderLayer)
+from analytics_zoo_tpu.models.common import ZooModel, register_model
+
+
+def next_token_loss(logits, labels):
+    """Mean over positions of the cross-entropy of ``labels`` (each
+    position's next token) under float32 ``logits`` [B, L, V]."""
+    logits = logits.astype(jnp.float32)
+    labels = labels.astype(jnp.int32)
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+
+class SparseDecoderModule(nn.Module):
+    vocab: int
+    hidden_size: int
+    layer_types: Sequence[str]
+    n_dense_layers: int
+    n_head: int
+    n_kv_head: int
+    head_dim: int
+    window: int
+    dense_width: int
+    expert_width: int
+    n_routed: int
+    n_held: int
+    first_held: int = 0
+    top_k: int = 8
+    route_scale: float = 1.0
+    shared_width: int = 0
+    bias_step: float = 0.001
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    scale_embedding: bool = True
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        ids = x["input_ids"] if isinstance(x, dict) else x
+        d = self.hidden_size
+        h = nn.Embed(self.vocab, d, name="embed",
+                     embedding_init=nn.initializers.normal(0.02))(
+            ids.astype(jnp.int32)).astype(self.dtype)
+        if self.scale_embedding:
+            h = h * np.sqrt(d).astype(self.dtype)
+        experts = dict(
+            width=self.expert_width, n_routed=self.n_routed,
+            n_held=self.n_held, first_held=self.first_held,
+            top_k=self.top_k, route_scale=self.route_scale,
+            shared_width=self.shared_width, bias_step=self.bias_step)
+        # the layer's input is all the backward pass keeps of it
+        layer = nn.remat(SparseDecoderLayer, static_argnums=(2,))
+        for i, kind in enumerate(self.layer_types):
+            h = layer(
+                kind=kind, n_head=self.n_head, n_kv_head=self.n_kv_head,
+                head_dim=self.head_dim, window=self.window,
+                dense_width=self.dense_width,
+                experts=None if i < self.n_dense_layers else experts,
+                rope_theta=self.rope_theta, eps=self.eps,
+                dtype=self.dtype, name=f"layer_{i}")(h, train)
+        h = RMSNorm(self.eps, self.dtype, name="final_norm")(h)
+        head = self.param("head", nn.initializers.normal(0.02),
+                          (d, self.vocab))
+        return jnp.dot(h, head.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+
+@register_model
+class SparseDecoderLM(ZooModel):
+    """fit expects x = {"input_ids": [B, L]} (or the array) and
+    y = [B, L], each position's next token; predict returns float32
+    logits [B, L, vocab]."""
+
+    default_loss = staticmethod(next_token_loss)
+    default_optimizer = "adam"
+    default_metrics = ()
+
+    def __init__(self, vocab: int, hidden_size: int,
+                 layer_types: Sequence[str], n_dense_layers: int,
+                 n_head: int, n_kv_head: int, head_dim: int, window: int,
+                 dense_width: int, expert_width: int, n_routed: int,
+                 n_held: int, first_held: int = 0, top_k: int = 8,
+                 route_scale: float = 1.0, n_shared: int = 1,
+                 bias_step: float = 0.001, rope_theta: float = 10000.0,
+                 eps: float = 1e-5, scale_embedding: bool = True,
+                 dtype: str = "float32"):
+        super().__init__(
+            vocab=vocab, hidden_size=hidden_size,
+            layer_types=list(layer_types), n_dense_layers=n_dense_layers,
+            n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
+            window=window, dense_width=dense_width,
+            expert_width=expert_width, n_routed=n_routed, n_held=n_held,
+            first_held=first_held, top_k=top_k, route_scale=route_scale,
+            n_shared=n_shared, bias_step=bias_step, rope_theta=rope_theta,
+            eps=eps, scale_embedding=scale_embedding, dtype=dtype)
+
+    def _build_module(self):
+        c = dict(self._config)
+        c["shared_width"] = c.pop("n_shared") * c["expert_width"]
+        c["layer_types"] = tuple(c["layer_types"])
+        c["dtype"] = jnp.dtype(c["dtype"])
+        return SparseDecoderModule(**c)
+
+    def _example_input(self):
+        return {"input_ids": np.zeros((1, 16), np.int32)}

@@ -1,19 +1,26 @@
-"""Attention dispatch: Pallas flash kernels on TPU, jnp einsum on CPU.
+"""Attention dispatch. One entry point, ``dot_product_attention``, and
+four paths; the one taken names itself in ``op_name``:
+
+- ``attention_flash`` -- the framework's own Pallas kernels
+  (``pallas_attention.pallas_flash_attention_fwd``, exact custom_vjp):
+  off the CPU, no mask or dropout, both lengths multiples of 128,
+  head_dim a multiple of 64, and either ``zoo.ops.attention_impl`` set
+  to it or a sequence longer than ``zoo.ops.attention_flash_min_seq``.
+  The only path that serves a causal ``window`` and K/V with fewer
+  heads than Q without materialising either (docs/kernels.md); a
+  window call is named ``attention_flash_window``;
+- ``attention_stock_pallas`` -- JAX's fused fwd+bwd kernel: the same
+  conditions with head_dim <= 128, for key-padding masks (lowered to
+  segment ids) and head sizes the owned kernel refuses;
+- ``attention_einsum`` -- batched matmuls with an f32 softmax and the
+  [L, L] scores in HBM: the CPU's path, short sequences on the chip
+  (BERT at L384), arbitrary 4-D masks. With a window:
+  ``attention_einsum_window``;
+- ``attention_reference`` -- the same in plain ``jnp``, for attention
+  dropout (flash kernels do not support it).
 
 Replaces the reference's O(L^2)-materialized attention
-(ref: zoo/.../keras/layers/TransformerLayer.scala attn -- builds the full
-[B, H, L, L] score matrix through BigDL ops). On TPU the flash kernels
-never materialize scores in HBM:
-
-- head_dim % 64 == 0 -> the framework's own Pallas kernel
-  (``pallas_attention.pallas_flash_attention_fwd``, exact custom_vjp;
-  covers BERT-base head_dim 64 since r5);
-- otherwise -> the stock fused fwd+bwd kernel, which also serves
-  key-padding masks (lowered to segment ids).
-
-The jnp reference path handles CPU, arbitrary 4-D masks, and attention
-dropout (flash kernels don't support prob dropout -- same trade-off every
-flash implementation makes).
+(ref: zoo/.../keras/layers/TransformerLayer.scala attn).
 """
 
 from __future__ import annotations
@@ -27,16 +34,39 @@ import numpy as np
 NEG_INF = -1e30
 
 
+def _repeat_kv(q, k, v):
+    """K/V with fewer heads than Q, repeated to Q's (query head n reads
+    KV head ``n // group``): what the paths that materialise scores do;
+    the owned flash kernel reads them through its index maps instead."""
+    if k.shape[1] == q.shape[1]:
+        return k, v
+    group, rest = divmod(q.shape[1], k.shape[1])
+    if rest:
+        raise ValueError(f"{q.shape[1]} query heads do not divide over "
+                         f"{k.shape[1]} KV heads")
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
+def _causal_keep(lq: int, lk: int, window: Optional[int]):
+    """[lq, lk] bool: bottom-right-aligned causal mask, and with
+    ``window`` only the ``window`` newest keys of each row."""
+    keep = jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq)
+    if window is not None:
+        keep &= ~jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq - window)
+    return keep
+
+
 def reference_attention(q, k, v, mask=None, causal: bool = False,
-                        scale: Optional[float] = None):
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
     """Exact jnp attention; the single source of truth the Pallas kernels
     are tested against and the custom_vjp backward recomputes through."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
-        lq, lk = q.shape[2], k.shape[2]
-        cm = jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq)
+        cm = _causal_keep(q.shape[2], k.shape[2], window)
         logits = jnp.where(cm[None, None], logits, NEG_INF)
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, NEG_INF)
@@ -45,7 +75,8 @@ def reference_attention(q, k, v, mask=None, causal: bool = False,
 
 
 def _einsum_attention(q, k, v, mask=None, causal: bool = False,
-                      scale: Optional[float] = None):
+                      scale: Optional[float] = None,
+                      window: Optional[int] = None):
     """MXU-shaped exact attention: scores accumulate in f32 (softmax
     numerics), probabilities drop back to the value dtype so the PV
     matmul rides the fast bf16 MXU path instead of a full-precision
@@ -53,11 +84,11 @@ def _einsum_attention(q, k, v, mask=None, causal: bool = False,
     it); this is the variant the dispatcher uses."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
-        lq, lk = q.shape[2], k.shape[2]
-        cm = jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq)
+        cm = _causal_keep(q.shape[2], k.shape[2], window)
         logits = jnp.where(cm[None, None], logits, NEG_INF)
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, NEG_INF)
@@ -76,16 +107,20 @@ def _platform(q) -> str:
 def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
                           causal: bool = False,
                           scale: Optional[float] = None,
-                          dropout_rate: float = 0.0, dropout_rng=None):
-    """q,k,v: [B, H, L, D]. ``mask``: arbitrary [B, H, Lq, Lk]-broadcastable
-    (1 = attend; forces the jnp path). ``key_padding_mask``: [B, Lk] with
-    1 = real token -- flash-compatible (lowered to segment ids).
+                          dropout_rate: float = 0.0, dropout_rng=None,
+                          window: Optional[int] = None):
+    """q: [B, H, L, D]; k, v: [B, H_kv, Lk, D] with ``H_kv`` dividing
+    ``H`` (query head n reads KV head ``n // (H / H_kv)``). ``mask``:
+    arbitrary [B, H, Lq, Lk]-broadcastable (1 = attend; forces the jnp
+    path). ``key_padding_mask``: [B, Lk] with 1 = real token --
+    flash-compatible (lowered to segment ids). ``window`` (with
+    ``causal``): row i reads only keys ``i - window < j <= i``.
     Returns [B, H, Lq, D].
 
     The path taken names itself in every op's ``op_name`` (and so in a
     device trace): ``attention_flash`` (this repo's Pallas kernel),
     ``attention_stock_pallas``, ``attention_einsum`` or
-    ``attention_reference``."""
+    ``attention_reference``; a window call adds ``_window``."""
     d = q.shape[-1]
     l, lk = q.shape[2], k.shape[2]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -93,6 +128,9 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
         # with the bottom-right-aligned diagonal the first lq-lk rows
         # attend to nothing; every backend would return garbage for them
         raise ValueError("causal attention requires len(q) <= len(kv)")
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    kind = "" if window is None else "_window"
 
     from analytics_zoo_tpu.common.config import get_config
 
@@ -109,19 +147,21 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
     flash_ok = (impl != "einsum"
                 and mask is None and dropout_rate == 0.0
                 and _platform(q) != "cpu"
-                and l % 128 == 0 and lk % 128 == 0
-                and not (causal and l > lk))
+                and l % 128 == 0 and lk % 128 == 0)
     if flash_ok and d % 64 == 0:
         from analytics_zoo_tpu.ops.pallas_attention import (
             pallas_flash_attention_fwd)
 
         if key_padding_mask is None:
-            with jax.named_scope("attention_flash"):
-                return pallas_flash_attention_fwd(q, k, v, causal, scale)
+            with jax.named_scope("attention_flash" + kind):
+                return pallas_flash_attention_fwd(q, k, v, causal, scale,
+                                                  None, None, window)
         # padding masks fall through to the stock kernel's segment ids
     # the stock kernel's causal mask is top-left aligned (no cross-length
-    # offset), so it only agrees with reference_attention when lq == lk
-    if flash_ok and d <= 128 and (not causal or l == lk):
+    # offset), so it only agrees with reference_attention when lq == lk;
+    # it knows neither a window nor grouped heads
+    if (flash_ok and d <= 128 and (not causal or l == lk)
+            and window is None and k.shape[1] == q.shape[1]):
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             SegmentIds, flash_attention)
 
@@ -139,16 +179,17 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
         pm = key_padding_mask[:, None, None, :].astype(bool)
         mask = pm if mask is None else (mask.astype(bool) & pm)
     if dropout_rate == 0.0:
-        with jax.named_scope("attention_einsum"):
+        with jax.named_scope("attention_einsum" + kind):
             return _einsum_attention(q, k, v, mask=mask, causal=causal,
-                                     scale=scale)
-    with jax.named_scope("attention_reference"):
+                                     scale=scale, window=window)
+    with jax.named_scope("attention_reference" + kind):
         if dropout_rate > 0.0 and dropout_rng is not None:
             # dropout needs the materialized probs; inline the reference
             # math
+            k, v = _repeat_kv(q, k, v)
             logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
             if causal:
-                cm = jnp.tril(jnp.ones((l, lk), bool), k=lk - l)
+                cm = _causal_keep(l, lk, window)
                 logits = jnp.where(cm[None, None], logits, NEG_INF)
             if mask is not None:
                 logits = jnp.where(mask.astype(bool), logits, NEG_INF)
@@ -158,4 +199,4 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
             probs = probs * keep / (1.0 - dropout_rate)
             return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
         return reference_attention(q, k, v, mask=mask, causal=causal,
-                                   scale=scale)
+                                   scale=scale, window=window)

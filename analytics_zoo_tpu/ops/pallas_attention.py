@@ -24,6 +24,22 @@ full); callers fall back to the jnp path otherwise. Causal masking
 aligns the diagonal bottom-right (tril k=lk-lq) to match
 ``reference_attention``; causal with len(q) > len(kv) is rejected.
 
+Two variants ride the same three kernels (docs/kernels.md):
+
+- **window** (``window=W``, causal only): row i reads keys
+  ``i - W < j <= i``. The sequential grid dimension is *relative*: it
+  has only as many steps as the widest run of blocks any row block
+  needs (``_steps``), step t of row block qi is block ``lo(qi) + t``
+  (``_kv_bounds``), and steps past ``hi(qi)`` neither compute nor
+  fetch (their index map stays on block ``hi``). A plain causal call
+  uses the same bounds with ``lo = 0``, so the blocks above the
+  diagonal are no longer fetched either.
+- **grouped KV heads**: K and V may carry fewer heads than Q
+  (``h % h_kv == 0``); query head n reads KV head ``n // (h / h_kv)``
+  through the index maps, so K/V are never repeated in HBM. The dK/dV
+  kernel walks the query heads of its group in its sequential
+  dimension and sums them in VMEM.
+
 The grid is declared (parallel, parallel, arbitrary) so Mosaic
 pipelines the sequential kv/q accumulation dimension while batch and
 row blocks schedule freely.
@@ -61,44 +77,83 @@ def _auto_block(length: int, cap: int = 1024) -> int:
     return 128
 
 
-def _causal_run(qi, ki, block_q: int, block_k: int, causal: bool,
-                offset: int):
-    """Whether kv-block ki overlaps the causal region of q-block qi."""
-    if not causal:
-        return True
-    return ki * block_k <= qi * block_q + (block_q - 1) + offset
+def _kv_bounds(qi, block_q: int, block_k: int, nk: int, causal: bool,
+               offset: int, window: Optional[int], lo_of=max, hi_of=min):
+    """First and last kv-block (inclusive) that q-block ``qi`` reads.
+    Works on Python ints (``_steps``) and, with ``lo_of=jnp.maximum``,
+    ``hi_of=jnp.minimum``, on program ids."""
+    lo, hi = 0, nk - 1
+    if causal:
+        hi = hi_of(nk - 1, (qi * block_q + block_q - 1 + offset) // block_k)
+    if window is not None:
+        lo = lo_of(qi * block_q + offset - window + 1, 0) // block_k
+    return lo, hi
 
 
-def _causal_mask(s, qi, ki, block_q: int, block_k: int, offset: int):
+def _q_bounds(ki, block_q: int, block_k: int, nq: int, causal: bool,
+              offset: int, window: Optional[int], lo_of=max, hi_of=min):
+    """The inverse: first and last q-block that reads kv-block ``ki``.
+    With len(q) < len(kv) the window can put the oldest kv-blocks out of
+    every row's reach: then ``hi`` is -1 (floor division) and no step
+    runs."""
+    lo, hi = 0, nq - 1
+    if causal:
+        lo = lo_of(ki * block_k - offset, 0) // block_q
+    if window is not None:
+        hi = hi_of(nq - 1, (ki * block_k + block_k + window - 2 - offset)
+                   // block_q)
+    return lo, hi
+
+
+def _steps(bounds, n_outer: int, **geometry) -> int:
+    """Length of the sequential grid dimension: the widest run of
+    blocks any outer block needs."""
+    return max(hi - lo + 1 for lo, hi in
+               (bounds(i, **geometry) for i in range(n_outer)))
+
+
+def _traced(bounds, i, **geometry):
+    return bounds(i, lo_of=jnp.maximum, hi_of=jnp.minimum, **geometry)
+
+
+def _mask(s, qi, ki, block_q: int, block_k: int, causal: bool,
+          offset: int, window: Optional[int]):
     """Mask scores above the bottom-right-aligned diagonal
     (reference_attention tril with k=lk-lq), so cross-length q/kv gives
-    identical results on every dispatch path."""
+    identical results on every dispatch path, and scores ``window`` or
+    more keys behind it. A row whose every key in an edge block is
+    masked adds exp(0) terms under a running max of NEG_INF; the first
+    block with a real score (the diagonal's, at the latest) rescales
+    them by exp(NEG_INF - m) = 0."""
+    if not causal:
+        return s
     q_pos = qi * block_q + offset + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = keep & (q_pos - k_pos < window)
+    return jnp.where(keep, s, NEG_INF)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
-                      scale: float, block_q: int, block_k: int,
-                      causal_offset: int, with_lse: bool):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
+                      with_lse: bool, geometry: dict):
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         lse_ref, (m_scr, l_scr, acc_scr) = None, rest
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    lo, hi = _traced(_kv_bounds, qi, **geometry)
+    ki = lo + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    run = _causal_run(qi, ki, block_q, block_k, causal, causal_offset)
-
-    @pl.when(run)
+    @pl.when(ki <= hi)
     def _body():
         # matmul operands stay in input dtype (bf16 rides the fast MXU
         # path; f32 accumulate via preferred_element_type) -- upcasting
@@ -109,8 +164,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, causal_offset)
+        s = _mask(s, qi, ki, **_mask_geometry(geometry))
 
         m_prev = m_scr[:, :1]                     # [BQ, 1]
         l_prev = l_scr[:, :1]
@@ -126,7 +180,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
         acc_scr[...] = acc
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -134,45 +188,71 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
             lse_ref[0] = (m_scr[...] + jnp.log(l)).astype(lse_ref.dtype)
 
 
-def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
-               block_k: int, with_lse: bool):
-    """Returns out [B,H,L,D] and, when ``with_lse``, the per-row
-    logsumexp at [B*H, L, 128] (value broadcast across the 128 lanes --
-    the TPU-native row-stat layout the stock flash kernel also uses;
-    inference passes ``with_lse=False`` so nothing extra hits HBM)."""
-    b, h, l, d = q.shape
-    lk = k.shape[2]
-    block_q = block_q or _auto_block(l)
-    block_k = block_k or _auto_block(lk)
-    if l % block_q or lk % block_k:
-        raise ValueError(f"seq lens ({l},{lk}) must divide blocks "
-                         f"({block_q},{block_k})")
+def _mask_geometry(geometry: dict) -> dict:
+    return {k: geometry[k] for k in
+            ("block_q", "block_k", "causal", "offset", "window")}
+
+
+def _check(q, k, causal: bool, window: Optional[int]) -> int:
+    """Validates the shapes; returns query heads per KV head."""
+    h, l, d = q.shape[1:]
+    h_kv, lk = k.shape[1:3]
     if d % 64:
         raise ValueError(f"head_dim {d} must be a multiple of 64")
     if causal and l > lk:
         # rows attending to nothing are undefined under flash semantics
         raise ValueError("causal attention requires len(q) <= len(kv)")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window needs causal=True and window >= 1")
+    if h % h_kv:
+        raise ValueError(f"{h} query heads do not divide over {h_kv} "
+                         "KV heads")
+    return h // h_kv
+
+
+def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
+               block_k: int, with_lse: bool, window: Optional[int] = None):
+    """Returns out [B,H,L,D] and, when ``with_lse``, the per-row
+    logsumexp at [B*H, L, 128] (value broadcast across the 128 lanes --
+    the TPU-native row-stat layout the stock flash kernel also uses;
+    inference passes ``with_lse=False`` so nothing extra hits HBM)."""
+    b, h, l, d = q.shape
+    h_kv, lk = k.shape[1:3]
+    group = _check(q, k, causal, window)
+    block_q = block_q or _auto_block(l)
+    block_k = block_k or _auto_block(lk)
+    if l % block_q or lk % block_k:
+        raise ValueError(f"seq lens ({l},{lk}) must divide blocks "
+                         f"({block_q},{block_k})")
     qr = q.reshape(b * h, l, d)
-    kr = k.reshape(b * h, lk, d)
-    vr = v.reshape(b * h, lk, d)
-    grid = (b * h, l // block_q, lk // block_k)
-    out_specs = [pl.BlockSpec((1, block_q, d),
-                              lambda bh, qi, ki: (bh, qi, 0))]
+    kr = k.reshape(b * h_kv, lk, d)
+    vr = v.reshape(b * h_kv, lk, d)
+    geometry = dict(block_q=block_q, block_k=block_k, nk=lk // block_k,
+                    causal=causal, offset=lk - l, window=window)
+    grid = (b * h, l // block_q, _steps(_kv_bounds, l // block_q,
+                                        **geometry))
+
+    def q_map(bh, qi, step):
+        return bh, qi, 0
+
+    def kv_map(bh, qi, step):
+        lo, hi = _traced(_kv_bounds, qi, **geometry)
+        return bh // group, jnp.minimum(lo + step, hi), 0
+
+    out_specs = [pl.BlockSpec((1, block_q, d), q_map)]
     out_shape = [jax.ShapeDtypeStruct((b * h, l, d), q.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, block_q, 128),
-                                      lambda bh, qi, ki: (bh, qi, 0)))
+        out_specs.append(pl.BlockSpec((1, block_q, 128), q_map))
         out_shape.append(jax.ShapeDtypeStruct((b * h, l, 128),
                                               jnp.float32))
     res = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k,
-                          causal_offset=lk - l, with_lse=with_lse),
+        functools.partial(_flash_fwd_kernel, scale=scale,
+                          with_lse=with_lse, geometry=geometry),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -208,87 +288,77 @@ def _grid_semantics():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dq_scr, *, causal: bool, scale: float,
-                     block_q: int, block_k: int, causal_offset: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _probs_and_ds(q, k, v, do, lse, delta, qi, ki, scale, geometry):
+    """The block's probabilities and score gradients, regenerated from
+    the saved row logsumexp (shared by the two backward kernels)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    s = _mask(s, qi, ki, **_mask_geometry(geometry))
+    p = jnp.exp(s - lse)                            # [BQ, BK]
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)         # [BQ, BK]
+    return p, p * (dp - delta) * scale
 
-    @pl.when(ki == 0)
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dq_scr, *, scale: float, geometry: dict):
+    qi = pl.program_id(1)
+    step = pl.program_id(2)
+    lo, hi = _traced(_kv_bounds, qi, **geometry)
+    ki = lo + step
+
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = _causal_run(qi, ki, block_q, block_k, causal, causal_offset)
-
-    @pl.when(run)
+    @pl.when(ki <= hi)
     def _body():
-        q = q_ref[0]                                # [BQ, D]
         k = k_ref[0]                                # [BK, D]
-        v = v_ref[0]
-        do = do_ref[0]                              # [BQ, D]
-        lse = lse_ref[0][:, :1]                     # [BQ, 1]
-        delta = delta_ref[0][:, :1]                 # [BQ, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, causal_offset)
-        p = jnp.exp(s - lse)                        # [BQ, BK]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [BQ, BK]
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
+        _, ds = _probs_and_ds(
+            q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0][:, :1],
+            delta_ref[0][:, :1], qi, ki, scale, geometry)
         dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
-                      scale: float, block_q: int, block_k: int,
-                      causal_offset: int):
+                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
+                      q_steps: int, geometry: dict, q_geometry: dict):
+    """One KV head's block ``ki``; the sequential dimension walks the
+    query heads of the group, and for each the q-blocks that read this
+    kv-block."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    lo, hi = _traced(_q_bounds, ki, **q_geometry)
+    qi = lo + step % q_steps
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = _causal_run(qi, ki, block_q, block_k, causal, causal_offset)
-
-    @pl.when(run)
+    @pl.when(qi <= hi)
     def _body():
         q = q_ref[0]                                # [BQ, D]
-        k = k_ref[0]                                # [BK, D]
-        v = v_ref[0]
         do = do_ref[0]                              # [BQ, D]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, causal_offset)
-        p = jnp.exp(s - lse)                        # [BQ, BK]
+        p, ds = _probs_and_ds(
+            q, k_ref[0], v_ref[0], do, lse_ref[0][:, :1],
+            delta_ref[0][:, :1], qi, ki, scale, geometry)
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)     # [BK, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [BQ, BK]
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
         dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)     # [BK, D]
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -305,77 +375,98 @@ def _bwd_cap(length: int, d: int) -> int:
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
-               block_q: int, block_k: int):
+               block_q: int, block_k: int, window: Optional[int] = None):
     b, h, l, d = q.shape
-    lk = k.shape[2]
+    h_kv, lk = k.shape[1:3]
+    group = h // h_kv
     block_q = block_q or _auto_block(l, cap=_bwd_cap(l, d))
     block_k = block_k or _auto_block(lk, cap=_bwd_cap(lk, d))
-    bh = b * h
+    bh, bh_kv = b * h, b * h_kv
+    nq, nk = l // block_q, lk // block_k
     qr = q.reshape(bh, l, d)
-    kr = k.reshape(bh, lk, d)
-    vr = v.reshape(bh, lk, d)
+    kr = k.reshape(bh_kv, lk, d)
+    vr = v.reshape(bh_kv, lk, d)
     dor = g.reshape(bh, l, d)
     # delta_i = rowsum(do_i * o_i): one fused elementwise pass, O(L*D)
     delta = jnp.sum(dor.astype(jnp.float32) *
                     o.reshape(bh, l, d).astype(jnp.float32),
                     axis=-1, keepdims=True)
     delta = jnp.broadcast_to(delta, (bh, l, 128))
-    common = dict(causal=causal, scale=scale, block_q=block_q,
-                  block_k=block_k, causal_offset=lk - l)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh_, a, b_: (bh_, a, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh_, a, b_: (bh_, b_, 0))
-    row_spec = pl.BlockSpec((1, block_q, 128),
-                            lambda bh_, a, b_: (bh_, a, 0))
+    geometry = dict(block_q=block_q, block_k=block_k, nk=nk,
+                    causal=causal, offset=lk - l, window=window)
+
+    def q_map(bh_, qi, step):
+        return bh_, qi, 0
+
+    def kv_map(bh_, qi, step):
+        lo, hi = _traced(_kv_bounds, qi, **geometry)
+        return bh_ // group, jnp.minimum(lo + step, hi), 0
+
+    q_spec = pl.BlockSpec((1, block_q, d), q_map)
+    k_spec = pl.BlockSpec((1, block_k, d), kv_map)
+    row_spec = pl.BlockSpec((1, block_q, 128), q_map)
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, **common),
-        grid=(bh, l // block_q, lk // block_k),
+        functools.partial(_flash_dq_kernel, scale=scale,
+                          geometry=geometry),
+        grid=(bh, nq, _steps(_kv_bounds, nq, **geometry)),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh_, a, b_: (bh_, a, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct((bh, l, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_grid_semantics(),
         interpret=_interpret(),
     )(qr, kr, vr, dor, lse, delta)
 
-    # dk/dv walk kv-blocks in the outer grid dim with q innermost; the
-    # index maps swap (a, b_) roles relative to the dq kernel
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh_, a, b_: (bh_, b_, 0))
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda bh_, a, b_: (bh_, a, 0))
-    row_spec2 = pl.BlockSpec((1, block_q, 128),
-                             lambda bh_, a, b_: (bh_, b_, 0))
+    # dk/dv walk kv-blocks in the outer grid dim; the sequential one
+    # covers (query head of the group) x (q-blocks that read the block)
+    q_geometry = dict(block_q=block_q, block_k=block_k, nq=nq,
+                      causal=causal, offset=lk - l, window=window)
+    q_steps = _steps(_q_bounds, nk, **q_geometry)
+
+    def q_map2(bh_, ki, step):
+        lo, hi = _traced(_q_bounds, ki, **q_geometry)
+        return (bh_ * group + step // q_steps,
+                jnp.maximum(jnp.minimum(lo + step % q_steps, hi), 0), 0)
+
+    def kv_map2(bh_, ki, step):
+        return bh_, ki, 0
+
+    q_spec2 = pl.BlockSpec((1, block_q, d), q_map2)
+    k_spec2 = pl.BlockSpec((1, block_k, d), kv_map2)
+    row_spec2 = pl.BlockSpec((1, block_q, 128), q_map2)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **common),
-        grid=(bh, lk // block_k, l // block_q),
+        functools.partial(_flash_dkv_kernel, scale=scale, q_steps=q_steps,
+                          geometry=geometry, q_geometry=q_geometry),
+        grid=(bh_kv, nk, group * q_steps),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2,
                   row_spec2],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh_, a, b_: (bh_, a, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, a, b_: (bh_, a, 0)),
-        ],
+        out_specs=[k_spec2, k_spec2],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, lk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh_kv, lk, d), k.dtype),
+            jax.ShapeDtypeStruct((bh_kv, lk, d), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_grid_semantics(),
         interpret=_interpret(),
     )(qr, kr, vr, dor, lse, delta)
-    return (dq.reshape(b, h, l, d), dk.reshape(b, h, lk, d),
-            dv.reshape(b, h, lk, d))
+    return (dq.reshape(b, h, l, d), dk.reshape(b, h_kv, lk, d),
+            dv.reshape(b, h_kv, lk, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def pallas_flash_attention_fwd(q, k, v, causal: bool = False,
                                scale: Optional[float] = None,
                                block_q: Optional[int] = None,
-                               block_k: Optional[int] = None):
-    """Flash attention on [B, H, L, D]; exact softmax attention.
-    ``block_q``/``block_k`` default to the largest 128-multiple divisor
-    of each sequence length, capped at 1024."""
+                               block_k: Optional[int] = None,
+                               window: Optional[int] = None):
+    """Flash attention on q [B, H, L, D], k/v [B, H_kv, Lk, D]; exact
+    softmax attention. ``block_q``/``block_k`` default to the largest
+    128-multiple divisor of each sequence length, capped at 1024.
+    ``window`` (causal only) keeps the ``window`` newest keys of each
+    row; ``H_kv`` may divide ``H`` (grouped heads)."""
     out, _ = _flash_fwd(q, k, v, causal, _resolve_scale(scale, q),
-                        block_q, block_k, with_lse=False)
+                        block_q, block_k, with_lse=False, window=window)
     return out
 
 
@@ -383,16 +474,17 @@ def _resolve_scale(scale, q) -> float:
     return scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
 
 
-def _vjp_fwd(q, k, v, causal, scale, block_q, block_k):
+def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, window):
     s = _resolve_scale(scale, q)
     out, lse = _flash_fwd(q, k, v, causal, s, block_q, block_k,
-                          with_lse=True)
+                          with_lse=True, window=window)
     return out, (q, k, v, out, lse, s)
 
 
-def _vjp_bwd(causal, scale, block_q, block_k, res, g):
+def _vjp_bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse, s = res
-    return _flash_bwd(q, k, v, out, lse, g, causal, s, block_q, block_k)
+    return _flash_bwd(q, k, v, out, lse, g, causal, s, block_q, block_k,
+                      window)
 
 
 pallas_flash_attention_fwd.defvjp(_vjp_fwd, _vjp_bwd)

@@ -93,3 +93,58 @@ def test_dispatcher_padding_mask_path_compiles(one_chip):
         lambda q, k, v, kpm: _scalar(attn(q, k, v, kpm)),
         argnums=(0, 1, 2))).lower(*args).compile().as_text()
     assert "tpu_custom_call" in bwd
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_window_and_grouped_heads_compile_at_8k(one_chip, window):
+    """The sparse decoder's attention at its published widths: 32 query
+    heads over 4 KV heads of 128, L8192, through the dispatcher. K/V
+    reach the kernels with 4 heads (no repeat to 32 in HBM) and no
+    [L, L] tensor exists forward or backward."""
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def attn(q, k, v):
+        return attention.dot_product_attention(q, k, v, causal=True,
+                                               window=window)
+
+    bwd = jax.jit(jax.grad(lambda q, k, v: _scalar(attn(q, k, v)),
+                           argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert bwd.count("tpu_custom_call") == 3  # fwd+lse, dq, dk/dv
+    assert "8192,8192" not in bwd
+    assert "bf16[1,32,8192,128]" in bwd       # q, and never K/V:
+    assert "bf16[32,8192,128]" in bwd
+    assert f"(attention_flash{'_window' if window else ''})" in bwd
+
+
+def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
+    """16 of 128 experts at d 2048 / width 1024 over 8,192 tokens,
+    forward and backward: the grouped products are Pallas kernels under
+    ``moe_experts`` (three forward and their transposes), and no buffer
+    is wider than the worst case of 8 rows a token."""
+    from analytics_zoo_tpu.keras.layers.moe import DroplessExperts
+
+    module = DroplessExperts(width=1024, n_routed=128, n_held=16, top_k=8,
+                             route_scale=2.826, shared_width=1024,
+                             dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    variables = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                               jnp.zeros((1, 128, 2048), jnp.bfloat16))
+    variables = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        variables)
+
+    def loss(params, state, x):
+        return _scalar(module.apply({"params": params, **state}, x))
+
+    params = variables.pop("params")
+    text = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+        params, variables, x).compile().as_text()
+    assert "moe_experts/jit(gmm)/pallas_call" in text
+    assert "moe_experts/jit(tgmm)/pallas_call" in text
+    assert "bf16[65536,2048]" in text
+    assert "[131072," not in text
