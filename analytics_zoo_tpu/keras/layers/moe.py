@@ -36,15 +36,21 @@ the ``losses`` collection as ``moe_aux_loss`` (fetch with
 gradient trains, normalised and scaled weights, SwiGLU experts without
 biases, an optional shared expert; *told which experts it holds*. One
 path: route over all ``n_routed`` experts, keep the assignments that
-fall on the ``n_held`` held here, sort them by expert, run the three
-SwiGLU products as grouped matrix products (``grouped_dot``), sum them
-back per token. No capacity and no dropped token, at static
+fall on the ``n_held`` held here, sort them by expert, run the SwiGLU
+as grouped matrix products (``grouped_dot``), sum them back per token
+under their weights. No capacity and no dropped token, at static
 shapes: the sorted buffer has one row for every assignment that could
-fall on a held expert (``tokens * min(top_k, n_held)``), and the
-grouped products touch only the rows that did. (A smaller buffer for
-the usual step behind ``lax.cond`` was tried and is not used: a traced
-``cond`` is counted beside its own body by the benchmark's reduction,
-PERF.md section 6.) With ``n_held ==
+fall on a held expert (``tokens * min(top_k, n_held)``), and *how much
+of it is touched is read on the device*. Every pass over it -- the row
+gather in (``_spread``), ``silu(a) * b`` (``_gated``), the weighted
+sum back per token (``_collect``), and their transposes -- is a loop
+over tiles whose trip count is ``ceil(held / tile)``, ``held`` being
+the step's count of assignments on held experts; the grouped products
+visit only the row tiles of their groups. Rows past the last visited
+tile are never written and never read (docs/kernels.md "Bounded
+passes"; the counters ``moe_buffer_tiles_visited`` / ``moe_buffer_tiles``
+say how much was visited). With every token on ``min(top_k, n_held)``
+held experts the loops cover the whole buffer. With ``n_held ==
 n_routed`` it is the whole layer; with fewer it is what one chip of an
 expert-parallel group computes between the two exchanges, which are
 not wired here.
@@ -52,7 +58,8 @@ not wired here.
 
 from __future__ import annotations
 
-from typing import Any, Optional
+import functools
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -118,37 +125,250 @@ def grouped_dot(x, w, sizes):
                False, False)
 
 
-def _gather_rows(x, plan):
-    token_of_row, row_held, _, _ = plan
-    return jnp.where(row_held[:, None], x[token_of_row], 0)
+# rows of the sorted buffer that one trip of a bounded pass covers: a
+# multiple of the grouped product's 512 (chosen on the chip,
+# docs/kernels.md "Bounded passes")
+BUFFER_TILE = 1024
+# token-major places that one product of the slot sum reduces
+SEGMENT_BLOCK = 256
+# The passes below are jitted with both as static arguments: the four
+# layers' forward, rematerialised forward and backward then share one
+# trace of each (traced in place they added a tenth to the cell's warm
+# set-up, PERF.md section 6), and every call keeps its own scope in
+# ``op_name``.
 
 
-def _sum_slots(y, plan):
-    _, _, row_of_slot, slot_held = plan
-    return jnp.sum(jnp.where(slot_held[..., None], y[row_of_slot], 0),
-                   axis=1)
+def _empty(shape, dtype):
+    """A buffer that nothing has written: the bounded passes fill the
+    tiles up to the last held assignment and nothing reads the rest."""
+    return jax.lax.empty(shape, dtype)
+
+
+class _Plan(NamedTuple):
+    """Where every held assignment stands, twice: sorted by expert (the
+    buffer's rows ``0 .. held - 1``) and in token order (its *places*
+    ``0 .. held - 1``, a token's assignments side by side). A slot is
+    ``token * top_k + choice``."""
+
+    held: jax.Array            # () how many assignments fall on held experts
+    slot_of_row: jax.Array     # [rows]
+    token_of_row: jax.Array    # [rows]
+    slot_of_place: jax.Array   # [rows]; n * k from place ``held`` on
+    token_of_place: jax.Array  # [rows]; n from place ``held`` on
+    row_of_place: jax.Array    # [rows]
+    first_place: jax.Array     # [n] a token's first place
+    has_place: jax.Array       # [n] does it have one?
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _plan(local, sizes, *, rows: int) -> _Plan:
+    """``local`` [n, k]: each choice's index among the ``len(sizes)``
+    held experts, or ``len(sizes)`` for an absent one. Two stable sorts
+    of integers (cheap on the TPU, where a scatter of as many is not):
+    the slots by expert give the rows, absent experts' last; the held
+    rows by slot give the places."""
+    n, k = local.shape
+    held = jnp.sum(sizes)
+    _, slot_of_row = jax.lax.sort(
+        (local.ravel(), jnp.arange(n * k, dtype=jnp.int32)), num_keys=1)
+    slot_of_row = slot_of_row[:rows]
+    row = jnp.arange(rows, dtype=jnp.int32)
+    slot_of_place, row_of_place = jax.lax.sort(
+        (jnp.where(row < held, slot_of_row, n * k), row), num_keys=1)
+    mine = jnp.sum(local < sizes.shape[0], axis=1, dtype=jnp.int32)
+    return _Plan(held, slot_of_row, slot_of_row // k, slot_of_place,
+                 slot_of_place // k, row_of_place, jnp.cumsum(mine) - mine,
+                 mine > 0)
+
+
+def _trips(held, tile: int):
+    return (held + tile - 1) // tile
+
+
+def _tiles(held, rows: int, tile: int, one_tile, out):
+    """``one_tile(at, tile, filled, out)`` over the buffer's tiles of
+    ``tile`` rows, up to the one that holds the last held assignment: a
+    loop whose trip count is read on the device. (Where the tile does
+    not divide the buffer the last one starts early and covers some
+    rows a second time, with the same values.)"""
+    tile = min(tile, rows)
+
+    def body(i, out):
+        at = jnp.minimum(i * tile, rows - tile)
+        return one_tile(at, tile, (at + jnp.arange(tile) < held)[:, None],
+                        out)
+
+    return jax.lax.fori_loop(0, _trips(held, tile), body, out)
+
+
+@functools.partial(jax.jit, static_argnames="tile")
+def _spread(x, plan: _Plan, weights=None, y=None, *, tile: int):
+    """[n, d] -> [rows, d]: row r takes its token's row of ``x``; the
+    rows behind the last held assignment in its tile are zeros, the
+    tiles behind it are not written. With ``weights`` [n, k] each row is
+    scaled by its assignment's weight, and the second result [n, k] is
+    each held assignment's product of that row of ``x`` with its row of
+    ``y`` [rows, d] (the weight's gradient)."""
+    rows, d = plan.slot_of_row.shape[0], x.shape[1]
+    dots = (None if weights is None
+            else jnp.zeros((weights.size,), jnp.float32))
+
+    def one_tile(at, tile, filled, carry):
+        out, dots = carry
+        got = x[jax.lax.dynamic_slice(plan.token_of_row, (at,), (tile,))]
+        if weights is not None:
+            slots = jax.lax.dynamic_slice(plan.slot_of_row, (at,), (tile,))
+            mine = jax.lax.dynamic_slice(y, (at, 0), (tile, d))
+            # (an unfilled row's index is past the end, each its own)
+            dots = dots.at[jnp.where(
+                filled[:, 0], slots, dots.shape[0] + jnp.arange(tile))].set(
+                jnp.sum(got.astype(jnp.float32) * mine.astype(jnp.float32),
+                        axis=-1), mode="drop", unique_indices=True)
+            got = got * weights.ravel()[slots][:, None].astype(x.dtype)
+        return jax.lax.dynamic_update_slice(
+            out, jnp.where(filled, got, 0), (at, 0)), dots
+
+    return _tiles(plan.held, rows, tile, one_tile,
+                  (_empty((rows, d), x.dtype), dots))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "block"))
+def _collect(y, plan: _Plan, weights=None, *, tile: int, block: int):
+    """[rows, d] -> [n, d]: each token's held assignments summed
+    (weighted by ``weights`` [n, k]), float32 accumulation. The rows are
+    gathered in token order, ``block`` places at a time up to
+    the last held one, and a token's neighbouring places are summed by
+    one product with a [block, block] matrix that holds the weight of
+    place p in the row of its token's first place; a block starts
+    ``most - 1`` places before the previous one ends, so that every
+    token's places lie whole in the block its first one is in. One
+    gather of n rows then reads each token's sum."""
+    n = plan.first_place.shape[0]
+    rows, d = y.shape
+    most = rows // n                       # of one token's assignments
+    block = max(block, most)
+    stride = block - most + 1
+    at_once = max(1, tile // block)
+    trips_at_most = -(-rows // (stride * at_once))
+    exact = jax.lax.Precision.HIGHEST if y.dtype == jnp.float32 else None
+    # a trip's places and the one before them, behind one another
+    reach = at_once * stride + most
+    room = (1, trips_at_most * at_once * stride + most - 1 - rows)
+    tokens = jnp.pad(plan.token_of_place, room, constant_values=n)
+    slots = jnp.pad(plan.slot_of_place, room)
+    sources = jnp.pad(plan.row_of_place, room)
+
+    def blocks(of, shift):
+        return jnp.stack([of[b * stride + shift:b * stride + shift + block]
+                          for b in range(at_once)])
+
+    def some_blocks(i, sums):
+        start = i * at_once * stride
+        here = [jax.lax.dynamic_slice(of, (start,), (reach,))
+                for of in (tokens, slots, sources)]
+        token, before = blocks(here[0], 1), blocks(here[0], 0)
+        ended = (start + jnp.arange(at_once)[:, None] * stride
+                 + jnp.arange(block)) >= plan.held
+        got = y[jnp.where(ended, 0, blocks(here[2], 1))]    # [b, block, d]
+        weight = (1.0 if weights is None else weights.ravel()[
+            jnp.where(ended, 0, blocks(here[1], 1))][:, None])
+        # row q: the places of q's token, if q is its first (the places
+        # past the last held one carry token n, which no token is)
+        mix = jnp.where((token != before)[:, :, None]
+                        & (token[:, :, None] == token[:, None]),
+                        weight, 0).astype(y.dtype)      # [b, block, block]
+        part = jnp.einsum("bqp,bpd->bqd", mix, got, precision=exact,
+                          preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_slice(
+            sums, part.astype(y.dtype).reshape(at_once * block, d),
+            (i * at_once * block, 0))
+
+    sums = jax.lax.fori_loop(
+        0, _trips(_trips(plan.held, stride), at_once), some_blocks,
+        _empty((trips_at_most * at_once * block, d), y.dtype))
+    first = plan.first_place
+    at = jnp.where(plan.has_place, first // stride * block + first % stride,
+                   0)
+    return jnp.where(plan.has_place[:, None], sums[at], 0)
+
+
+# the tile and the block as they stand when the layer is traced
+def _spread_now(x, plan, weights=None, y=None):
+    return _spread(x, plan, weights, y, tile=BUFFER_TILE)
+
+
+def _collect_now(y, plan, weights=None):
+    return _collect(y, plan, weights, tile=BUFFER_TILE, block=SEGMENT_BLOCK)
 
 
 @jax.custom_vjp
 def _rows_out(x, plan):
-    """[n, d] -> [rows, d]: the token of every sorted assignment, zeros
-    in the rows no held assignment fills. Its transpose is
-    ``_rows_back``, so both directions are gathers (a scatter-add of
-    tens of thousands of rows serialises on the TPU)."""
-    return _gather_rows(x, plan)
+    """[n, d] -> [rows, d]: the token of every sorted assignment
+    (``_spread``). Its transpose is ``_collect``, so both directions
+    are gathers (a scatter-add of thousands of rows serialises on the
+    TPU), and both have a trip count read on the device, which has no
+    reverse-mode rule: hence the ``custom_vjp``."""
+    return _spread_now(x, plan)[0]
 
 
 @jax.custom_vjp
-def _rows_back(y, plan):
-    """[rows, d] -> [n, d]: each token's held assignments summed; rows
-    that no held assignment fills are never read."""
-    return _sum_slots(y, plan)
+def _rows_back(y, weights, plan):
+    """[rows, d], [n, k] -> [n, d]: ``sum_s weights[t, s] * y[row of
+    (t, s)]`` over a token's held assignments (``_collect``); no row
+    past the last held assignment is read."""
+    return _collect_now(y, plan, weights)
 
 
-_rows_out.defvjp(lambda x, plan: (_gather_rows(x, plan), plan),
-                 lambda plan, g: (_rows_back(g, plan), None))
-_rows_back.defvjp(lambda y, plan: (_sum_slots(y, plan), plan),
-                  lambda plan, g: (_rows_out(g, plan), None))
+def _rows_back_bwd(res, g):
+    y, weights, plan = res
+    dy, dots = _spread_now(g, plan, weights, y)
+    return dy, dots.reshape(weights.shape).astype(weights.dtype), None
+
+
+_rows_out.defvjp(lambda x, plan: (_spread_now(x, plan)[0], plan),
+                 lambda plan, g: (_collect_now(g, plan), None))
+_rows_back.defvjp(
+    lambda y, weights, plan: (_collect_now(y, plan, weights),
+                              (y, weights, plan)),
+    _rows_back_bwd)
+
+
+def _swiglu_halves(ab):
+    width = ab.shape[-1] // 2
+    return nn.silu(ab[:, :width]) * ab[:, width:]
+
+
+@functools.partial(jax.jit, static_argnames="tile")
+def _gate(ab, held, g=None, *, tile: int):
+    """[rows, 2 * width] -> [rows, width]: ``silu(a) * b`` of the two
+    halves, over the tiles that hold assignments (the same bounded pass
+    as ``_spread``); with ``g`` [rows, width], its gradient
+    [rows, 2 * width] under that cotangent."""
+    rows, both = ab.shape
+
+    def one_tile(at, tile, filled, out):
+        mine = jax.lax.dynamic_slice(ab, (at, 0), (tile, both))
+        if g is None:
+            new = _swiglu_halves(mine)
+        else:
+            new, = jax.vjp(_swiglu_halves, mine)[1](
+                jax.lax.dynamic_slice(g, (at, 0), (tile, both // 2)))
+        return jax.lax.dynamic_update_slice(
+            out, jnp.where(filled, new, 0), (at, 0))
+
+    return _tiles(held, rows, tile, one_tile, _empty(
+        (rows, both // 2 if g is None else both), ab.dtype))
+
+
+@jax.custom_vjp
+def _gated(ab, held):
+    """``_gate`` with its bounded backward pass."""
+    return _gate(ab, held, tile=BUFFER_TILE)
+
+
+_gated.defvjp(
+    lambda ab, held: (_gate(ab, held, tile=BUFFER_TILE), (ab, held)),
+    lambda res, g: (_gate(*res, g, tile=BUFFER_TILE), None))
 
 
 class DroplessExperts(nn.Module):
@@ -175,8 +395,10 @@ class DroplessExperts(nn.Module):
     training applies and published by the Estimator at each epoch's
     host sync (docs/observability.md): ``moe_assignments``,
     ``moe_assignments_held``, ``moe_assignments_dropped`` (always 0:
-    the buffer covers the worst case), ``moe_bias_steps`` and
-    ``moe_expert_assignments`` [n_held]."""
+    the buffer covers the worst case), ``moe_bias_steps``,
+    ``moe_expert_assignments`` [n_held], and the tiles of the buffer the
+    bounded passes visited and could have visited,
+    ``moe_buffer_tiles_visited`` and ``moe_buffer_tiles``."""
 
     width: int
     n_routed: int
@@ -198,22 +420,30 @@ class DroplessExperts(nn.Module):
                              lambda: jnp.zeros((e,), jnp.float32))
         _, idx = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias.value), self.top_k)
-        chosen = jnp.take_along_axis(scores, idx, axis=-1)
+        # the chosen scores and the counts by comparison with every
+        # expert's index: on the TPU a gather and a scatter-add of
+        # n * k scalars cost three times this select and sum
+        picked = idx[..., None] == jnp.arange(e)               # [n, k, E]
+        chosen = jnp.sum(jnp.where(picked, scores[:, None], 0), axis=-1)
+        counts = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
         weights = chosen / (jnp.sum(chosen, -1, keepdims=True)
                             + 1e-20) * self.route_scale
-        counts = jnp.zeros((e,), jnp.int32).at[idx.ravel()].add(1)
         if train and self.is_mutable_collection("router_state"):
             load = counts.astype(jnp.float32)
             bias.value = bias.value + self.bias_step * jnp.sign(
                 jnp.mean(load) - load)
         return weights, idx, counts
 
-    def _count(self, counts, held, train: bool):
+    def _count(self, counts, held, rows: int, train: bool):
+        tile = min(BUFFER_TILE, rows)
         adds = {"moe_assignments": jnp.sum(counts),
                 "moe_assignments_held": jnp.sum(held),
                 "moe_assignments_dropped": jnp.zeros((), jnp.int32),
                 "moe_bias_steps": jnp.ones((), jnp.int32),
-                "moe_expert_assignments": held}
+                "moe_expert_assignments": held,
+                "moe_buffer_tiles_visited": _trips(jnp.sum(held), tile),
+                "moe_buffer_tiles": jnp.full((), -(-rows // tile),
+                                             jnp.int32)}
         for name, add in adds.items():
             counter = self.variable(
                 "counters", name,
@@ -230,11 +460,15 @@ class DroplessExperts(nn.Module):
         d, k, held_n = x.shape[-1], self.top_k, self.n_held
         m = x.reshape(-1, d).astype(self.dtype)
         n = m.shape[0]
+        # a token's held assignments are at most min(k, held): a buffer
+        # of that many rows a token covers the worst case, and nothing
+        # is ever dropped; how much of it is touched is read on the device
+        rows = n * min(k, held_n)
         with jax.named_scope("moe_route"):
             weights, idx, counts = self._route(m, train)
             # assignments per held expert: the grouped products' groups
             sizes = counts[self.first_held:self.first_held + held_n]
-            self._count(counts, sizes, train)
+            self._count(counts, sizes, rows, train)
 
         def expert_param(name, shape):
             return self.param(name, nn.initializers.lecun_normal(
@@ -246,34 +480,17 @@ class DroplessExperts(nn.Module):
         w2 = expert_param("w2", (self.width, d))
 
         with jax.named_scope("moe_dispatch"):
-            # assignments on absent experts sort behind the held ones
-            local = idx.ravel() - self.first_held
-            local = jnp.where((local >= 0) & (local < held_n), local,
-                              held_n)
-            order = jnp.argsort(local, stable=True)           # [n * k]
-            row_of_slot = jnp.zeros((n * k,), jnp.int32).at[order].set(
-                jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
-            # a token's held assignments are at most min(k, held): the
-            # rows past that bound can only be absent experts', so the
-            # buffer covers the worst case and nothing is ever dropped
-            rows = n * min(k, held_n)
-            first = order[:rows]
-            plan = (first // k, local[first] < held_n,
-                    jnp.minimum(row_of_slot, rows - 1).reshape(n, k),
-                    (local < held_n).reshape(n, k))
+            local = idx - self.first_held
+            plan = _plan(jnp.where((local >= 0) & (local < held_n), local,
+                                   held_n), sizes, rows=rows)
             xs = _rows_out(m, plan)
         with jax.named_scope("moe_experts"):
-            h = (nn.silu(grouped_dot(xs, w1, sizes))
-                 * grouped_dot(xs, w3, sizes))
+            # w1 | w3 side by side: one product, and one gradient for xs
+            h = _gated(grouped_dot(xs, jnp.concatenate([w1, w3], axis=-1),
+                                   sizes), plan.held)
             ys = grouped_dot(h, w2, sizes)
         with jax.named_scope("moe_combine"):
-            # whatever the grouped product left in the unfilled rows is
-            # replaced BEFORE it meets a weight: masked after the
-            # product, its NaNs would reach the weights' gradient as
-            # 0 * NaN
-            w_row = weights.ravel()[first][:, None].astype(self.dtype)
-            out = _rows_back(jnp.where(plan[1][:, None], ys, 0) * w_row,
-                             plan)
+            out = _rows_back(ys, weights, plan)
         if self.shared_width:
             with jax.named_scope("moe_shared"):
                 out = out + SwiGLU(self.shared_width, dtype=self.dtype,

@@ -123,8 +123,9 @@ def test_window_and_grouped_heads_compile_at_8k(one_chip, window):
 def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
     """16 of 128 experts at d 2048 / width 1024 over 8,192 tokens,
     forward and backward: the grouped products are Pallas kernels under
-    ``moe_experts`` (three forward and their transposes), and no buffer
-    is wider than the worst case of 8 rows a token."""
+    ``moe_experts`` (two forward and their transposes), the passes over
+    the sorted buffer are loops under their scopes, and no buffer is
+    wider than the worst case of 8 rows a token."""
     from analytics_zoo_tpu.keras.layers.moe import DroplessExperts
 
     module = DroplessExperts(width=1024, n_routed=128, n_held=16, top_k=8,
@@ -148,3 +149,10 @@ def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
     assert "moe_experts/jit(tgmm)/pallas_call" in text
     assert "bf16[65536,2048]" in text
     assert "[131072," not in text
+    # (the gradient of a sum needs no forward combine)
+    for loop in ("/jvp(DroplessExperts)/moe_dispatch/jit(_spread)",
+                 "/jvp(DroplessExperts)/moe_experts/jit(_gate)",
+                 "transpose(jvp(DroplessExperts))/moe_combine/jit(_spread)",
+                 "transpose(jvp(DroplessExperts))/moe_experts/jit(_gate)",
+                 "transpose(jvp(DroplessExperts))/moe_dispatch/jit(_collect)"):
+        assert loop + "/while/body" in text, loop

@@ -329,24 +329,60 @@ def test_bias_moves_selection_and_not_the_weights():
                                rtol=2e-5)
 
 
+@jax.custom_vjp
+def _poison_back(x, filled):
+    return x
+
+
+_poison_back.defvjp(lambda x, filled: (x, filled),
+                    lambda filled, g: (jnp.where(filled, g, jnp.nan), None))
+
+
 def _poisoned(real):
-    """What the chip's grouped matmul does to the rows past the groups:
-    leaves them undefined. Here: NaN."""
+    """What the chip's grouped matmul does with the rows past the
+    groups: reads none of them, and leaves them undefined in its result
+    and in its operand's gradient. Here: NaN."""
     def grouped(x, w, sizes):
-        out = real(x, w, sizes)
-        filled = jnp.arange(out.shape[0])[:, None] < jnp.sum(sizes)
+        filled = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
+        out = real(jnp.where(filled, _poison_back(x, filled), 0), w, sizes)
         return jnp.where(filled, out, jnp.nan)
     return grouped
 
 
+def _small_tiles(monkeypatch, tile=16, block=8):
+    """The bounded passes' tile and block cut down to the tests' sizes."""
+    from analytics_zoo_tpu.keras.layers import moe
+
+    monkeypatch.setattr(moe, "BUFFER_TILE", tile)
+    monkeypatch.setattr(moe, "SEGMENT_BLOCK", block)
+    return moe
+
+
+@pytest.fixture
+def unwritten_is_nan(monkeypatch):
+    """Every buffer the bounded passes allocate starts as NaN, as an
+    unwritten one may be on the chip. The passes are jitted: their
+    traces from before are dropped first, and the poisoned ones after."""
+    from analytics_zoo_tpu.keras.layers import moe
+
+    jax.clear_caches()
+    monkeypatch.setattr(
+        moe, "_empty", lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    yield
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("lifted", [None, 3])
-def test_rows_no_assignment_fills_reach_nothing(monkeypatch, lifted):
+def test_rows_no_assignment_fills_reach_nothing(monkeypatch,
+                                                unwritten_is_nan, lifted):
     """256 tokens, 4 of 32 experts held, a balanced router and one with
     a bias on a held expert: the rows of the worst-case buffer that no
     assignment fills, NaN here as they may be on the chip, reach neither
-    the output nor any gradient."""
-    from analytics_zoo_tpu.keras.layers import moe
-
+    the output nor any gradient -- in the grouped products' results and
+    in every buffer the bounded passes allocate (the sorted rows, the
+    SwiGLU's, the token-order sums, and their gradients), which hold
+    NaN past the last tile visited."""
+    moe = _small_tiles(monkeypatch, tile=64, block=16)
     monkeypatch.setattr(moe, "grouped_dot", _poisoned(moe.grouped_dot))
     module = DroplessExperts(width=16, n_routed=32, n_held=4, first_held=2,
                              top_k=4, route_scale=2.0, shared_width=16)
@@ -381,6 +417,247 @@ def test_rows_no_assignment_fills_reach_nothing(monkeypatch, lifted):
                     jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(g)).all()
         np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+
+
+# PR 27's full-buffer forms of the two row passes and its plan from a
+# stable sort, kept as oracles for the bounded ones
+def _gather_rows(x, plan):
+    token_of_row, row_held, _, _ = plan
+    return jnp.where(row_held[:, None], x[token_of_row], 0)
+
+
+def _sum_slots(y, plan):
+    _, _, row_of_slot, slot_held = plan
+    return jnp.sum(jnp.where(slot_held[..., None], y[row_of_slot], 0),
+                   axis=1)
+
+
+def _sorted_plan(local, held_n, rows):
+    n, k = local.shape
+    flat = local.ravel()
+    order = jnp.argsort(flat, stable=True)
+    row_of_slot = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    first = order[:rows]
+    return (first // k, flat[first] < held_n,
+            jnp.minimum(row_of_slot, rows - 1).reshape(n, k),
+            (flat < held_n).reshape(n, k))
+
+
+# tokens, experts routed over, held (4..7), a token: a buffer of 192
+# rows in tiles of 16
+N_TOKENS, N_ROUTED, N_HELD, FIRST_HELD, PER_TOKEN = 48, 16, 4, 4, 4
+HELD_CASES = pytest.mark.parametrize("held", [
+    0,       # no trip: only the shared expert answers
+    1,       # one row
+    16,      # exactly one tile
+    17,      # one past a tile
+    24,      # the usual eighth of the buffer
+    192,     # every token on four held experts: the whole buffer
+])
+
+
+def _choices(held, seed=0):
+    """[n, k] expert ids, distinct in a row, ``held`` of them on the
+    held experts, spread over the tokens at random."""
+    rng = np.random.default_rng(seed)
+    per_token = np.zeros(N_TOKENS, int)
+    for _ in range(held):
+        per_token[rng.choice(np.flatnonzero(per_token < N_HELD))] += 1
+    inside = np.arange(FIRST_HELD, FIRST_HELD + N_HELD)
+    outside = np.setdiff1d(np.arange(N_ROUTED), inside)
+    return np.stack([rng.permutation(np.concatenate([
+        rng.choice(inside, c, replace=False),
+        rng.choice(outside, PER_TOKEN - c, replace=False)]))
+        for c in per_token])
+
+
+@HELD_CASES
+def test_bounded_row_passes_match_the_full_buffer_ones(monkeypatch, held):
+    """``_rows_out`` / ``_rows_back`` over a plan made without a sort
+    against PR 27's gathers over its sorted plan: values, and the three
+    gradients, on the rows that hold assignments."""
+    moe = _small_tiles(monkeypatch)
+    rows, d = N_TOKENS * N_HELD, 8
+    local = jnp.asarray(_choices(held)) - FIRST_HELD
+    local = jnp.where((local >= 0) & (local < N_HELD), local, N_HELD)
+    sizes = jnp.sum(local.ravel()[:, None] == jnp.arange(N_HELD), 0)
+    plan, oracle = moe._plan(local, sizes, rows=rows), _sorted_plan(
+        local, N_HELD, rows)
+    assert int(plan.held) == held
+    np.testing.assert_array_equal(
+        plan.slot_of_row[:held] // PER_TOKEN, oracle[0][:held])
+    ks = jax.random.split(jax.random.PRNGKey(held), 4)
+    x = jax.random.normal(ks[0], (N_TOKENS, d))
+    y = jax.random.normal(ks[1], (rows, d))
+    weights = jax.random.uniform(ks[2], (N_TOKENS, PER_TOKEN)) + 0.5
+    row_held = oracle[1][:, None]
+
+    def full_out(x):
+        return _gather_rows(x, oracle)
+
+    def full_back(y, weights):
+        w_row = weights.ravel()[plan.slot_of_row][:, None]
+        return _sum_slots(jnp.where(row_held, y, 0) * w_row, oracle)
+
+    out, pull_out = jax.vjp(lambda x: moe._rows_out(x, plan), x)
+    want_out, want_pull_out = jax.vjp(full_out, x)
+    np.testing.assert_allclose(out[:held], want_out[:held], atol=1e-6)
+    back, pull_back = jax.vjp(
+        lambda y, w: moe._rows_back(y, w, plan), y, weights)
+    want_back, want_pull_back = jax.vjp(full_back, y, weights)
+    np.testing.assert_allclose(back, want_back, atol=1e-5, rtol=1e-5)
+    # cotangents: what the rows past the last assignment hold reaches
+    # nothing, so they are zeroed on both sides
+    ct_rows = jnp.where(row_held, jax.random.normal(ks[3], (rows, d)), 0)
+    ct_tokens = jax.random.normal(ks[3], (N_TOKENS, d))
+    np.testing.assert_allclose(pull_out(ct_rows)[0],
+                               want_pull_out(ct_rows)[0],
+                               atol=1e-5, rtol=1e-5)
+    dy, dw = pull_back(ct_tokens)
+    want_dy, want_dw = want_pull_back(ct_tokens)
+    np.testing.assert_allclose(dy[:held], want_dy[:held], atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(dw, want_dw, atol=1e-5, rtol=1e-5)
+
+
+@HELD_CASES
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                       ("bfloat16", 3e-2)])
+def test_layer_matches_per_token_reference_at_every_fill(
+        monkeypatch, held, dtype, tol):
+    """The layer's output and the gradients of ``x``, ``w1`` / ``w2`` /
+    ``w3`` and the router against the plain reference (every expert on
+    every token) while the buffer holds nothing, one row, a whole tile,
+    one row more, its usual eighth, and everything; the tile is 16 rows
+    of the buffer's 192, so the passes make 0 to 12 trips."""
+    _small_tiles(monkeypatch)
+    d = 32
+    module = DroplessExperts(
+        width=24, n_routed=N_ROUTED, n_held=N_HELD, first_held=FIRST_HELD,
+        top_k=PER_TOKEN, route_scale=2.826, shared_width=24,
+        dtype=jnp.dtype(dtype))
+    chosen = _choices(held, seed=held)
+    # the router reads each token's choices off its first 16 features
+    marks = np.full((N_TOKENS, N_ROUTED), -3.0)
+    np.put_along_axis(marks, chosen, 3.0, axis=1)
+    rng = np.random.default_rng(held)
+    x = rng.normal(0, 0.5, (1, N_TOKENS, d))
+    x[0, :, :N_ROUTED] = marks + rng.normal(0, 0.3, marks.shape)
+    # what the layer computes on: x in its compute dtype
+    x = jnp.asarray(x, jnp.dtype(dtype)).astype(jnp.float32)
+    variables = module.init(jax.random.PRNGKey(0), x)
+    params = dict(variables["params"])
+    params["router"] = {"kernel": jnp.eye(d, N_ROUTED)}
+    bias = jnp.zeros((N_ROUTED,))
+    config = dict(CONFIG, first_expert_held=FIRST_HELD,
+                  num_experts_per_tok=PER_TOKEN)
+    ct = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+
+    variables = {**variables, "router_state": {"bias": bias}}
+
+    def program(params, x):
+        return module.apply({**variables, "params": params}, x)
+
+    def reference(params, x):
+        out, _ = ref.expert_layer(
+            x.reshape(-1, d), _reference_moe({"params": params}, bias),
+            config)
+        return out.reshape(x.shape)
+
+    _, state = module.apply({**variables, "params": params}, x, train=True,
+                            mutable=["router_state", "counters"])
+    counters = state["counters"]
+    assert int(counters["moe_assignments_held"]) == held
+    assert int(counters["moe_buffer_tiles_visited"]) == -(-held // 16)
+    assert int(counters["moe_buffer_tiles"]) == 12
+    with jax.default_matmul_precision("highest"):
+        # a sum of squares: a random cotangent would make each weight's
+        # gradient one cancelling bfloat16 dot product
+        got = jax.value_and_grad(
+            lambda p, x: jnp.sum(program(p, x) ** 2) / 2, (0, 1))(params, x)
+        want = jax.value_and_grad(
+            lambda p, x: jnp.sum(reference(p, x) ** 2) / 2, (0, 1))(
+                params, x)
+        np.testing.assert_allclose(
+            program(params, x), reference(params, x), atol=20 * tol,
+            rtol=20 * tol)
+    got = jax.tree_util.tree_leaves_with_path(got)
+    want = jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want) == 9   # the sum, 7 parameters, x
+    for (path, g), w in zip(got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), jax.tree_util.keystr(path)
+        assert np.linalg.norm(g - w) <= tol * np.linalg.norm(w) + 1e-6, (
+            jax.tree_util.keystr(path))
+
+
+def _not_in_a_loop(jaxpr, inside=False):
+    """(equation, under a ``while`` or a Pallas call?) for every
+    equation of ``jaxpr`` and of what it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        here = inside or eqn.primitive.name in ("while", "pallas_call")
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _not_in_a_loop(sub, here)
+
+
+def test_no_pass_over_the_whole_buffer_outside_a_loop(monkeypatch):
+    """The jaxpr of the layer's ``value_and_grad``: no gather, select,
+    multiply or add makes buffer-many (256 here, 65,536 at the published
+    widths) rows of the model's or the experts' width outside a
+    ``while`` body or a Pallas call; and every loop's trip count is
+    computed from the router's choice (the per-expert ``sizes``), not a
+    constant."""
+    _small_tiles(monkeypatch)
+    module = DroplessExperts(width=24, n_routed=16, n_held=4, first_held=4,
+                             top_k=4, route_scale=2.0, shared_width=24)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 32))
+    variables = module.init(jax.random.PRNGKey(0), x)
+    rows = 64 * 4
+
+    def loss(params, x):
+        return jnp.sum(jnp.sin(module.apply({**variables, "params": params},
+                                            x)))
+
+    closed = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(
+        variables["params"], x)
+    loops, whole = [], []
+    for eqn, inside in _not_in_a_loop(closed.jaxpr):
+        if eqn.primitive.name == "while":
+            loops.append(eqn)
+        wide = [v.aval.shape for v in eqn.outvars
+                if len(v.aval.shape) == 2 and v.aval.shape[0] >= rows
+                and v.aval.shape[1] >= 24]
+        if wide and not inside and eqn.primitive.name in (
+                "gather", "select_n", "mul", "add", "scatter-add",
+                "scatter", "logistic", "broadcast_in_dim"):
+            whole.append((eqn.primitive.name, wide))
+    assert not whole, whole
+    # dispatch, SwiGLU, combine: forward and backward
+    assert len(loops) == 6, len(loops)
+    for eqn in loops:
+        # fori_loop's carry is (i, upper, value): upper must be computed
+        n_consts = eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]
+        upper = eqn.invars[n_consts + 1]
+        assert not isinstance(upper, jax.extend.core.Literal)
+
+    # ... from the routing: with the choices cut out of the program (an
+    # eval on fixed counts) the same loops' bounds would be constants,
+    # so check the dependence by value -- more held, more trips
+    def trips(bias):
+        _, state = module.apply(
+            {**variables, "router_state": {"bias": bias}}, x, train=True,
+            mutable=["router_state", "counters"])
+        return int(state["counters"]["moe_buffer_tiles_visited"])
+
+    few = trips(jnp.zeros((16,)).at[4:8].set(-10.0))
+    many = trips(jnp.zeros((16,)).at[4:8].set(10.0))
+    assert (few, many) == (0, 16)
 
 
 def test_grouped_dot_kernel_path_in_interpret_mode(monkeypatch):
@@ -439,10 +716,30 @@ def test_model_matches_reference_in_bfloat16():
     assert abs(float(loss) - float(ref.loss(variables, x, y, config))) < 0.02
 
 
-def test_fit_updates_router_state_and_publishes_counters():
+@pytest.fixture
+def compiled_anew():
+    """The step program of the 8-device CPU mesh holds ``while`` loops
+    with collectives in their bodies (GSPMD gathers the sharded tokens
+    inside the bounded passes). Compiled, it runs; loaded from the
+    persistent compilation cache, XLA:CPU's executable deadlocks in the
+    first of them (jaxlib 0.9.0; the TPU loads its own fine). So no
+    cache here, read or written."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def test_fit_updates_router_state_and_publishes_counters(monkeypatch,
+                                                         compiled_anew):
     """Through ``compile`` / ``fit`` / ``predict``: the bias and the
     counters ride ``_step_math`` like batch statistics, and the epoch's
-    host sync publishes the counters' growth."""
+    host sync publishes the counters' growth; the buffer of 384 rows a
+    layer is cut into 6 tiles here."""
+    _small_tiles(monkeypatch, tile=64, block=16)
     _, model = _model()
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 64, (16, 17)).astype(np.int32)
@@ -454,7 +751,8 @@ def test_fit_updates_router_state_and_publishes_counters():
 
     before = {n: published(n) for n in (
         "moe_assignments", "moe_assignments_dropped", "moe_bias_steps",
-        "moe_expert_assignments", "moe_assignments_held")}
+        "moe_expert_assignments", "moe_assignments_held",
+        "moe_buffer_tiles_visited", "moe_buffer_tiles")}
     history = model.fit(({"input_ids": ids[:, :-1]}, ids[:, 1:]),
                         batch_size=8, epochs=3)
     assert history[-1]["loss"] < history[0]["loss"]
@@ -464,6 +762,12 @@ def test_fit_updates_router_state_and_publishes_counters():
     assert grown["moe_bias_steps"] == steps * layers
     assert grown["moe_assignments_dropped"] == 0
     assert grown["moe_expert_assignments"] == grown["moe_assignments_held"]
+    # one layer-step's worth a step: 6 tiles, and as many visited as
+    # the step's held assignments reach into
+    assert grown["moe_buffer_tiles"] == steps * layers * 6
+    assert (steps * layers <= grown["moe_buffer_tiles_visited"]
+            <= grown["moe_assignments_held"] // 64 + steps * layers
+            < grown["moe_buffer_tiles"])
     state = model.estimator.variables["router_state"]
     assert np.abs(np.asarray(state["layer_1"]["moe"]["bias"])).max() > 0
     logits = model.predict({"input_ids": ids[:8, :-1]}, batch_size=8)
