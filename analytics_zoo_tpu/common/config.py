@@ -23,12 +23,6 @@ _DEFAULTS: Dict[str, Any] = {
     "zoo.train.failure.retry_times": 5,          # ref: bigdl.failure.retryTimes (Topology.scala:1256)
     "zoo.train.failure.retry_interval_s": 120,   # ref: bigdl.failure.retryTimeInterval
     "zoo.train.log_every_n_steps": 50,
-    "zoo.train.donate_buffers": True,
-    # training PRNG stream (dropout masks, epoch shuffles): "auto" uses
-    # the hardware RBG generator on TPU -- threefry2x32 dropout costs
-    # ~23 ms/step on BERT-base b32/L384 v5e (MFU 0.35 -> 0.42 measured)
-    # -- and threefry elsewhere; set explicitly to pin an impl
-    "zoo.train.prng_impl": "auto",
     # mesh / parallelism axis names -- read through
     # parallel.mesh.config_axis("<role>") (a prefix-built key, so
     # grep for the wrapper, not the literal)
@@ -38,26 +32,11 @@ _DEFAULTS: Dict[str, Any] = {
     "zoo.mesh.axis.pipeline": "pipe",
     "zoo.mesh.axis.expert": "expert",
     # ops
-    # attention kernel dispatch: "auto" (flash on TPU when shapes
-    # allow, einsum otherwise), "flash", or "einsum". At short seqs
-    # (<=512) the materialized einsum path is often faster on TPU than
-    # a flash kernel at head_dim 64; auto picks per shape.
-    "zoo.ops.attention_impl": "auto",
-    # seq length at/below which auto prefers the einsum path (scores
-    # fit HBM comfortably and XLA's batched matmuls beat the blockwise
-    # kernel's VPU overhead at these sizes)
-    "zoo.ops.attention_flash_min_seq": 512,
     # causal ring-attention schedule: "zigzag" balances causal load
     # over the ring (~2x less compute), "contiguous" is the classic
     # layout; "auto" picks zigzag for causal when shapes divide
     "zoo.ops.ring_schedule": "auto",
     # data layer
-    # image-backbone BN statistics rows: 0 = exact full-batch stats;
-    # K > 0 computes train-time BN stats over the first K batch rows
-    # (the stat reduce is a pure HBM-bandwidth pass -- 31% of the r4
-    # ResNet-50 step; see SampledBatchNorm)
-    "zoo.models.bn_stat_rows": 0,
-
     "zoo.data.prefetch_buffer": 2,
     "zoo.data.check_batch_divisible": True,      # ref: tf_dataset.py:142-147 batch % cores == 0
     # serving
@@ -309,18 +288,12 @@ _SPECS: Dict[str, tuple] = {
     "zoo.train.failure.retry_times": ("int", 0, None),
     "zoo.train.failure.retry_interval_s": ("float", 0, None),
     "zoo.train.log_every_n_steps": ("int", 1, None),
-    "zoo.train.donate_buffers": ("bool",),
-    "zoo.train.prng_impl": ("str",),   # "auto"/"rbg"/"threefry2x32"/
-                                       # any jax.random.key impl name
     "zoo.mesh.axis.data": ("str",),
     "zoo.mesh.axis.model": ("str",),
     "zoo.mesh.axis.sequence": ("str",),
     "zoo.mesh.axis.pipeline": ("str",),
     "zoo.mesh.axis.expert": ("str",),
-    "zoo.ops.attention_impl": ("enum", "auto", "flash", "einsum"),
-    "zoo.ops.attention_flash_min_seq": ("int", 0, None),
     "zoo.ops.ring_schedule": ("enum", "auto", "zigzag", "contiguous"),
-    "zoo.models.bn_stat_rows": ("int", 0, None),
     "zoo.data.prefetch_buffer": ("int", 0, None),
     "zoo.data.check_batch_divisible": ("bool",),
     "zoo.serving.batch_size": ("int", 1, None),

@@ -3,89 +3,18 @@ zoo/.../keras/layers/internal LayerNorm used by Transformer/BERT)."""
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from functools import partial
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.keras.layers.base import FnModule, KerasLayer
 
 
-class SampledBatchNorm(nn.Module):
-    """BatchNorm whose TRAIN-time statistics come from the first
-    ``stat_rows`` batch rows (0 = whole batch, exact nn.BatchNorm
-    semantics).
-
-    Why: on TPU the batch-statistics reduce is a pure-HBM-bandwidth
-    pass over every activation map -- the r4 ResNet-50 device trace
-    put it at 31% of step time (BENCH_NOTES.md). Sampling the stats
-    over K of B rows cuts that pass's traffic B/K-fold while every
-    row is still normalized (the normalize pass is unchanged). The
-    estimate is noisier -- statistically the same trade as training
-    with batch K for BN purposes (ghost-batch-norm territory, known
-    to be mildly regularizing) -- so it is strictly OPT-IN:
-    ``zoo.models.bn_stat_rows`` routes the image backbones here, and
-    the default (0) keeps exact full-batch statistics.
-
-    Inference (``use_running_average=True``) is identical to
-    nn.BatchNorm: running stats, updated with the same momentum EMA.
-    """
-
-    use_running_average: bool = False
-    momentum: float = 0.9
-    epsilon: float = 1e-3
-    dtype: Optional[Any] = None
-    stat_rows: int = 0
-    scale_init: Any = nn.initializers.ones
-    bias_init: Any = nn.initializers.zeros
-
-    @nn.compact
-    def __call__(self, x):
-        feat = x.shape[-1]
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros(feat, jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones(feat, jnp.float32))
-        scale = self.param("scale", self.scale_init, (feat,))
-        bias = self.param("bias", self.bias_init, (feat,))
-        if self.use_running_average:
-            mean, var = ra_mean.value, ra_var.value
-        else:
-            k = self.stat_rows
-            xs = x if k <= 0 or k >= x.shape[0] else x[:k]
-            xf = xs.astype(jnp.float32)
-            axes = tuple(range(xf.ndim - 1))
-            mean = jnp.mean(xf, axes)
-            # E[x^2] - E[x]^2: both reduces share one input pass (XLA
-            # multi-output fusion), vs the two-pass (x - mean)^2 form
-            var = jnp.maximum(
-                jnp.mean(jnp.square(xf), axes) - jnp.square(mean), 0.0)
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * mean
-                ra_var.value = m * ra_var.value + (1 - m) * var
-        dt = self.dtype or x.dtype
-        inv = jax.lax.rsqrt(var + self.epsilon) * scale
-        return (x.astype(dt) * inv.astype(dt)
-                + (bias - mean * inv).astype(dt))
-
-
 def batch_norm(train: bool, dtype, momentum: float = 0.9,
                epsilon: float = 1e-3):
-    """The backbone BN factory: flax ``nn.BatchNorm`` by default, or
-    :class:`SampledBatchNorm` when ``zoo.models.bn_stat_rows`` is set
-    (opt-in stat sampling -- see the class docstring). Read at TRACE
-    time, like the ``zoo.ops`` kernel-dispatch keys."""
-    from functools import partial
-
-    from analytics_zoo_tpu.common.config import get_config
-
-    rows = int(get_config().get("zoo.models.bn_stat_rows", 0) or 0)
-    if rows > 0:
-        return partial(SampledBatchNorm, use_running_average=not train,
-                       momentum=momentum, epsilon=epsilon, dtype=dtype,
-                       stat_rows=rows)
+    """The backbones' BN factory: the one home of their momentum and
+    epsilon (flax ``nn.BatchNorm``, exact full-batch statistics)."""
     return partial(nn.BatchNorm, use_running_average=not train,
                    momentum=momentum, epsilon=epsilon, dtype=dtype)
 

@@ -55,18 +55,13 @@ _M_EPOCHS = _REG.counter(
 
 def training_prng_key(seed: int):
     """PRNG key for the training stream (dropout masks, on-device epoch
-    shuffles), with the implementation chosen by ``zoo.train.prng_impl``.
-    "auto" picks the hardware RBG generator on TPU: threefry2x32 dropout
-    mask generation costs ~23 ms/step on BERT-base (b32, L384, v5e)
-    where RBG is near-free; elsewhere auto keeps the default threefry
-    stream so CPU runs stay bit-reproducible across jax versions."""
-    impl = get_config().get("zoo.train.prng_impl")
-    if impl == "auto":
-        impl = ("rbg" if jax.devices()[0].platform == "tpu"
-                else "threefry2x32")
-    if impl in (None, "", "threefry2x32", "default"):
-        return jax.random.PRNGKey(seed)
-    return jax.random.key(seed, impl=impl)
+    shuffles): the hardware RBG generator on the TPU, where threefry2x32
+    dropout mask generation costs ~23 ms/step on BERT-base (b32, L384,
+    v5e) and RBG is near-free; elsewhere the default threefry stream,
+    so CPU runs stay bit-reproducible across jax versions."""
+    if jax.devices()[0].platform == "tpu":
+        return jax.random.key(seed, impl="rbg")
+    return jax.random.PRNGKey(seed)
 
 
 def _as_dataset(data, labeled: bool = True) -> ZooDataset:
@@ -417,7 +412,6 @@ class Estimator:
             return self._train_step
         if self.loss_fn is None:
             raise ValueError("Estimator needs a loss to train")
-        donate = get_config().get("zoo.train.donate_buffers")
 
         def step(variables, opt_state, loss_sum, x, y, rng):
             variables, opt_state, loss = self._step_math(
@@ -433,7 +427,7 @@ class Estimator:
         # recompile-storm detector (a fit() whose batches keep changing
         # shape recompiles every step and warns instead of crawling)
         self._train_step = instrument_compiles(
-            jax.jit(step, donate_argnums=(0, 1, 2) if donate else ()),
+            jax.jit(step, donate_argnums=(0, 1, 2)),
             "estimator.train_step", subsystem="learn")
         return self._train_step
 
@@ -479,9 +473,8 @@ class Estimator:
                 0, n_steps, body, init)
             return variables, opt_state, loss_sum / n_steps
 
-        donate = get_config().get("zoo.train.donate_buffers")
         return instrument_compiles(
-            jax.jit(epoch, donate_argnums=(0, 1) if donate else ()),
+            jax.jit(epoch, donate_argnums=(0, 1)),
             "estimator.epoch", subsystem="learn")
 
     def _eval_metrics(self) -> List[Metric]:

@@ -217,7 +217,6 @@ class PopulationEstimator:
     def _build_train_step(self):
         if self._train_step is not None:
             return self._train_step
-        donate = get_config().get("zoo.train.donate_buffers")
         stepv = jax.vmap(self._member_step)
 
         def step(variables, opt_state, x, y, rngs, lr, wd, mask):
@@ -225,7 +224,7 @@ class PopulationEstimator:
                          mask)
 
         self._train_step = instrument_compiles(
-            jax.jit(step, donate_argnums=(0, 1) if donate else ()),
+            jax.jit(step, donate_argnums=(0, 1)),
             "population.train_step", subsystem="learn")
         return self._train_step
 

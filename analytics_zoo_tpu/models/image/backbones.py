@@ -24,13 +24,8 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-
-def _norm(train: bool, dtype):
-    # config-aware BN factory: exact nn.BatchNorm, or opt-in sampled
-    # statistics via zoo.models.bn_stat_rows (see SampledBatchNorm)
-    from analytics_zoo_tpu.keras.layers.normalization import batch_norm
-
-    return batch_norm(train, dtype, momentum=0.9, epsilon=1e-3)
+from analytics_zoo_tpu.keras.layers.normalization import (
+    batch_norm as _norm)
 
 
 class InceptionBlock(nn.Module):

@@ -1,14 +1,14 @@
 """Attention dispatch. One entry point, ``dot_product_attention``, and
-four paths; the one taken names itself in ``op_name``:
+four paths, chosen by ``attention_path`` from the platform and the
+call's shapes alone; the one taken names itself in ``op_name``:
 
 - ``attention_flash`` -- the framework's own Pallas kernels
   (``pallas_attention.pallas_flash_attention_fwd``, exact custom_vjp):
   off the CPU, no mask or dropout, both lengths multiples of 128,
-  head_dim a multiple of 64, and either ``zoo.ops.attention_impl`` set
-  to it or a sequence longer than ``zoo.ops.attention_flash_min_seq``.
-  The only path that serves a causal ``window`` and K/V with fewer
-  heads than Q without materialising either (docs/kernels.md); a
-  window call is named ``attention_flash_window``;
+  head_dim a multiple of 64, and a sequence longer than
+  ``FLASH_MIN_SEQ``. The only path that serves a causal ``window``
+  and K/V with fewer heads than Q without materialising either
+  (docs/kernels.md); a window call is named ``attention_flash_window``;
 - ``attention_stock_pallas`` -- JAX's fused fwd+bwd kernel: the same
   conditions with head_dim <= 128, for key-padding masks (lowered to
   segment ids) and head sizes the owned kernel refuses;
@@ -58,9 +58,12 @@ def _causal_keep(lq: int, lk: int, window: Optional[int]):
 
 def reference_attention(q, k, v, mask=None, causal: bool = False,
                         scale: Optional[float] = None,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        dropout_rate: float = 0.0, dropout_rng=None):
     """Exact jnp attention; the single source of truth the Pallas kernels
-    are tested against and the custom_vjp backward recomputes through."""
+    are tested against and the custom_vjp backward recomputes through.
+    With a ``dropout_rng``, attention dropout on the materialised
+    probabilities (no kernel supports it)."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     k, v = _repeat_kv(q, k, v)
@@ -71,6 +74,10 @@ def reference_attention(q, k, v, mask=None, causal: bool = False,
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
+    if dropout_rate > 0.0 and dropout_rng is not None:
+        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
+                                    probs.shape)
+        probs = probs * keep / (1.0 - dropout_rate)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
@@ -104,6 +111,41 @@ def _platform(q) -> str:
     return jax.default_backend()
 
 
+# max(Lq, Lk) up to which the [L, L] scores in HBM are cheaper than the
+# blockwise kernels. Measured on the v5e (docs/kernels.md "Measured
+# crossover": h12 d64 bf16, forward + backward): the owned kernel ties
+# einsum at L384 (7.85 against 7.92 ms), the two trade places at L512
+# from one window to the next, and flash wins 1.37x at L1024; so the
+# gate is inclusive. The benchmark has a cell on each side: BERT at
+# L384 (einsum) and Trinity-Mini at L8192 (flash).
+FLASH_MIN_SEQ = 512
+
+
+def attention_path(platform: str, lq: int, lk: int, head_dim: int,
+                   q_heads: int, kv_heads: int, *, mask: bool = False,
+                   key_padding_mask: bool = False, dropout: bool = False,
+                   causal: bool = False, window: bool = False) -> str:
+    """Which path serves a call: ``flash``, ``stock_pallas``,
+    ``einsum`` or ``reference``. A pure function of what the call site
+    can observe: the platform, the shapes, and which of a 4-D mask, a
+    key-padding mask, dropout, ``causal`` and a window are present."""
+    # the einsum path is the CPU's; any other platform compiles the
+    # kernels (or fails loudly), whatever it calls itself
+    kernels = (platform != "cpu" and max(lq, lk) > FLASH_MIN_SEQ
+               and lq % 128 == 0 and lk % 128 == 0
+               and not mask and not dropout)
+    if kernels and head_dim % 64 == 0 and not key_padding_mask:
+        return "flash"
+    # padding masks ride the stock kernel's segment ids. Its causal mask
+    # is top-left aligned (no cross-length offset), so it only agrees
+    # with reference_attention when lq == lk; it knows neither a window
+    # nor grouped heads
+    if (kernels and head_dim <= 128 and (not causal or lq == lk)
+            and not window and kv_heads == q_heads):
+        return "stock_pallas"
+    return "reference" if dropout else "einsum"
+
+
 def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
                           causal: bool = False,
                           scale: Optional[float] = None,
@@ -117,10 +159,11 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
     ``causal``): row i reads only keys ``i - window < j <= i``.
     Returns [B, H, Lq, D].
 
-    The path taken names itself in every op's ``op_name`` (and so in a
-    device trace): ``attention_flash`` (this repo's Pallas kernel),
-    ``attention_stock_pallas``, ``attention_einsum`` or
-    ``attention_reference``; a window call adds ``_window``."""
+    The path taken (``attention_path``) names itself in every op's
+    ``op_name`` (and so in a device trace): ``attention_flash`` (this
+    repo's Pallas kernel), ``attention_stock_pallas``,
+    ``attention_einsum`` or ``attention_reference``; a window call adds
+    ``_window``."""
     d = q.shape[-1]
     l, lk = q.shape[2], k.shape[2]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -130,38 +173,22 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
         raise ValueError("causal attention requires len(q) <= len(kv)")
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
-    kind = "" if window is None else "_window"
 
-    from analytics_zoo_tpu.common.config import get_config
-
-    cfg = get_config()
-    impl = cfg.get("zoo.ops.attention_impl")
-    if impl == "auto" and max(l, lk) <= int(
-            cfg.get("zoo.ops.attention_flash_min_seq")):
-        # short sequences: the [L, L] scores are small enough that
-        # XLA's fused batched-matmul attention beats the blockwise
-        # kernels (measured ~2x on v5e at BERT-base L=384/d=64)
-        impl = "einsum"
-    # the einsum path is the CPU's; any other platform compiles the
-    # kernels (or fails loudly), whatever it calls itself
-    flash_ok = (impl != "einsum"
-                and mask is None and dropout_rate == 0.0
-                and _platform(q) != "cpu"
-                and l % 128 == 0 and lk % 128 == 0)
-    if flash_ok and d % 64 == 0:
+    path = attention_path(
+        _platform(q), l, lk, d, q.shape[1], k.shape[1],
+        mask=mask is not None, key_padding_mask=key_padding_mask is not None,
+        dropout=dropout_rate != 0.0, causal=causal,
+        window=window is not None)
+    scope = jax.named_scope(
+        f"attention_{path}" + ("" if window is None else "_window"))
+    if path == "flash":
         from analytics_zoo_tpu.ops.pallas_attention import (
             pallas_flash_attention_fwd)
 
-        if key_padding_mask is None:
-            with jax.named_scope("attention_flash" + kind):
-                return pallas_flash_attention_fwd(q, k, v, causal, scale,
-                                                  None, None, window)
-        # padding masks fall through to the stock kernel's segment ids
-    # the stock kernel's causal mask is top-left aligned (no cross-length
-    # offset), so it only agrees with reference_attention when lq == lk;
-    # it knows neither a window nor grouped heads
-    if (flash_ok and d <= 128 and (not causal or l == lk)
-            and window is None and k.shape[1] == q.shape[1]):
+        with scope:
+            return pallas_flash_attention_fwd(q, k, v, causal, scale,
+                                              None, None, window)
+    if path == "stock_pallas":
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             SegmentIds, flash_attention)
 
@@ -171,32 +198,17 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
             q_seg = (kv_seg if lk == l
                      else jnp.ones((q.shape[0], l), jnp.int32))
             seg = SegmentIds(q=q_seg, kv=kv_seg)
-        with jax.named_scope("attention_stock_pallas"):
+        with scope:
             return flash_attention(q, k, v, segment_ids=seg, causal=causal,
                                    sm_scale=scale)
-
     if key_padding_mask is not None:
         pm = key_padding_mask[:, None, None, :].astype(bool)
         mask = pm if mask is None else (mask.astype(bool) & pm)
-    if dropout_rate == 0.0:
-        with jax.named_scope("attention_einsum" + kind):
+    with scope:
+        if path == "einsum":
             return _einsum_attention(q, k, v, mask=mask, causal=causal,
                                      scale=scale, window=window)
-    with jax.named_scope("attention_reference" + kind):
-        if dropout_rate > 0.0 and dropout_rng is not None:
-            # dropout needs the materialized probs; inline the reference
-            # math
-            k, v = _repeat_kv(q, k, v)
-            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-            if causal:
-                cm = _causal_keep(l, lk, window)
-                logits = jnp.where(cm[None, None], logits, NEG_INF)
-            if mask is not None:
-                logits = jnp.where(mask.astype(bool), logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1)
-            keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
-                                        probs.shape)
-            probs = probs * keep / (1.0 - dropout_rate)
-            return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
         return reference_attention(q, k, v, mask=mask, causal=causal,
-                                   scale=scale, window=window)
+                                   scale=scale, window=window,
+                                   dropout_rate=dropout_rate,
+                                   dropout_rng=dropout_rng)

@@ -368,7 +368,7 @@ def _bwd_cap(length: int, d: int) -> int:
     """Backward block cap: 512 keeps the three [BQ, BK] f32
     intermediates inside VMEM at d=128; at d <= 64 every q/k/v/do tile
     halves, so 1024-blocks fit AND measure 6-7% faster at L >= 2048
-    (scripts/perf_flash_blocks.py) -- but only when the sequential
+    (docs/kernels.md "Measured crossover") -- but only when the sequential
     grid dim keeps >= 2 steps, else Mosaic has nothing to pipeline
     and L=1024 regresses ~25%."""
     return 1024 if (d <= 64 and length >= 2048) else 512

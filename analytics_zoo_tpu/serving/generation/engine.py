@@ -139,8 +139,7 @@ class DecodeEngine:
         # keep the input alive -- one full-pool copy per generated
         # token and 2x peak HBM on the dominant allocation. (On CPU
         # donation is ignored with a one-time warning; the estimator's
-        # train step uses the same pattern under
-        # zoo.train.donate_buffers.)
+        # train step donates its state the same way.)
         self._prefill_jit = jax.jit(self._prefill_impl,
                                     donate_argnums=(1,))
         self._step_jit = jax.jit(self._step_impl, donate_argnums=(1,))

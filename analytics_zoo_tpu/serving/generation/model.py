@@ -126,7 +126,7 @@ class TinyGenLM:
         [layers, B, L, heads, head_dim] -- the cache chunks the engine
         scatters into the page pool. Attention routes through the ops
         dispatcher, so TPU prefill rides the owned causal Pallas flash
-        kernel when shapes allow (``zoo.ops.attention_impl``)."""
+        kernel when shapes allow (``ops.attention.attention_path``)."""
         from analytics_zoo_tpu.ops.attention import (
             dot_product_attention)
 

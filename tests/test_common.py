@@ -41,9 +41,24 @@ class TestConfig:
         assert conf.get("zoo.train.log_every_n_steps") == 99
 
     def test_coercion(self, monkeypatch):
-        monkeypatch.setenv("AZT_ZOO_TRAIN_DONATE_BUFFERS", "false")
+        monkeypatch.setenv("AZT_ZOO_DATA_CHECK_BATCH_DIVISIBLE", "false")
         conf = ZooConfig(conf_file="")
-        assert conf.get("zoo.train.donate_buffers") is False
+        assert conf.get("zoo.data.check_batch_divisible") is False
+
+    @pytest.mark.parametrize("key", [
+        "zoo.ops.attention_impl", "zoo.ops.attention_flash_min_seq",
+        "zoo.models.bn_stat_rows", "zoo.train.donate_buffers",
+        "zoo.train.prng_impl"])
+    def test_no_key_turns_the_compiled_step(self, key):
+        """The train step is a function of the model, the shapes and
+        the platform (PR 29): these five were read while it was traced,
+        so setting one after the first ``fit`` silently did nothing."""
+        assert key not in config_mod._DEFAULTS
+        assert key not in config_mod._SPECS
+        docs = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "docs", "runtime.md")
+        with open(docs) as f:
+            assert key not in f.read()
 
 
 class TestContext:

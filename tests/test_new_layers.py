@@ -267,76 +267,3 @@ class TestTableOps:
         preds = model.predict(x, batch_size=8)
         want = np.einsum("bik,bjk->bij", x[:, :, :3], x[:, :, 3:])
         np.testing.assert_allclose(preds, want, rtol=1e-4, atol=1e-5)
-
-
-class TestSampledBatchNorm:
-    """Opt-in sampled BN statistics (zoo.models.bn_stat_rows): exact
-    nn.BatchNorm semantics at stat_rows=0, K-row stats otherwise."""
-
-    def _x(self, b=16, seed=0):
-        rng = np.random.RandomState(seed)
-        return jnp.asarray(rng.randn(b, 4, 4, 8) * 2 + 1, jnp.float32)
-
-    def test_zero_rows_matches_flax_batchnorm(self):
-        import flax.linen as nn
-        from analytics_zoo_tpu.keras.layers.normalization import (
-            SampledBatchNorm)
-
-        x = self._x()
-        ref = nn.BatchNorm(use_running_average=False, momentum=0.9,
-                           epsilon=1e-3)
-        ours = SampledBatchNorm(use_running_average=False, momentum=0.9,
-                                epsilon=1e-3, stat_rows=0)
-        vr = ref.init(jax.random.PRNGKey(0), x)
-        vo = ours.init(jax.random.PRNGKey(0), x)
-        yr, sr = ref.apply(vr, x, mutable=["batch_stats"])
-        yo, so = ours.apply(vo, x, mutable=["batch_stats"])
-        np.testing.assert_allclose(np.asarray(yo), np.asarray(yr),
-                                   rtol=1e-4, atol=1e-5)
-        for k in ("mean", "var"):
-            np.testing.assert_allclose(
-                np.asarray(so["batch_stats"][k]).ravel(),
-                np.asarray(sr["batch_stats"][k]).ravel(),
-                rtol=1e-4, atol=1e-5)
-        # inference path uses running stats identically
-        ref_eval = nn.BatchNorm(use_running_average=True, momentum=0.9,
-                                epsilon=1e-3)
-        yr2 = ref_eval.apply({**vr, **sr}, x)
-        ours_eval = SampledBatchNorm(use_running_average=True,
-                                     momentum=0.9, epsilon=1e-3)
-        yo2 = ours_eval.apply({**vo, **so}, x)
-        np.testing.assert_allclose(np.asarray(yo2), np.asarray(yr2),
-                                   rtol=1e-4, atol=1e-5)
-
-    def test_sampled_rows_use_prefix_stats(self):
-        from analytics_zoo_tpu.keras.layers.normalization import (
-            SampledBatchNorm)
-
-        x = self._x(b=16, seed=1)
-        k = 4
-        m = SampledBatchNorm(use_running_average=False, stat_rows=k,
-                             epsilon=1e-3)
-        v = m.init(jax.random.PRNGKey(0), x)
-        y, _ = m.apply(v, x, mutable=["batch_stats"])
-        xs = np.asarray(x[:k], np.float64)
-        mean = xs.mean(axis=(0, 1, 2))
-        var = xs.var(axis=(0, 1, 2))
-        want = (np.asarray(x) - mean) / np.sqrt(var + 1e-3)
-        np.testing.assert_allclose(np.asarray(y), want, rtol=1e-3,
-                                   atol=1e-3)
-
-    def test_backbone_norm_routes_by_config(self):
-        from analytics_zoo_tpu.common.config import get_config
-        from analytics_zoo_tpu.keras.layers.normalization import (
-            SampledBatchNorm)
-        from analytics_zoo_tpu.models.image.backbones import _norm
-
-        cfg = get_config()
-        try:
-            cfg.set("zoo.models.bn_stat_rows", 8)
-            assert _norm(True, jnp.float32).func is SampledBatchNorm
-            cfg.set("zoo.models.bn_stat_rows", 0)
-            import flax.linen as nn
-            assert _norm(True, jnp.float32).func is nn.BatchNorm
-        finally:
-            cfg.set("zoo.models.bn_stat_rows", 0)

@@ -161,6 +161,71 @@ def test_attention_path_names_itself(monkeypatch, scope, on_tpu, length,
     assert found & set(ATTENTION_SCOPES) == {scope}
 
 
+# The rule itself, as a table. Expected paths are read off the parent's
+# dispatcher under its default, ``auto``, setting (156435e,
+# ``ops/attention.py:139-164``), not off ``attention_path``. Columns:
+# platform, Lq, Lk, head_dim, query heads, KV heads, what is present.
+@pytest.mark.parametrize("path,platform,lq,lk,d,hq,hkv,present", [
+    # the gate is inclusive: 512 is still the einsum's
+    ("einsum", "tpu", 512, 512, 64, 12, 12, ()),
+    ("flash", "tpu", 640, 640, 64, 12, 12, ()),
+    # the longer side decides
+    ("flash", "tpu", 128, 1024, 64, 12, 12, ()),
+    # the owned kernel is bottom-right aligned: cross-length causal is its own
+    ("flash", "tpu", 512, 1024, 128, 8, 8, ("causal",)),
+    # Trinity-Mini's two layer kinds at 8k
+    ("flash", "tpu", 8192, 8192, 128, 32, 4, ("causal", "window")),
+    ("flash", "tpu", 8192, 8192, 128, 32, 4, ("causal",)),
+    # no kernel takes a length that is not a multiple of 128
+    ("einsum", "tpu", 1000, 1000, 64, 12, 12, ()),
+    ("einsum", "tpu", 1024, 1000, 64, 12, 12, ()),
+    # head sizes the owned kernel refuses: the stock one up to 128
+    ("stock_pallas", "tpu", 1024, 1024, 96, 12, 12, ()),
+    ("einsum", "tpu", 1024, 1024, 160, 12, 12, ()),
+    # key-padding masks ride the stock kernel's segment ids, which know
+    # no grouped heads, no cross-length causal offset and no window
+    ("stock_pallas", "tpu", 1024, 1024, 64, 12, 12,
+     ("key_padding_mask", "causal")),
+    ("einsum", "tpu", 1024, 1024, 64, 8, 2, ("key_padding_mask",)),
+    ("einsum", "tpu", 512, 1024, 64, 12, 12, ("key_padding_mask", "causal")),
+    ("einsum", "tpu", 1024, 1024, 64, 12, 12,
+     ("key_padding_mask", "causal", "window")),
+    # an arbitrary mask materialises the scores at any length
+    ("einsum", "tpu", 8192, 8192, 128, 32, 4, ("mask",)),
+    # dropout is the reference's, with or without an rng, on any platform
+    ("reference", "tpu", 1024, 1024, 64, 12, 12, ("dropout",)),
+    ("reference", "cpu", 128, 128, 64, 12, 12, ("dropout",)),
+    # the CPU compiles no kernel, whatever the shape
+    ("einsum", "cpu", 8192, 8192, 128, 32, 4, ("causal", "window")),
+    ("einsum", "cpu", 1024, 1024, 64, 12, 12, ("key_padding_mask",)),
+])
+def test_attention_path_rule(path, platform, lq, lk, d, hq, hkv, present):
+    assert attention.attention_path(
+        platform, lq, lk, d, hq, hkv,
+        **{name: True for name in present}) == path
+
+
+def test_ops_import_nothing_from_common():
+    """``ops/`` is the lowest layer: what a kernel's dispatch needs it
+    is handed by the caller or reads off the shapes, never out of the
+    process-wide config."""
+    import ast
+    import pathlib
+
+    ops = pathlib.Path(attention.__file__).parent
+    for path in sorted(ops.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            names = []
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [node.module or ""] + [
+                    f"{node.module}.{a.name}" for a in node.names]
+            assert not any(
+                n.startswith("analytics_zoo_tpu.common") for n in names), (
+                f"{path.name}:{node.lineno} imports {names}")
+
+
 # ------------------------------------------------------------------ #
 # the sparse decoder's scopes, and what this must not move           #
 # ------------------------------------------------------------------ #
@@ -237,3 +302,55 @@ def test_bert_step_program_is_what_it_was(fresh_compiles):
     assert "attention_einsum" in text
     assert hashlib.sha256(_strip_metadata(text).encode()).hexdigest() \
         == BERT_STEP[1]
+
+
+# The same pin for the two other programs the benchmark's cells run,
+# both recorded on the parent of PR 29 (156435e), before the attention
+# dispatcher lost its config keys and ``batch_norm()`` its sampled
+# branch: an image classifier whose backbone goes through
+# ``batch_norm()``, and the sparse decoder's step (window and full
+# attention over grouped KV heads, the dropless expert layer).
+RESNET_STEP = ("0.9.0", "730eda3c954f4d916e67046572c6f2e1"
+                        "a565c6751a7c592f64331af8971379fe")
+DECODER_STEP = ("0.9.0", "12e3a18fcbce696e4fd44390abd4da20"
+                         "3017628d46f8bfd6cbe167c180cc775c")
+
+
+def _stripped_sha256(hlo_text: str) -> str:
+    """Of the stripped text with every ``%name.N`` replaced by its rank
+    of first appearance: XLA:CPU numbers a large module's instructions
+    differently from one compile to the next (ResNet-18's step read
+    ``%convert.660`` or ``%convert.662``), the program being the same."""
+    import hashlib
+
+    ranks = {}
+    text = re.sub(r"%[\w.\-]+",
+                  lambda m: ranks.setdefault(m.group(), f"%{len(ranks)}"),
+                  _strip_metadata(hlo_text))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_resnet_step_program_is_what_it_was(fresh_compiles):
+    from analytics_zoo_tpu.models import ImageClassifier
+
+    if jax.__version__ != RESNET_STEP[0]:
+        pytest.skip(f"recorded with jax {RESNET_STEP[0]}")
+    model = ImageClassifier(class_num=4, backbone="resnet18", image_size=32,
+                            dtype="bfloat16")
+    model.compile(optimizer=optax.sgd(0.1, momentum=0.9))
+    est = model.estimator
+    x = np.zeros((4, 32, 32, 3), np.uint8)
+    est._ensure_built(x)
+    text = jax.jit(lambda *args: est._step_math(*args)).lower(
+        est.variables, est.opt_state, x, np.zeros((4,), np.int32),
+        jax.random.PRNGKey(0)).compile().as_text()
+    assert "BatchNorm" in text
+    assert _stripped_sha256(text) == RESNET_STEP[1]
+
+
+def test_decoder_step_program_is_what_it_was(fresh_compiles):
+    if jax.__version__ != DECODER_STEP[0]:
+        pytest.skip(f"recorded with jax {DECODER_STEP[0]}")
+    text = _decoder_step_text()
+    assert "attention_einsum_window" in text
+    assert _stripped_sha256(text) == DECODER_STEP[1]

@@ -1132,7 +1132,7 @@ _SPECS = {
                 "zoo.serving.pipeline.depth", 0)
         with pytest.raises(ValueError):
             cfg_mod.validate_config_value(
-                "zoo.ops.attention_impl", "turbo")
+                "zoo.ops.ring_schedule", "turbo")
 
 
 # ===================================================================== #
