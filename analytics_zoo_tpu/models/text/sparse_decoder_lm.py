@@ -9,7 +9,13 @@ layers and then routed experts -- trained on the next token.
 The vocabulary and the experts may be one chip's share of a larger
 deployment: ``vocab`` rows of the table and the head, ``n_held`` of
 ``n_routed`` experts (``keras/layers/moe.DroplessExperts``). Each layer
-is rematerialised for the backward pass: only its input is kept.
+is rematerialised for the backward pass, which keeps its input and the
+two results of the flash attention kernel (its output and its
+logsumexp, 201 MB a layer at [1, 32, 8192, 128]): the second forward
+runs the projections, the norms and the expert layer again, and no
+attention kernel. On the paths that hold [L, L] scores (the CPU's,
+short sequences) nothing carries those names and only the input is
+kept.
 """
 
 from __future__ import annotations
@@ -24,6 +30,8 @@ import numpy as np
 from analytics_zoo_tpu.keras.layers.sparse_decoder import (
     RMSNorm, SLIDING, SparseDecoderLayer)
 from analytics_zoo_tpu.models.common import ZooModel, register_model
+from analytics_zoo_tpu.ops.pallas_attention import (
+    FLASH_LSE_NAME, FLASH_OUT_NAME)
 
 
 def next_token_loss(logits, labels):
@@ -72,8 +80,13 @@ class SparseDecoderModule(nn.Module):
             n_held=self.n_held, first_held=self.first_held,
             top_k=self.top_k, route_scale=self.route_scale,
             shared_width=self.shared_width, bias_step=self.bias_step)
-        # the layer's input is all the backward pass keeps of it
-        layer = nn.remat(SparseDecoderLayer, static_argnums=(2,))
+        # the backward pass keeps the layer's input and, where the
+        # flash kernel ran, its output and logsumexp (the names exist
+        # on no other attention path); all else is computed again
+        layer = nn.remat(
+            SparseDecoderLayer, static_argnums=(2,),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                FLASH_OUT_NAME, FLASH_LSE_NAME))
         for i, kind in enumerate(self.layer_types):
             h = layer(
                 kind=kind, n_head=self.n_head, n_kv_head=self.n_kv_head,

@@ -53,6 +53,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -474,10 +475,23 @@ def _resolve_scale(scale, q) -> float:
     return scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
 
 
+# The forward kernel's two results, named for ``jax.checkpoint``
+# policies (``save_only_these_names``): a caller that rematerialises the
+# layer round this call keeps them, and its second forward then holds no
+# attention kernel (``models/text/sparse_decoder_lm.py``). Outside such
+# a policy a name is the identity.
+FLASH_OUT_NAME = "flash_attention_out"
+FLASH_LSE_NAME = "flash_attention_lse"
+
+
 def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, window):
     s = _resolve_scale(scale, q)
     out, lse = _flash_fwd(q, k, v, causal, s, block_q, block_k,
                           with_lse=True, window=window)
+    out = checkpoint_name(out, FLASH_OUT_NAME)
+    # in the kernel's lanes layout: one value a row would cost a slice
+    # here and a broadcast in _flash_bwd for little memory (docs/kernels.md)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, out, lse, s)
 
 

@@ -56,6 +56,13 @@ def _scalar(out):
     return jnp.sum(out.astype(jnp.float32))
 
 
+def _shapes_on(tree, sharding):
+    """The tree's leaves as shapes placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
 @pytest.mark.parametrize("shape,causal", [
     ((48, 12, 384, 64), False),    # BERT-base b48 / L384
     ((4, 12, 2048, 64), True),     # long context, d64
@@ -135,9 +142,7 @@ def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
                              sharding=one_chip)
     variables = jax.eval_shape(module.init, jax.random.PRNGKey(0),
                                jnp.zeros((1, 128, 2048), jnp.bfloat16))
-    variables = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        variables)
+    variables = _shapes_on(variables, one_chip)
 
     def loss(params, state, x):
         return _scalar(module.apply({"params": params, **state}, x))
@@ -156,3 +161,36 @@ def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
                  "transpose(jvp(DroplessExperts))/moe_experts/jit(_gate)",
                  "transpose(jvp(DroplessExperts))/moe_dispatch/jit(_collect)"):
         assert loop + "/while/body" in text, loop
+
+
+def test_decoder_layers_backward_holds_three_kernels_a_layer(one_chip):
+    """A window and a full layer of the sparse decoder at the published
+    attention widths (32 query heads over 4 KV heads of 128, L8192),
+    rematerialised as ``SparseDecoderModule`` declares it: the gradient
+    compiles and holds forward + logsumexp, dQ and dK/dV for each, and
+    no fourth: the second forward finds the kernel's output and its
+    logsumexp kept."""
+    from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
+        SparseDecoderModule, next_token_loss)
+
+    module = SparseDecoderModule(
+        vocab=1024, hidden_size=2048,
+        layer_types=("sliding_attention", "full_attention"),
+        n_dense_layers=2, n_head=32, n_kv_head=4, head_dim=128,
+        window=2048, dense_width=6144, expert_width=1024, n_routed=128,
+        n_held=16, dtype=jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    params = _shapes_on(jax.eval_shape(
+        module.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 128), jnp.int32))["params"], one_chip)
+
+    def loss(params, ids):
+        return next_token_loss(module.apply({"params": params}, ids), ids)
+
+    text = jax.jit(jax.grad(loss)).lower(params, ids).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 6
+    for scope in ("attention_flash_window", "attention_flash"):
+        assert sum(f"/{scope}/" in line for line in kernels) == 3, scope
+    assert "8192,8192" not in text

@@ -12,11 +12,13 @@ import pytest
 
 from analytics_zoo_tpu.keras.layers.moe import DroplessExperts, grouped_dot
 from analytics_zoo_tpu.keras.layers.sparse_decoder import (
-    GatedGroupedAttention)
+    FULL, SLIDING, GatedGroupedAttention)
 from analytics_zoo_tpu.learn.optim import AdamWeightDecay
 from analytics_zoo_tpu.models.text import SparseDecoderLM
-from analytics_zoo_tpu.models.text.sparse_decoder_lm import next_token_loss
+from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
+    SparseDecoderModule, next_token_loss)
 from analytics_zoo_tpu.obs.metrics import get_registry
+from analytics_zoo_tpu.ops import attention, pallas_attention
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention, reference_attention)
 from analytics_zoo_tpu.ops.pallas_attention import (
@@ -714,6 +716,123 @@ def test_model_matches_reference_in_bfloat16():
     assert len(routing) == 2 and routing[0].shape == (2, 20, 3)
     loss = next_token_loss(logits, y)
     assert abs(float(loss) - float(ref.loss(variables, x, y, config))) < 0.02
+
+
+# ------------------------------------------------------------------ #
+# rematerialisation keeps the flash kernel's two results             #
+# ------------------------------------------------------------------ #
+KINDS = pytest.mark.parametrize("kind", [SLIDING, FULL])
+
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """The dispatcher told it is on the chip, as
+    ``test_step_scopes.test_attention_path_names_itself`` does; the
+    kernels it then picks run here in interpret mode."""
+    monkeypatch.setattr(attention, "_platform", lambda q: "tpu")
+
+
+def _one_layer(kind):
+    """``SparseDecoderModule`` with one dense layer of ``kind`` at
+    L1024, which the flash path takes (2 query heads over 1 KV head of
+    64, window 256), its parameters, and a loss of them."""
+    module = SparseDecoderModule(
+        vocab=64, hidden_size=64, layer_types=(kind,), n_dense_layers=1,
+        n_head=2, n_kv_head=1, head_dim=64, window=256, dense_width=96,
+        expert_width=16, n_routed=8, n_held=4)
+    ids = np.random.default_rng(0).integers(0, 64, (1, 1024)).astype(
+        np.int32)
+    params = module.init(jax.random.PRNGKey(0), ids)["params"]
+
+    def loss(params):
+        logits = module.apply({"params": params}, ids)
+        return next_token_loss(logits, jnp.roll(ids, -1, 1)), logits
+
+    return params, loss
+
+
+def _without_policy(monkeypatch):
+    """``nn.remat`` as it stood before the policy: the layer's input is
+    all the backward pass keeps."""
+    remat = nn.remat
+    monkeypatch.setattr(
+        nn, "remat", lambda target, policy, **kwargs: remat(target, **kwargs))
+
+
+@KINDS
+def test_remat_layer_backward_holds_three_flash_kernels(
+        monkeypatch, on_the_chip, kind):
+    """Forward + logsumexp, dQ, dK/dV: the layer's second forward holds
+    no attention kernel, because both results of the first are kept.
+    Without the policy it holds a fourth; on the path that holds [L, L]
+    scores nothing carries the names and nothing but the input is
+    kept."""
+    params, loss = _one_layer(kind)
+
+    def text():
+        return str(jax.make_jaxpr(jax.grad(loss, has_aux=True))(params))
+
+    assert text().count("pallas_call[") == 3
+    _without_policy(monkeypatch)
+    assert text().count("pallas_call[") == 4
+    monkeypatch.undo()      # the policy again, and the dispatcher's own eyes
+    monkeypatch.setattr(attention, "_platform", lambda q: "cpu")
+    scores = text()
+    assert "pallas_call[" not in scores and "name=flash_" not in scores
+
+
+@KINDS
+def test_remat_policy_changes_no_bit(monkeypatch, on_the_chip, kind):
+    """The kept values are what the kernel would produce again: loss,
+    logits and every gradient leaf equal those of the layer rematerialised
+    whole."""
+    params, loss = _one_layer(kind)
+
+    def run():
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    (kept_loss, kept_logits), kept_grads = run()
+    _without_policy(monkeypatch)
+    (loss_, logits), grads = run()
+    assert float(kept_loss) == float(loss_)
+    np.testing.assert_array_equal(kept_logits, logits)
+    for (path, g), k in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(kept_grads),
+                            strict=True):
+        assert np.abs(np.asarray(g)).max() > 0, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(k, g, jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("window", [256, None])
+def test_names_outside_a_policy_change_no_program(monkeypatch, window):
+    """A differentiated flash call under no ``jax.checkpoint`` lowers
+    to the same program with the names and without them, and keeps
+    for its backward what it kept: q, k, v, the output and the
+    logsumexp as the kernel writes it."""
+    q = jax.ShapeDtypeStruct((1, 2, 1024, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1, 1024, 64), jnp.bfloat16)
+
+    def attend(q, k, v):
+        return jnp.sum(pallas_flash_attention_fwd(
+            q, k, v, True, None, None, None, window).astype(jnp.float32))
+
+    def lowered():
+        return jax.jit(jax.grad(attend, (0, 1, 2))).trace(q, kv, kv).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    kept = jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda *args: jax.vjp(attend, *args)[1], q, kv, kv))
+    assert [(k.shape, k.dtype) for k in kept] == [
+        (q.shape, q.dtype), (kv.shape, kv.dtype), (kv.shape, kv.dtype),
+        (q.shape, q.dtype), ((2, 1024, 128), jnp.float32)]
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    texts = []
+    for name in (pallas_attention.checkpoint_name, lambda x, name: x):
+        monkeypatch.setattr(pallas_attention, "checkpoint_name", name)
+        texts.append(lowered())     # one call site: kernels carry theirs
+    named, bare = texts
+    assert named.count("stablehlo.custom_call @tpu_custom_call") == 3
+    assert named == bare
 
 
 @pytest.fixture
