@@ -98,31 +98,105 @@ class SwiGLU(nn.Module):
         return dense(x.shape[-1], "w2")(h)
 
 
-# (rows, contraction, columns) tile of the grouped product's kernel
-GROUPED_DOT_TILING = (512, 1024, 1024)
+# rows of the sorted buffer in one tile of the grouped products' kernels
+GROUPED_DOT_ROWS = 512
+# widest contraction or column tile, and the most a contraction tile
+# times a column tile may cover: the weights' gradient keeps that many
+# float32 sums in VMEM beside its operands' tiles, and 1,024 x 1,408
+# is 0.8 MB over what the chip's compiler allows a kernel
+# (docs/kernels.md)
+GROUPED_DOT_WIDEST = 1408
+GROUPED_DOT_AREA = 1024 * 1024
+
+
+def grouped_dot_tiling(k: int, n: int) -> tuple:
+    """(rows, contraction, columns) tile of one grouped product
+    [m, k] x [groups, k, n]: multiples of 128 that divide k and n, the
+    pair that covers most within ``GROUPED_DOT_AREA`` -- (512, 1024,
+    1024) at experts 1,024 wide under d 2,048; at 1,408 = 11 x 128 the
+    whole width in one tile beside 512 of the other side, where a
+    1,024 tile would have the kernels mask or pad 27 % of their second
+    tile (docs/kernels.md). A width no multiple of 128 divides gets
+    128 (the kernels mask the remainder)."""
+    def tiles(width: int) -> list:
+        return [t for t in range(128, GROUPED_DOT_WIDEST + 1, 128)
+                if width % t == 0] or [128]
+
+    tile_k, tile_n = max(
+        ((a, b) for a in tiles(k) for b in tiles(n)
+         if a * b <= GROUPED_DOT_AREA), key=lambda ab: (ab[0] * ab[1], ab))
+    return GROUPED_DOT_ROWS, tile_k, tile_n
+
+
+def _interpret() -> bool:
+    """The attention kernels' switch: the CPU interprets (the tests' way
+    in), every other backend compiles."""
+    from analytics_zoo_tpu.ops import pallas_attention
+
+    return pallas_attention._interpret()
+
+
+@jax.custom_vjp
+def _gmm(x, w, sizes):
+    """``megablox``' grouped product with each pass tiled for its own
+    shapes: the library's custom VJP hands the forward's tile to the
+    two backward products, whose contraction and columns are other
+    widths."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(x, w, sizes, x.dtype, grouped_dot_tiling(*w.shape[1:]),
+               interpret=_interpret())
+
+
+def _gmm_fwd(x, w, sizes):
+    return _gmm(x, w, sizes), (x, w, sizes)
+
+
+def _gmm_bwd(res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    x, w, sizes = res
+    k, n = w.shape[1:]
+    dx = gmm(g, w, sizes, x.dtype, grouped_dot_tiling(n, k),
+             transpose_rhs=True, interpret=_interpret())
+    dw = tgmm(x.swapaxes(0, 1), g, sizes, w.dtype,
+              grouped_dot_tiling(k, n), None, w.shape[0],
+              interpret=_interpret())
+    return dx, dw, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_dot(x, w, sizes):
     """``x[rows of group e] @ w[e]`` for rows sorted by group: x [m, k],
     w [groups, k, n], ``sizes`` [groups] int32. Rows past ``sum(sizes)``
     are left undefined. Off the CPU the product is JAX's Pallas grouped
-    matmul (``megablox.gmm``: it visits only the row tiles that hold a
-    group's rows, and its ``pallas_call`` keeps the caller's scope in
-    ``op_name``); on the CPU, and for a row count its tile does not
-    divide, ``jax.lax.ragged_dot``. On the TPU ``ragged_dot`` compiles
-    to a kernel of XLA's own that is named ``ragged-dot-none`` whatever
-    scope it was called under, so a device trace cannot attribute it
-    (docs/kernels.md has both timings)."""
+    matmul (``megablox``' ``gmm``, and ``gmm`` transposed and ``tgmm``
+    backward, each tiled by ``grouped_dot_tiling`` -- through the
+    library's own VJP where one tile serves all three, else through
+    ``_gmm``'s: they visit only the row tiles that hold a group's rows,
+    and their ``pallas_call`` keeps the caller's scope in ``op_name``);
+    on the CPU, and for a row count the tile does not divide,
+    ``jax.lax.ragged_dot``. On the TPU
+    ``ragged_dot`` compiles to a kernel of XLA's own that is named
+    ``ragged-dot-none`` whatever scope it was called under, so a device
+    trace cannot attribute it (docs/kernels.md has both timings)."""
     from analytics_zoo_tpu.ops.attention import _platform
 
-    tile_m = GROUPED_DOT_TILING[0]
-    if _platform(x) == "cpu" or x.shape[0] % tile_m:
+    if _platform(x) == "cpu" or x.shape[0] % GROUPED_DOT_ROWS:
         return jax.lax.ragged_dot(x, w, sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    k, n = w.shape[1:]
+    tiling = grouped_dot_tiling(k, n)
+    if tiling == grouped_dot_tiling(n, k):
+        # one tile serves the three passes (1,024-wide experts under
+        # d 2,048): the library's own VJP runs exactly these kernels
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    # positional: the custom_vjp marks arguments 3, 4, 7, 8 static
-    return gmm(x, w, sizes, x.dtype, GROUPED_DOT_TILING, None, None,
-               False, False)
+        # positional: the custom_vjp marks arguments 3, 4, 7, 8 static
+        return gmm(x, w, sizes, x.dtype, tiling, None, None, False,
+                   _interpret())
+    return _gmm(x, w, sizes)
 
 
 # rows of the sorted buffer that one trip of a bounded pass covers: a
@@ -390,6 +464,11 @@ class DroplessExperts(nn.Module):
         sign(mean(count) - count)`` over the step's per-expert
         assignment counts; no gradient reaches ``bias`` (collection
         ``router_state``).
+      router_init_std: the router matrix starts normal with this
+        deviation (None: LeCun-normal, scores spread over ~0.2). Small
+        (0.001: scores within ~0.01 of each other), ``bias_step``
+        balances a fresh router's load within a few steps instead of
+        hundreds.
 
     Collection ``counters`` holds cumulative int32 counts, updated on
     training applies and published by the Estimator at each epoch's
@@ -408,14 +487,17 @@ class DroplessExperts(nn.Module):
     route_scale: float = 1.0
     shared_width: int = 0
     bias_step: float = 0.001
+    router_init_std: Optional[float] = None
     dtype: Any = jnp.float32
 
     def _route(self, m, train: bool):
         """Weights [n, k] (float32), expert ids [n, k], counts [E]."""
         e = self.n_routed
+        init = (nn.linear.default_kernel_init if self.router_init_std is None
+                else nn.initializers.normal(self.router_init_std))
         scores = jax.nn.sigmoid(nn.Dense(
-            e, use_bias=False, dtype=jnp.float32, name="router")(
-                m.astype(jnp.float32)))                      # [n, E]
+            e, use_bias=False, dtype=jnp.float32, kernel_init=init,
+            name="router")(m.astype(jnp.float32)))           # [n, E]
         bias = self.variable("router_state", "bias",
                              lambda: jnp.zeros((e,), jnp.float32))
         _, idx = jax.lax.top_k(

@@ -1,10 +1,15 @@
-"""SparseDecoderLM: a causal language model of ``SparseDecoderLayer``
-blocks -- window and full attention mixed by layer, leading dense
-layers and then routed experts -- trained on the next token.
+"""Causal language models of sparse decoder blocks, trained on the
+next token: leading dense layers and then routed experts.
 
     h0 = Embed[ids] * sqrt(d)     (``scale_embedding``)
     h  = layers(h0)
     logits = RMSNorm(h) W_head    (untied, float32)
+
+``SparseDecoderLM`` stacks ``SparseDecoderLayer`` blocks (window and
+full grouped-KV attention mixed by layer, four norms, an output gate),
+``LatentDecoderLM`` stacks ``LatentDecoderLayer`` blocks (latent
+attention, two norms); embedding, rematerialisation, final norm, head
+and loss are shared.
 
 The vocabulary and the experts may be one chip's share of a larger
 deployment: ``vocab`` rows of the table and the head, ``n_held`` of
@@ -20,15 +25,16 @@ kept.
 
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Any, Optional, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.keras.layers.latent_decoder import LatentDecoderLayer
 from analytics_zoo_tpu.keras.layers.sparse_decoder import (
-    RMSNorm, SLIDING, SparseDecoderLayer)
+    RMSNorm, SparseDecoderLayer)
 from analytics_zoo_tpu.models.common import ZooModel, register_model
 from analytics_zoo_tpu.ops.pallas_attention import (
     FLASH_LSE_NAME, FLASH_OUT_NAME)
@@ -41,6 +47,37 @@ def next_token_loss(logits, labels):
     labels = labels.astype(jnp.int32)
     picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+
+def _embed(module, x, init_std: float = 0.02):
+    """Token ids -> [B, L, d] in ``module.dtype``, times sqrt(d) where
+    the module scales its embeddings."""
+    ids = x["input_ids"] if isinstance(x, dict) else x
+    d = module.hidden_size
+    h = nn.Embed(module.vocab, d, name="embed",
+                 embedding_init=nn.initializers.normal(init_std))(
+        ids.astype(jnp.int32)).astype(module.dtype)
+    if module.scale_embedding:
+        h = h * np.sqrt(d).astype(module.dtype)
+    return h
+
+
+def _rematerialised(layer_cls):
+    """The backward pass keeps the layer's input and, where the flash
+    kernel ran, its output and logsumexp (the names exist on no other
+    attention path); all else is computed again."""
+    return nn.remat(
+        layer_cls, static_argnums=(2,),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME))
+
+
+def _logits(module, h):
+    h = RMSNorm(module.eps, module.dtype, name="final_norm")(h)
+    head = module.param("head", nn.initializers.normal(0.02),
+                        (module.hidden_size, module.vocab))
+    return jnp.dot(h, head.astype(module.dtype),
+                   preferred_element_type=jnp.float32)
 
 
 class SparseDecoderModule(nn.Module):
@@ -68,25 +105,13 @@ class SparseDecoderModule(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        ids = x["input_ids"] if isinstance(x, dict) else x
-        d = self.hidden_size
-        h = nn.Embed(self.vocab, d, name="embed",
-                     embedding_init=nn.initializers.normal(0.02))(
-            ids.astype(jnp.int32)).astype(self.dtype)
-        if self.scale_embedding:
-            h = h * np.sqrt(d).astype(self.dtype)
+        h = _embed(self, x)
         experts = dict(
             width=self.expert_width, n_routed=self.n_routed,
             n_held=self.n_held, first_held=self.first_held,
             top_k=self.top_k, route_scale=self.route_scale,
             shared_width=self.shared_width, bias_step=self.bias_step)
-        # the backward pass keeps the layer's input and, where the
-        # flash kernel ran, its output and logsumexp (the names exist
-        # on no other attention path); all else is computed again
-        layer = nn.remat(
-            SparseDecoderLayer, static_argnums=(2,),
-            policy=jax.checkpoint_policies.save_only_these_names(
-                FLASH_OUT_NAME, FLASH_LSE_NAME))
+        layer = _rematerialised(SparseDecoderLayer)
         for i, kind in enumerate(self.layer_types):
             h = layer(
                 kind=kind, n_head=self.n_head, n_kv_head=self.n_kv_head,
@@ -95,15 +120,58 @@ class SparseDecoderModule(nn.Module):
                 experts=None if i < self.n_dense_layers else experts,
                 rope_theta=self.rope_theta, eps=self.eps,
                 dtype=self.dtype, name=f"layer_{i}")(h, train)
-        h = RMSNorm(self.eps, self.dtype, name="final_norm")(h)
-        head = self.param("head", nn.initializers.normal(0.02),
-                          (d, self.vocab))
-        return jnp.dot(h, head.astype(self.dtype),
-                       preferred_element_type=jnp.float32)
+        return _logits(self, h)
 
 
-@register_model
-class SparseDecoderLM(ZooModel):
+class LatentDecoderModule(nn.Module):
+    vocab: int
+    hidden_size: int
+    n_layers: int
+    n_dense_layers: int
+    n_head: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    latent_dim: int
+    dense_width: int
+    expert_width: int
+    n_routed: int
+    n_held: int
+    first_held: int = 0
+    top_k: int = 6
+    route_scale: float = 1.0
+    shared_width: int = 0
+    bias_step: float = 0.001
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    scale_embedding: bool = False
+    embed_init_std: float = 0.02
+    router_init_std: Optional[float] = None
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        h = _embed(self, x, self.embed_init_std)
+        attention = dict(
+            n_head=self.n_head, nope_dim=self.nope_dim,
+            rope_dim=self.rope_dim, v_dim=self.v_dim,
+            latent_dim=self.latent_dim, rope_theta=self.rope_theta)
+        experts = dict(
+            width=self.expert_width, n_routed=self.n_routed,
+            n_held=self.n_held, first_held=self.first_held,
+            top_k=self.top_k, route_scale=self.route_scale,
+            shared_width=self.shared_width, bias_step=self.bias_step,
+            router_init_std=self.router_init_std)
+        layer = _rematerialised(LatentDecoderLayer)
+        for i in range(self.n_layers):
+            h = layer(
+                attention=attention, dense_width=self.dense_width,
+                experts=None if i < self.n_dense_layers else experts,
+                eps=self.eps, dtype=self.dtype, name=f"layer_{i}")(h, train)
+        return _logits(self, h)
+
+
+class _DecoderLM(ZooModel):
     """fit expects x = {"input_ids": [B, L]} (or the array) and
     y = [B, L], each position's next token; predict returns float32
     logits [B, L, vocab]."""
@@ -111,6 +179,16 @@ class SparseDecoderLM(ZooModel):
     default_loss = staticmethod(next_token_loss)
     default_optimizer = "adam"
     default_metrics = ()
+
+    def _example_input(self):
+        return {"input_ids": np.zeros((1, 16), np.int32)}
+
+
+@register_model
+class SparseDecoderLM(_DecoderLM):
+    """A decoder of ``SparseDecoderLayer`` blocks (window and full
+    grouped-KV attention by ``layer_types``, four norms, an output
+    gate); ``_DecoderLM``'s contract."""
 
     def __init__(self, vocab: int, hidden_size: int,
                  layer_types: Sequence[str], n_dense_layers: int,
@@ -138,5 +216,41 @@ class SparseDecoderLM(ZooModel):
         c["dtype"] = jnp.dtype(c["dtype"])
         return SparseDecoderModule(**c)
 
-    def _example_input(self):
-        return {"input_ids": np.zeros((1, 16), np.int32)}
+
+@register_model
+class LatentDecoderLM(_DecoderLM):
+    """A decoder of latent-attention blocks (``LatentDecoderLayer``):
+    ``n_dense_layers`` dense layers, then routed experts with
+    ``n_shared`` shared experts of ``expert_width`` beside them;
+    ``_DecoderLM``'s contract. ``embed_init_std`` / ``router_init_std``:
+    how a fresh model starts (the benchmark configuration's
+    ``assumed.initialisation`` says why)."""
+
+    def __init__(self, vocab: int, hidden_size: int, n_layers: int,
+                 n_dense_layers: int, n_head: int, nope_dim: int,
+                 rope_dim: int, v_dim: int, latent_dim: int,
+                 dense_width: int, expert_width: int, n_routed: int,
+                 n_held: int, first_held: int = 0, top_k: int = 6,
+                 route_scale: float = 1.0, n_shared: int = 2,
+                 bias_step: float = 0.001, rope_theta: float = 10000.0,
+                 eps: float = 1e-5, scale_embedding: bool = False,
+                 embed_init_std: float = 0.02,
+                 router_init_std: Optional[float] = None,
+                 dtype: str = "float32"):
+        super().__init__(
+            vocab=vocab, hidden_size=hidden_size, n_layers=n_layers,
+            n_dense_layers=n_dense_layers, n_head=n_head,
+            nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+            latent_dim=latent_dim, dense_width=dense_width,
+            expert_width=expert_width, n_routed=n_routed, n_held=n_held,
+            first_held=first_held, top_k=top_k, route_scale=route_scale,
+            n_shared=n_shared, bias_step=bias_step, rope_theta=rope_theta,
+            eps=eps, scale_embedding=scale_embedding,
+            embed_init_std=embed_init_std,
+            router_init_std=router_init_std, dtype=dtype)
+
+    def _build_module(self):
+        c = dict(self._config)
+        c["shared_width"] = c.pop("n_shared") * c["expert_width"]
+        c["dtype"] = jnp.dtype(c["dtype"])
+        return LatentDecoderModule(**c)

@@ -5,13 +5,16 @@ call's shapes alone; the one taken names itself in ``op_name``:
 - ``attention_flash`` -- the framework's own Pallas kernels
   (``pallas_attention.pallas_flash_attention_fwd``, exact custom_vjp):
   off the CPU, no mask or dropout, both lengths multiples of 128,
-  head_dim a multiple of 64, and a sequence longer than
-  ``FLASH_MIN_SEQ``. The only path that serves a causal ``window``
-  and K/V with fewer heads than Q without materialising either
-  (docs/kernels.md); a window call is named ``attention_flash_window``;
+  head_dim (and the values' width) a multiple of 64, and a sequence
+  longer than ``FLASH_MIN_SEQ``. The only path that serves a causal
+  ``window``, K/V with fewer heads than Q, values narrower than the
+  keys and a key head shared by every query head (``k_shared``)
+  without materialising any of them (docs/kernels.md); a window call
+  is named ``attention_flash_window``, one whose values' width is not
+  the queries' (latent attention) ``attention_flash_latent``;
 - ``attention_stock_pallas`` -- JAX's fused fwd+bwd kernel: the same
-  conditions with head_dim <= 128, for key-padding masks (lowered to
-  segment ids) and head sizes the owned kernel refuses;
+  conditions with one head_dim <= 128, for key-padding masks (lowered
+  to segment ids) and head sizes the owned kernel refuses;
 - ``attention_einsum`` -- batched matmuls with an f32 softmax and the
   [L, L] scores in HBM: the CPU's path, short sequences on the chip
   (BERT at L384), arbitrary 4-D masks. With a window:
@@ -122,25 +125,31 @@ FLASH_MIN_SEQ = 512
 
 
 def attention_path(platform: str, lq: int, lk: int, head_dim: int,
-                   q_heads: int, kv_heads: int, *, mask: bool = False,
+                   q_heads: int, kv_heads: int, *,
+                   value_dim: Optional[int] = None, mask: bool = False,
                    key_padding_mask: bool = False, dropout: bool = False,
                    causal: bool = False, window: bool = False) -> str:
     """Which path serves a call: ``flash``, ``stock_pallas``,
     ``einsum`` or ``reference``. A pure function of what the call site
-    can observe: the platform, the shapes, and which of a 4-D mask, a
-    key-padding mask, dropout, ``causal`` and a window are present."""
+    can observe: the platform, the shapes (``value_dim`` is the
+    values' width where it is not ``head_dim``), and which of a 4-D
+    mask, a key-padding mask, dropout, ``causal`` and a window are
+    present."""
+    value_dim = head_dim if value_dim is None else value_dim
     # the einsum path is the CPU's; any other platform compiles the
     # kernels (or fails loudly), whatever it calls itself
     kernels = (platform != "cpu" and max(lq, lk) > FLASH_MIN_SEQ
                and lq % 128 == 0 and lk % 128 == 0
                and not mask and not dropout)
-    if kernels and head_dim % 64 == 0 and not key_padding_mask:
+    if (kernels and head_dim % 64 == 0 and value_dim % 64 == 0
+            and not key_padding_mask):
         return "flash"
     # padding masks ride the stock kernel's segment ids. Its causal mask
     # is top-left aligned (no cross-length offset), so it only agrees
     # with reference_attention when lq == lk; it knows neither a window
     # nor grouped heads
-    if (kernels and head_dim <= 128 and (not causal or lq == lk)
+    if (kernels and head_dim <= 128 and value_dim == head_dim
+            and (not causal or lq == lk)
             and not window and kv_heads == q_heads):
         return "stock_pallas"
     return "reference" if dropout else "einsum"
@@ -150,19 +159,24 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
                           causal: bool = False,
                           scale: Optional[float] = None,
                           dropout_rate: float = 0.0, dropout_rng=None,
-                          window: Optional[int] = None):
-    """q: [B, H, L, D]; k, v: [B, H_kv, Lk, D] with ``H_kv`` dividing
-    ``H`` (query head n reads KV head ``n // (H / H_kv)``). ``mask``:
+                          window: Optional[int] = None, k_shared=None):
+    """q: [B, H, L, D]; k: [B, H_kv, Lk, D], v: [B, H_kv, Lk, Dv] with
+    ``H_kv`` dividing ``H`` (query head n reads KV head
+    ``n // (H / H_kv)``); ``Dv`` need not be ``D``. ``k_shared``
+    [B, 1, Lk, Ds]: one more key head that the last ``Ds`` columns of
+    every query head contract with, beside that head's own ``k``
+    [B, H, Lk, D - Ds] (latent attention's rotary key). ``mask``:
     arbitrary [B, H, Lq, Lk]-broadcastable (1 = attend; forces the jnp
     path). ``key_padding_mask``: [B, Lk] with 1 = real token --
     flash-compatible (lowered to segment ids). ``window`` (with
     ``causal``): row i reads only keys ``i - window < j <= i``.
-    Returns [B, H, Lq, D].
+    Returns [B, H, Lq, Dv].
 
     The path taken (``attention_path``) names itself in every op's
     ``op_name`` (and so in a device trace): ``attention_flash`` (this
     repo's Pallas kernel), ``attention_stock_pallas``,
-    ``attention_einsum`` or ``attention_reference``; a window call adds
+    ``attention_einsum`` or ``attention_reference``; a call whose
+    values' width is not the queries' adds ``_latent``, a window call
     ``_window``."""
     d = q.shape[-1]
     l, lk = q.shape[2], k.shape[2]
@@ -176,18 +190,25 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
 
     path = attention_path(
         _platform(q), l, lk, d, q.shape[1], k.shape[1],
-        mask=mask is not None, key_padding_mask=key_padding_mask is not None,
+        value_dim=v.shape[-1], mask=mask is not None,
+        key_padding_mask=key_padding_mask is not None,
         dropout=dropout_rate != 0.0, causal=causal,
         window=window is not None)
     scope = jax.named_scope(
-        f"attention_{path}" + ("" if window is None else "_window"))
+        f"attention_{path}" + ("" if v.shape[-1] == d else "_latent")
+        + ("" if window is None else "_window"))
     if path == "flash":
         from analytics_zoo_tpu.ops.pallas_attention import (
             pallas_flash_attention_fwd)
 
         with scope:
             return pallas_flash_attention_fwd(q, k, v, causal, scale,
-                                              None, None, window)
+                                              None, None, window, k_shared)
+    if k_shared is not None:
+        # the paths that hold [L, L] scores hold the joined keys too
+        with scope:
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                k_shared, k.shape[:-1] + k_shared.shape[-1:])], axis=-1)
     if path == "stock_pallas":
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             SegmentIds, flash_attention)

@@ -137,8 +137,18 @@ def _mask(s, qi, ki, block_q: int, block_k: int, causal: bool,
     return jnp.where(keep, s, NEG_INF)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
-                      with_lse: bool, geometry: dict):
+def _keys(k_ref, ks_ref):
+    """The block's keys [BK, D]: with a shared head, its columns follow
+    the head's own (joined in VMEM; HBM holds the shared head once)."""
+    if ks_ref is None:
+        return k_ref[0]
+    return jnp.concatenate([k_ref[0], ks_ref[0]], axis=-1)
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float,
+                      with_lse: bool, shared: bool, geometry: dict):
+    ks_ref, rest = (rest[0], rest[1:]) if shared else (None, rest)
+    o_ref, rest = rest[0], rest[1:]
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -160,8 +170,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
         # path; f32 accumulate via preferred_element_type) -- upcasting
         # here would silently fall to the slow full-precision MXU mode
         q = q_ref[0]                              # [BQ, D]
-        k = k_ref[0]                              # [BK, D]
-        v = v_ref[0]
+        k = _keys(k_ref, ks_ref)                  # [BK, D]
+        v = v_ref[0]                              # [BK, Dv]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [BQ, BK]
@@ -194,12 +204,20 @@ def _mask_geometry(geometry: dict) -> dict:
             ("block_q", "block_k", "causal", "offset", "window")}
 
 
-def _check(q, k, causal: bool, window: Optional[int]) -> int:
+def _check(q, k, v, causal: bool, window: Optional[int],
+           k_shared=None) -> int:
     """Validates the shapes; returns query heads per KV head."""
     h, l, d = q.shape[1:]
     h_kv, lk = k.shape[1:3]
-    if d % 64:
-        raise ValueError(f"head_dim {d} must be a multiple of 64")
+    d_keys = k.shape[-1] + (0 if k_shared is None else k_shared.shape[-1])
+    if d % 64 or v.shape[-1] % 64 or k.shape[-1] % 64:
+        raise ValueError(f"head_dims {d} (q), {k.shape[-1]} (k), "
+                         f"{v.shape[-1]} (v) must be multiples of 64")
+    if d_keys != d:
+        raise ValueError(f"keys are {d_keys} wide, queries {d}")
+    if k_shared is not None and (k_shared.shape[1] != 1 or h_kv != h):
+        raise ValueError("a shared key is one head beside a key head "
+                         "for every query head")
     if causal and l > lk:
         # rows attending to nothing are undefined under flash semantics
         raise ValueError("causal attention requires len(q) <= len(kv)")
@@ -212,22 +230,24 @@ def _check(q, k, causal: bool, window: Optional[int]) -> int:
 
 
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
-               block_k: int, with_lse: bool, window: Optional[int] = None):
-    """Returns out [B,H,L,D] and, when ``with_lse``, the per-row
+               block_k: int, with_lse: bool, window: Optional[int] = None,
+               k_shared=None):
+    """Returns out [B,H,L,Dv] and, when ``with_lse``, the per-row
     logsumexp at [B*H, L, 128] (value broadcast across the 128 lanes --
     the TPU-native row-stat layout the stock flash kernel also uses;
     inference passes ``with_lse=False`` so nothing extra hits HBM)."""
     b, h, l, d = q.shape
-    h_kv, lk = k.shape[1:3]
-    group = _check(q, k, causal, window)
+    h_kv, lk, d_k = k.shape[1:]
+    d_v = v.shape[-1]
+    group = _check(q, k, v, causal, window, k_shared)
     block_q = block_q or _auto_block(l)
     block_k = block_k or _auto_block(lk)
     if l % block_q or lk % block_k:
         raise ValueError(f"seq lens ({l},{lk}) must divide blocks "
                          f"({block_q},{block_k})")
     qr = q.reshape(b * h, l, d)
-    kr = k.reshape(b * h_kv, lk, d)
-    vr = v.reshape(b * h_kv, lk, d)
+    kr = k.reshape(b * h_kv, lk, d_k)
+    vr = v.reshape(b * h_kv, lk, d_v)
     geometry = dict(block_q=block_q, block_k=block_k, nk=lk // block_k,
                     causal=causal, offset=lk - l, window=window)
     grid = (b * h, l // block_q, _steps(_kv_bounds, l // block_q,
@@ -236,38 +256,46 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
     def q_map(bh, qi, step):
         return bh, qi, 0
 
-    def kv_map(bh, qi, step):
+    def kv_map(bh, qi, step, heads=group):
         lo, hi = _traced(_kv_bounds, qi, **geometry)
-        return bh // group, jnp.minimum(lo + step, hi), 0
+        return bh // heads, jnp.minimum(lo + step, hi), 0
 
-    out_specs = [pl.BlockSpec((1, block_q, d), q_map)]
-    out_shape = [jax.ShapeDtypeStruct((b * h, l, d), q.dtype)]
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_k, d_k), kv_map),
+        pl.BlockSpec((1, block_k, d_v), kv_map),
+    ]
+    operands = [qr, kr, vr]
+    if k_shared is not None:
+        # one head a batch row, read by every query head of the row
+        in_specs.append(pl.BlockSpec(
+            (1, block_k, d - d_k), functools.partial(kv_map, heads=h)))
+        operands.append(k_shared.reshape(b, lk, d - d_k))
+    out_specs = [pl.BlockSpec((1, block_q, d_v), q_map)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, l, d_v), q.dtype)]
     if with_lse:
         out_specs.append(pl.BlockSpec((1, block_q, 128), q_map))
         out_shape.append(jax.ShapeDtypeStruct((b * h, l, 128),
                                               jnp.float32))
     res = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=scale,
-                          with_lse=with_lse, geometry=geometry),
+                          with_lse=with_lse, shared=k_shared is not None,
+                          geometry=geometry),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=_grid_semantics(),
         interpret=_interpret(),
-    )(qr, kr, vr)
+    )(*operands)
     out = res[0]
     lse = res[1] if with_lse else None
-    return out.reshape(b, h, l, d), lse
+    return out.reshape(b, h, l, d_v), lse
 
 
 def _interpret() -> bool:
@@ -304,7 +332,8 @@ def _probs_and_ds(q, k, v, do, lse, delta, qi, ki, scale, geometry):
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dq_scr, *, scale: float, geometry: dict):
+                     *rest, scale: float, shared: bool, geometry: dict):
+    ks_ref, (dq_ref, dq_scr) = (rest[0], rest[1:]) if shared else (None, rest)
     qi = pl.program_id(1)
     step = pl.program_id(2)
     lo, hi = _traced(_kv_bounds, qi, **geometry)
@@ -316,7 +345,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ki <= hi)
     def _body():
-        k = k_ref[0]                                # [BK, D]
+        k = _keys(k_ref, ks_ref)                    # [BK, D]
         _, ds = _probs_and_ds(
             q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0][:, :1],
             delta_ref[0][:, :1], qi, ki, scale, geometry)
@@ -330,11 +359,18 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                      q_steps: int, geometry: dict, q_geometry: dict):
+                      *rest, scale: float, q_steps: int, shared: bool,
+                      geometry: dict, q_geometry: dict):
     """One KV head's block ``ki``; the sequential dimension walks the
     query heads of the group, and for each the q-blocks that read this
-    kv-block."""
+    kv-block. With a shared key head, ``dk_scr`` holds both parts and
+    the shared columns go out per head (summed over the heads by the
+    caller)."""
+    if shared:
+        ks_ref, dk_ref, dv_ref, dks_ref, dk_scr, dv_scr = rest
+    else:
+        ks_ref, dks_ref = None, None
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
     ki = pl.program_id(1)
     step = pl.program_id(2)
     lo, hi = _traced(_q_bounds, ki, **q_geometry)
@@ -348,21 +384,26 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(qi <= hi)
     def _body():
         q = q_ref[0]                                # [BQ, D]
-        do = do_ref[0]                              # [BQ, D]
+        do = do_ref[0]                              # [BQ, Dv]
         p, ds = _probs_and_ds(
-            q, k_ref[0], v_ref[0], do, lse_ref[0][:, :1],
+            q, _keys(k_ref, ks_ref), v_ref[0], do, lse_ref[0][:, :1],
             delta_ref[0][:, :1], qi, ki, scale, geometry)
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [BK, D]
+            preferred_element_type=jnp.float32)     # [BK, Dv]
         dk_scr[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)     # [BK, D]
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        if shared:
+            d_k = dk_ref.shape[-1]
+            dk_ref[0] = dk_scr[:, :d_k].astype(dk_ref.dtype)
+            dks_ref[0] = dk_scr[:, d_k:]
+        else:
+            dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
 
 
 def _bwd_cap(length: int, d: int) -> int:
@@ -376,47 +417,59 @@ def _bwd_cap(length: int, d: int) -> int:
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
-               block_q: int, block_k: int, window: Optional[int] = None):
+               block_q: int, block_k: int, window: Optional[int] = None,
+               k_shared=None):
     b, h, l, d = q.shape
-    h_kv, lk = k.shape[1:3]
+    h_kv, lk, d_k = k.shape[1:]
+    d_v = v.shape[-1]
     group = h // h_kv
+    shared = k_shared is not None
     block_q = block_q or _auto_block(l, cap=_bwd_cap(l, d))
     block_k = block_k or _auto_block(lk, cap=_bwd_cap(lk, d))
     bh, bh_kv = b * h, b * h_kv
     nq, nk = l // block_q, lk // block_k
     qr = q.reshape(bh, l, d)
-    kr = k.reshape(bh_kv, lk, d)
-    vr = v.reshape(bh_kv, lk, d)
-    dor = g.reshape(bh, l, d)
+    kr = k.reshape(bh_kv, lk, d_k)
+    vr = v.reshape(bh_kv, lk, d_v)
+    dor = g.reshape(bh, l, d_v)
     # delta_i = rowsum(do_i * o_i): one fused elementwise pass, O(L*D)
     delta = jnp.sum(dor.astype(jnp.float32) *
-                    o.reshape(bh, l, d).astype(jnp.float32),
+                    o.reshape(bh, l, d_v).astype(jnp.float32),
                     axis=-1, keepdims=True)
     delta = jnp.broadcast_to(delta, (bh, l, 128))
     geometry = dict(block_q=block_q, block_k=block_k, nk=nk,
                     causal=causal, offset=lk - l, window=window)
+    operands = [qr, kr, vr, dor, lse, delta]
+    if shared:
+        operands.append(k_shared.reshape(b, lk, d - d_k))
 
     def q_map(bh_, qi, step):
         return bh_, qi, 0
 
-    def kv_map(bh_, qi, step):
+    def kv_map(bh_, qi, step, heads=group):
         lo, hi = _traced(_kv_bounds, qi, **geometry)
-        return bh_ // group, jnp.minimum(lo + step, hi), 0
+        return bh_ // heads, jnp.minimum(lo + step, hi), 0
 
     q_spec = pl.BlockSpec((1, block_q, d), q_map)
-    k_spec = pl.BlockSpec((1, block_k, d), kv_map)
+    do_spec = pl.BlockSpec((1, block_q, d_v), q_map)
+    k_spec = pl.BlockSpec((1, block_k, d_k), kv_map)
+    v_spec = pl.BlockSpec((1, block_k, d_v), kv_map)
     row_spec = pl.BlockSpec((1, block_q, 128), q_map)
+    in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
+    if shared:
+        in_specs.append(pl.BlockSpec(
+            (1, block_k, d - d_k), functools.partial(kv_map, heads=h)))
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale,
+        functools.partial(_flash_dq_kernel, scale=scale, shared=shared,
                           geometry=geometry),
         grid=(bh, nq, _steps(_kv_bounds, nq, **geometry)),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct((bh, l, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_grid_semantics(),
         interpret=_interpret(),
-    )(qr, kr, vr, dor, lse, delta)
+    )(*operands)
 
     # dk/dv walk kv-blocks in the outer grid dim; the sequential one
     # covers (query head of the group) x (q-blocks that read the block)
@@ -433,26 +486,40 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
         return bh_, ki, 0
 
     q_spec2 = pl.BlockSpec((1, block_q, d), q_map2)
-    k_spec2 = pl.BlockSpec((1, block_k, d), kv_map2)
+    do_spec2 = pl.BlockSpec((1, block_q, d_v), q_map2)
+    k_spec2 = pl.BlockSpec((1, block_k, d_k), kv_map2)
+    v_spec2 = pl.BlockSpec((1, block_k, d_v), kv_map2)
     row_spec2 = pl.BlockSpec((1, block_q, 128), q_map2)
-    dk, dv = pl.pallas_call(
+    in_specs = [q_spec2, k_spec2, v_spec2, do_spec2, row_spec2, row_spec2]
+    out_specs = [k_spec2, v_spec2]
+    out_shape = [jax.ShapeDtypeStruct((bh_kv, lk, d_k), k.dtype),
+                 jax.ShapeDtypeStruct((bh_kv, lk, d_v), v.dtype)]
+    if shared:
+        in_specs.append(pl.BlockSpec((1, block_k, d - d_k),
+                                     lambda bh_, ki, step: (bh_ // h, ki, 0)))
+        # each head's part of the shared head's gradient, in float32
+        out_specs.append(pl.BlockSpec((1, block_k, d - d_k), kv_map2))
+        out_shape.append(jax.ShapeDtypeStruct((bh_kv, lk, d - d_k),
+                                              jnp.float32))
+    dk, dv, *dks = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, q_steps=q_steps,
-                          geometry=geometry, q_geometry=q_geometry),
+                          shared=shared, geometry=geometry,
+                          q_geometry=q_geometry),
         grid=(bh_kv, nk, group * q_steps),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2,
-                  row_spec2],
-        out_specs=[k_spec2, k_spec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh_kv, lk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh_kv, lk, d), v.dtype),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         compiler_params=_grid_semantics(),
         interpret=_interpret(),
-    )(qr, kr, vr, dor, lse, delta)
-    return (dq.reshape(b, h, l, d), dk.reshape(b, h_kv, lk, d),
-            dv.reshape(b, h_kv, lk, d))
+    )(*operands)
+    grads = (dq.reshape(b, h, l, d), dk.reshape(b, h_kv, lk, d_k),
+             dv.reshape(b, h_kv, lk, d_v))
+    if not shared:
+        return grads + (None,)
+    return grads + (dks[0].reshape(b, h, lk, d - d_k).sum(
+        1, keepdims=True).astype(k_shared.dtype),)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -460,14 +527,21 @@ def pallas_flash_attention_fwd(q, k, v, causal: bool = False,
                                scale: Optional[float] = None,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
-                               window: Optional[int] = None):
-    """Flash attention on q [B, H, L, D], k/v [B, H_kv, Lk, D]; exact
-    softmax attention. ``block_q``/``block_k`` default to the largest
-    128-multiple divisor of each sequence length, capped at 1024.
-    ``window`` (causal only) keeps the ``window`` newest keys of each
-    row; ``H_kv`` may divide ``H`` (grouped heads)."""
+                               window: Optional[int] = None,
+                               k_shared=None):
+    """Flash attention on q [B, H, L, D], k [B, H_kv, Lk, D],
+    v [B, H_kv, Lk, Dv]; exact softmax attention, out [B, H, L, Dv]:
+    the values' width need not be the queries' (latent attention reads
+    192-wide keys and 128-wide values). ``block_q``/``block_k`` default
+    to the largest 128-multiple divisor of each sequence length, capped
+    at 1024. ``window`` (causal only) keeps the ``window`` newest keys
+    of each row; ``H_kv`` may divide ``H`` (grouped heads).
+    ``k_shared`` [B, 1, Lk, Ds]: one more key head whose columns every
+    query head's last ``Ds`` columns contract with, beside its own
+    ``k`` [B, H, Lk, D - Ds]."""
     out, _ = _flash_fwd(q, k, v, causal, _resolve_scale(scale, q),
-                        block_q, block_k, with_lse=False, window=window)
+                        block_q, block_k, with_lse=False, window=window,
+                        k_shared=k_shared)
     return out
 
 
@@ -484,21 +558,22 @@ FLASH_OUT_NAME = "flash_attention_out"
 FLASH_LSE_NAME = "flash_attention_lse"
 
 
-def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, window):
+def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, window,
+             k_shared=None):
     s = _resolve_scale(scale, q)
     out, lse = _flash_fwd(q, k, v, causal, s, block_q, block_k,
-                          with_lse=True, window=window)
+                          with_lse=True, window=window, k_shared=k_shared)
     out = checkpoint_name(out, FLASH_OUT_NAME)
     # in the kernel's lanes layout: one value a row would cost a slice
     # here and a broadcast in _flash_bwd for little memory (docs/kernels.md)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
-    return out, (q, k, v, out, lse, s)
+    return out, (q, k, v, k_shared, out, lse, s)
 
 
 def _vjp_bwd(causal, scale, block_q, block_k, window, res, g):
-    q, k, v, out, lse, s = res
+    q, k, v, k_shared, out, lse, s = res
     return _flash_bwd(q, k, v, out, lse, g, causal, s, block_q, block_k,
-                      window)
+                      window, k_shared)
 
 
 pallas_flash_attention_fwd.defvjp(_vjp_fwd, _vjp_bwd)

@@ -3,9 +3,14 @@
 against ``megablox.gmm`` at several tilings) and the attention kernels
 (this repo's flash kernels with a window and grouped KV heads against
 JAX's ``splash_attention``), forward + backward at the published
-widths. Run through the chip tool; prints one JSON line per reading.
+widths. ``--latent`` times instead the latent-attention decoder's two:
+the flash kernels at [1, 16, 8192, 192 | 128] with the rotary key read
+as one shared head against the keys joined in HBM before the call, and
+the grouped products at 1,408-wide experts under several tilings. Run
+through the chip tool; prints one JSON line per reading.
 
     python scripts/perf_sparse_decoder_kernels.py [--skip-splash] [--skip-grouped]
+    python scripts/perf_sparse_decoder_kernels.py --latent
 """
 
 import json
@@ -138,9 +143,121 @@ def attention(skip_splash: bool):
                    window=window, error=str(e)[:300])
 
 
+def latent_attention():
+    """Forward + backward from (q, k_nope, k_rot, v) to all four
+    gradients, so that the join, the repeat and the sum over heads of
+    the form that builds 192-wide keys in HBM are inside the time."""
+    from analytics_zoo_tpu.ops.attention import dot_product_attention
+
+    heads, nope, rot, v_dim = 16, 128, 64, 128
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q = jax.random.normal(ks[0], (1, heads, L, nope + rot), jnp.bfloat16)
+    k_nope = jax.random.normal(ks[1], (1, heads, L, nope), jnp.bfloat16)
+    k_rot = jax.random.normal(ks[2], (1, 1, L, rot), jnp.bfloat16)
+    v = jax.random.normal(ks[3], (1, heads, L, v_dim), jnp.bfloat16)
+    flops = 3 * (L * (L + 1) // 2) * 2 * (nope + rot + v_dim) * heads
+
+    def shared(q, k_nope, k_rot, v):
+        return dot_product_attention(q, k_nope, v, causal=True,
+                                     k_shared=k_rot)
+
+    def joined(q, k_nope, k_rot, v):
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rot, (1, heads, L, rot))], axis=-1)
+        return dot_product_attention(q, k, v, causal=True)
+
+    def padded(q, k_nope, k_rot, v):
+        """What a one-width kernel would need: V padded to 192."""
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rot, (1, heads, L, rot))], axis=-1)
+        wide = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, nope + rot - v_dim)))
+        return dot_product_attention(q, k, wide, causal=True)[..., :v_dim]
+
+    outs = {}
+    for name, fn in (("shared_rotary_head", shared),
+                     ("keys_joined_in_hbm", joined),
+                     ("values_padded_to_192", padded)):
+        def total(*a, fn=fn):
+            return jnp.sum(fn(*a).astype(jnp.float32))
+
+        outs[name] = jax.jit(fn)(q, k_nope, k_rot, v)
+        ms = timed(jax.jit(jax.grad(total, argnums=(0, 1, 2, 3))),
+                   q, k_nope, k_rot, v)
+        report(what="latent_attention_fwd_bwd", impl=name, ms=ms,
+               model_tflops=flops / ms / 1e9)
+        ms = timed(jax.jit(fn), q, k_nope, k_rot, v)
+        report(what="latent_attention_fwd", impl=name, ms=ms,
+               model_tflops=flops / 3 / ms / 1e9)
+    base = outs["keys_joined_in_hbm"].astype(jnp.float32)
+    for name, out in outs.items():
+        report(what="latent_attention_agreement", impl=name,
+               max_abs_diff_from_joined=float(jnp.max(jnp.abs(
+                   out.astype(jnp.float32) - base))))
+
+
+def latent_grouped(rows: int = 49152, held: int = 6144):
+    """SwiGLU over 8 experts [2048 -> 1408 -> 2048] with ``w1 | w3`` side
+    by side, as ``DroplessExperts`` runs it: ``grouped_dot`` (each pass
+    tiled for its own shapes) against ``megablox``' own VJP under one
+    tile, which at 1,408 = 11 x 128 does not divide: what the library
+    does then is in the error column and the time."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from analytics_zoo_tpu.keras.layers.moe import grouped_dot
+
+    d, width, experts = 2048, 1408, 8
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(ks[0], (rows, d), jnp.bfloat16)
+    w13 = jax.random.normal(ks[1], (experts, d, 2 * width),
+                            jnp.bfloat16) * 0.02
+    w2 = jax.random.normal(ks[2], (experts, width, d), jnp.bfloat16) * 0.02
+    sizes = jnp.full((experts,), held // experts, jnp.int32)
+    mask = (jnp.arange(rows) < held)[:, None]
+
+    def swiglu(dot):
+        def f(x, w13, w2):
+            ab = dot(x, w13)
+            h = jax.nn.silu(ab[:, :width]) * ab[:, width:]
+            return jnp.sum(jnp.where(mask, dot(h.astype(x.dtype), w2),
+                                     0).astype(jnp.float32))
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+    flops = 3 * 3 * 2 * held * d * width
+    impls = {"ragged_dot": lambda a, b: jax.lax.ragged_dot(a, b, sizes),
+             "grouped_dot_per_pass": lambda a, b: grouped_dot(a, b, sizes)}
+    for tiling in ((512, 1024, 1024), (512, 512, 512), (512, 256, 256),
+                   (512, 128, 128), (512, 1408, 1408)):
+        impls["gmm" + str(tiling)] = (
+            lambda a, b, t=tiling: gmm(a, b, sizes, a.dtype, t, None, None,
+                                       False, False))
+    want = None
+    for name, dot in impls.items():
+        try:
+            fn = swiglu(dot)
+            value, grads = fn(x, w13, w2)
+            got = [np.asarray(value, np.float32)] + [
+                np.asarray(g[:held] if g.shape[0] == rows else g,
+                           np.float32) for g in grads]
+            want = want or got
+            worst = max(float(np.max(np.abs(g - w)) / (np.max(np.abs(w))
+                                                       + 1e-30))
+                        for g, w in zip(got, want))
+            ms = timed(fn, x, w13, w2)
+            report(what="latent_swiglu_fwd_bwd", impl=name, rows=rows,
+                   held=held, ms=ms, tflops=flops / ms / 1e9,
+                   worst_rel_diff_from_ragged_dot=worst)
+        except Exception as e:  # a tiling the compiler refuses
+            report(what="latent_swiglu_fwd_bwd", impl=name, rows=rows,
+                   held=held, error=str(e)[:200])
+
+
 if __name__ == "__main__":
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("perf_sparse_decoder_kernels: needs a TPU")
+    if "--latent" in sys.argv:
+        latent_attention()
+        latent_grouped()
+        raise SystemExit(0)
     if "--skip-grouped" not in sys.argv:
         grouped(rows=8192, held=8192)
         grouped(rows=65536, held=8192)

@@ -40,3 +40,20 @@ def tmp_ckpt_dir(tmp_path):
     d = tmp_path / "ckpt"
     d.mkdir()
     return str(d)
+
+
+@pytest.fixture
+def compiled_anew():
+    """The step program of the 8-device CPU mesh holds ``while`` loops
+    with collectives in their bodies (GSPMD gathers the sharded tokens
+    inside the expert layer's bounded passes). Compiled, it runs; loaded
+    from the persistent compilation cache, XLA:CPU's executable
+    deadlocks in the first of them (jaxlib 0.9.0; the TPU loads its own
+    fine). So no cache here, read or written."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()

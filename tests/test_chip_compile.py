@@ -127,6 +127,40 @@ def test_window_and_grouped_heads_compile_at_8k(one_chip, window):
     assert f"(attention_flash{'_window' if window else ''})" in bwd
 
 
+def test_latent_attention_kernels_compile_at_8k(one_chip):
+    """Latent attention at its published widths through the dispatcher:
+    16 heads, queries and keys 192 wide (128 of the head's own + the 64
+    of one rotary key head that every head reads), values 128 wide,
+    L8192. Forward + logsumexp, dQ and dK/dV compile; the rotary key
+    reaches them as one head (no [1, 16, 8192, 192] keys in HBM), the
+    values stay 128 wide, and no [L, L] tensor exists."""
+    def on(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    args = (on((1, 16, 8192, 192)), on((1, 16, 8192, 128)),
+            on((1, 1, 8192, 64)), on((1, 16, 8192, 128)))
+
+    def attn(q, k_nope, k_rot, v):
+        return attention.dot_product_attention(q, k_nope, v, causal=True,
+                                               k_shared=k_rot)
+
+    fwd = jax.jit(attn).lower(*args).compile().as_text()
+    assert fwd.count("tpu_custom_call") == 1
+    bwd = jax.jit(jax.grad(lambda *a: _scalar(attn(*a)),
+                           argnums=(0, 1, 2, 3))).lower(
+        *args).compile().as_text()
+    assert bwd.count("tpu_custom_call") == 3  # fwd+lse, dq, dk/dv
+    assert "8192,8192" not in bwd
+    assert "(attention_flash_latent)" in bwd
+    kernels = [line for line in bwd.splitlines()
+               if "tpu_custom_call" in line]
+    # the joined keys exist in VMEM only: no kernel reads or writes a
+    # 192-wide key or a 192-wide value
+    assert all("bf16[16,8192,192]" in line for line in kernels)   # q / dq
+    assert sum(line.count("bf16[16,8192,192]") for line in kernels) == 4
+    assert all("bf16[1,8192,64]" in line for line in kernels)
+
+
 def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
     """16 of 128 experts at d 2048 / width 1024 over 8,192 tokens,
     forward and backward: the grouped products are Pallas kernels under

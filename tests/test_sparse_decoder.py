@@ -835,23 +835,6 @@ def test_names_outside_a_policy_change_no_program(monkeypatch, window):
     assert named == bare
 
 
-@pytest.fixture
-def compiled_anew():
-    """The step program of the 8-device CPU mesh holds ``while`` loops
-    with collectives in their bodies (GSPMD gathers the sharded tokens
-    inside the bounded passes). Compiled, it runs; loaded from the
-    persistent compilation cache, XLA:CPU's executable deadlocks in the
-    first of them (jaxlib 0.9.0; the TPU loads its own fine). So no
-    cache here, read or written."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
-
-
 def test_fit_updates_router_state_and_publishes_counters(monkeypatch,
                                                          compiled_anew):
     """Through ``compile`` / ``fit`` / ``predict``: the bias and the
