@@ -4,13 +4,21 @@ The fused attention hot op the framework owns end-to-end. Blockwise
 online-softmax forward: the grid walks (batch*heads, q-blocks, kv-blocks)
 with the kv dimension innermost; running (max, sum, acc) live in VMEM
 scratch across kv iterations, so the [L, L] score matrix never exists in
-HBM. The forward also emits the per-row logsumexp, which the backward
-kernels use to regenerate probabilities blockwise:
+HBM. The forward also emits the per-row logsumexp, from which the
+backward regenerates each block's probabilities, once:
 
-- dQ kernel: grid (BH, q-blocks, kv-blocks), accumulates
-  dq_i = sum_j (p_ij * (do_i v_j^T - delta_i)) k_j in VMEM scratch;
-- dK/dV kernel: grid (BH, kv-blocks, q-blocks), accumulates
-  dv_j = sum_i p_ij^T do_i and dk_j = sum_i ds_ij^T q_i.
+- one backward kernel: grid (KV heads, query heads of the group, the
+  (kv-block, q-block) pairs that hold an allowed score); per pair
+  s = q k^T, the mask, p = exp(s - lse), dp = do v^T and
+  ds = p (dp - delta) feed dv_j += p^T do_i, dk_j += ds^T q_i and
+  dq_i += ds k_j: five MXU products. dq of the current query head and
+  dk, dv of the KV head are whole-sequence float32 accumulators in
+  VMEM, so the call asks for the VMEM they need
+  (``_fused_bwd_vmem_bytes``);
+- past ``FUSED_BWD_VMEM_BUDGET`` (d = 128 in bfloat16: beyond ~20k) a dQ
+  kernel, grid (BH, q-blocks, kv-blocks), and a dK/dV kernel, grid
+  (BH, kv-blocks, q-blocks), hold only blocks and each regenerate the
+  scores: seven products a pair. ``flash_backward_path`` is the rule.
 
 Training memory is O(L) on this kernel (saves only q, k, v, o, lse) --
 the flash backward recurrence of Dao et al., re-derived for the TPU
@@ -24,25 +32,27 @@ full); callers fall back to the jnp path otherwise. Causal masking
 aligns the diagonal bottom-right (tril k=lk-lq) to match
 ``reference_attention``; causal with len(q) > len(kv) is rejected.
 
-Two variants ride the same three kernels (docs/kernels.md):
+Two variants ride the same kernels (docs/kernels.md):
 
 - **window** (``window=W``, causal only): row i reads keys
-  ``i - W < j <= i``. The sequential grid dimension is *relative*: it
-  has only as many steps as the widest run of blocks any row block
-  needs (``_steps``), step t of row block qi is block ``lo(qi) + t``
-  (``_kv_bounds``), and steps past ``hi(qi)`` neither compute nor
-  fetch (their index map stays on block ``hi``). A plain causal call
-  uses the same bounds with ``lo = 0``, so the blocks above the
-  diagonal are no longer fetched either.
+  ``i - W < j <= i``. The forward's sequential grid dimension is
+  *relative*: it has only as many steps as the widest run of blocks
+  any row block needs (``_steps``), step t of row block qi is block
+  ``lo(qi) + t`` (``_kv_bounds``), and steps past ``hi(qi)`` neither
+  compute nor fetch (their index map stays on block ``hi``). A plain
+  causal call uses the same bounds with ``lo = 0``, so the blocks above
+  the diagonal are not fetched either. The one-kernel backward walks a
+  list of exactly the pairs inside the bounds (``_pair_walk``).
 - **grouped KV heads**: K and V may carry fewer heads than Q
   (``h % h_kv == 0``); query head n reads KV head ``n // (h / h_kv)``
-  through the index maps, so K/V are never repeated in HBM. The dK/dV
-  kernel walks the query heads of its group in its sequential
-  dimension and sums them in VMEM.
+  through the index maps, so K/V are never repeated in HBM. The
+  backward walks the query heads of a KV head's group in a sequential
+  grid dimension and sums their dK/dV in VMEM.
 
-The grid is declared (parallel, parallel, arbitrary) so Mosaic
-pipelines the sequential kv/q accumulation dimension while batch and
-row blocks schedule freely.
+The forward's grid is declared (parallel, parallel, arbitrary) so Mosaic
+pipelines the sequential kv accumulation dimension while batch and row
+blocks schedule freely; the backward's is (parallel, arbitrary,
+arbitrary): its accumulators outlive all but the KV head.
 """
 
 from __future__ import annotations
@@ -63,15 +73,11 @@ NEG_INF = -1e30
 def _auto_block(length: int, cap: int = 1024) -> int:
     """Largest 128-multiple block <= ``cap`` dividing ``length``: big
     blocks amortize the per-block VPU softmax work against the MXU
-    matmuls (measured ~2.5x fwd+bwd at L=4096 vs 128-blocks) while
-    staying inside VMEM (s/p tiles at [1024, 1024] f32 = 4 MB each).
-
-    The backward kernels pass ``_bwd_cap``: 512 at d >= 128 -- they
-    hold three [BQ, BK] f32 intermediates (s, p, dp) plus
-    q/k/v/do/lse/delta tiles and scratch, which at 1024^2 blocks
-    (~12 MB of intermediates alone) would crowd the ~16 MB per-core
-    VMEM budget -- but 1024 at d <= 64 / L >= 2048, where the halved
-    tiles fit and measure 6-7% faster (see _bwd_cap)."""
+    matmuls (measured ~2.5x fwd+bwd at L=4096 vs 128-blocks). The
+    forward at [1024, 1024] holds s and p tiles of 4 MB each in float32,
+    ~11 MB with its operand tiles: inside the 16 MiB of VMEM a kernel
+    gets when it asks for none (the chip has 128 MiB). The backward
+    chooses by ``_bwd_blocks`` and asks for what it needs."""
     for b in (1024, 896, 768, 640, 512, 384, 256, 128):
         if b <= cap and length % b == 0:
             return b
@@ -290,7 +296,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
-        compiler_params=_grid_semantics(),
+        compiler_params=_compiler_params(),
         interpret=_interpret(),
     )(*operands)
     out = res[0]
@@ -305,21 +311,27 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _grid_semantics():
-    """All three kernels iterate their LAST grid dim sequentially (the
-    online-softmax / gradient accumulation over kv- or q-blocks) while
-    the leading (batch*heads, row-block) dims are independent; telling
-    Mosaic so lets it overlap the next block's HBM->VMEM copies with
-    the current block's compute instead of assuming a serial grid."""
+# Mosaic's own scratch beside the buffers a kernel declares.
+_VMEM_SLACK = 4 * 2 ** 20
+
+
+def _compiler_params(semantics=("parallel", "parallel", "arbitrary"),
+                     **params):
+    """The forward and the two block-wise backward kernels iterate their
+    LAST grid dim sequentially (the online-softmax / gradient
+    accumulation over kv- or q-blocks) while the leading (batch*heads,
+    row-block) dims are independent; telling Mosaic so lets it overlap
+    the next block's HBM->VMEM copies with the current block's compute
+    instead of assuming a serial grid. The one-kernel backward names its
+    own semantics and the VMEM it needs."""
     if _interpret():
         return None  # interpret mode takes no TPU compiler params
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=semantics, **params)
 
 
 def _probs_and_ds(q, k, v, do, lse, delta, qi, ki, scale, geometry):
     """The block's probabilities and score gradients, regenerated from
-    the saved row logsumexp (shared by the two backward kernels)."""
+    the saved row logsumexp (one body for every backward kernel)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -329,6 +341,32 @@ def _probs_and_ds(q, k, v, do, lse, delta, qi, ki, scale, geometry):
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)         # [BQ, BK]
     return p, p * (dp - delta) * scale
+
+
+def _write_dkv(dk_scr, dv_scr, dk_ref, dv_ref, dks_ref):
+    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+    if dks_ref is None:
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+    else:
+        d_k = dk_ref.shape[-1]
+        dk_ref[0] = dk_scr[:, :d_k].astype(dk_ref.dtype)
+        dks_ref[0] = dk_scr[:, d_k:]
+
+
+def _bwd_in_specs(geometry: dict, d: int, d_k: int, d_v: int, q_map,
+                  kv_map, shared_map=None) -> list:
+    """Block specs of a backward kernel's operands (q, k, v, do, lse,
+    delta and, with ``shared_map``, the shared key head) under its
+    index maps."""
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
+    row_spec = pl.BlockSpec((1, block_q, 128), q_map)
+    specs = [pl.BlockSpec((1, block_q, d), q_map),
+             pl.BlockSpec((1, block_k, d_k), kv_map),
+             pl.BlockSpec((1, block_k, d_v), kv_map),
+             pl.BlockSpec((1, block_q, d_v), q_map), row_spec, row_spec]
+    if shared_map is not None:
+        specs.append(pl.BlockSpec((1, block_k, d - d_k), shared_map))
+    return specs
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -397,23 +435,240 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
-        if shared:
-            d_k = dk_ref.shape[-1]
-            dk_ref[0] = dk_scr[:, :d_k].astype(dk_ref.dtype)
-            dks_ref[0] = dk_scr[:, d_k:]
-        else:
-            dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        _write_dkv(dk_scr, dv_scr, dk_ref, dv_ref, dks_ref)
 
 
-def _bwd_cap(length: int, d: int) -> int:
-    """Backward block cap: 512 keeps the three [BQ, BK] f32
-    intermediates inside VMEM at d=128; at d <= 64 every q/k/v/do tile
-    halves, so 1024-blocks fit AND measure 6-7% faster at L >= 2048
-    (docs/kernels.md "Measured crossover") -- but only when the sequential
-    grid dim keeps >= 2 steps, else Mosaic has nothing to pipeline
-    and L=1024 regresses ~25%."""
-    return 1024 if (d <= 64 and length >= 2048) else 512
+def _flash_bwd_split(operands, dq_shape, dkv_shape, scale: float,
+                     group: int, h: int, geometry: dict, q_geometry: dict):
+    """The backward as two kernels that hold only blocks, for sequences
+    whose whole-sequence accumulators pass ``FUSED_BWD_VMEM_BUDGET``:
+    dQ walks (head, q-block, kv-blocks), dK/dV walks (KV head, kv-block,
+    group's heads x q-blocks); each regenerates the scores."""
+    shared = len(dkv_shape) == 3
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
+    (bh, l, d), (bh_kv, lk, d_k) = dq_shape.shape, dkv_shape[0].shape
+    d_v = dkv_shape[1].shape[-1]
+    nq, nk = l // block_q, lk // block_k
+
+    def q_map(bh_, qi, step):
+        return bh_, qi, 0
+
+    def kv_map(bh_, qi, step, heads=group):
+        lo, hi = _traced(_kv_bounds, qi, **geometry)
+        return bh_ // heads, jnp.minimum(lo + step, hi), 0
+
+    dq = pl.pallas_call(
+        functools.partial(_flash_dq_kernel, scale=scale, shared=shared,
+                          geometry=geometry),
+        grid=(bh, nq, _steps(_kv_bounds, nq, **geometry)),
+        in_specs=_bwd_in_specs(
+            geometry, d, d_k, d_v, q_map, kv_map,
+            functools.partial(kv_map, heads=h) if shared else None),
+        out_specs=pl.BlockSpec((1, block_q, d), q_map),
+        out_shape=dq_shape,
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=_interpret(),
+    )(*operands)
+
+    q_steps = _steps(_q_bounds, nk, **q_geometry)
+
+    def q_map2(bh_, ki, step):
+        lo, hi = _traced(_q_bounds, ki, **q_geometry)
+        return (bh_ * group + step // q_steps,
+                jnp.maximum(jnp.minimum(lo + step % q_steps, hi), 0), 0)
+
+    def kv_map2(bh_, ki, step):
+        return bh_, ki, 0
+
+    return [dq] + list(pl.pallas_call(
+        functools.partial(_flash_dkv_kernel, scale=scale, q_steps=q_steps,
+                          shared=shared, geometry=geometry,
+                          q_geometry=q_geometry),
+        grid=(bh_kv, nk, group * q_steps),
+        in_specs=_bwd_in_specs(
+            geometry, d, d_k, d_v, q_map2, kv_map2,
+            (lambda bh_, ki, step: (bh_ // h, ki, 0)) if shared else None),
+        out_specs=[pl.BlockSpec((1, block_k) + s.shape[2:], kv_map2)
+                   for s in dkv_shape],
+        out_shape=dkv_shape,
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=_interpret(),
+    )(*operands))
+
+
+def _flash_bwd_kernel(ki_ref, qi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, *rest, scale: float, shared: bool,
+                      geometry: dict):
+    """One (kv-block, q-block) pair of one query head: the scores are
+    regenerated once and feed all three gradients. The grid walks (KV
+    head, query head of its group, the pairs that hold an allowed score:
+    ``_pair_walk``'s two tables, read from SMEM); ``dq`` of the current
+    query head and ``dk``/``dv`` of the KV head live in float32 for the
+    whole sequence in VMEM, so ``dq`` outlives the kv-blocks and
+    ``dk``/``dv`` the group's heads. With a shared key head ``dk_scr``
+    holds both parts and the shared columns go out per head in float32
+    (summed over the heads by the caller)."""
+    if shared:
+        (ks_ref, dq_ref, dk_ref, dv_ref, dks_ref,
+         dq_scr, dk_scr, dv_scr) = rest
+    else:
+        ks_ref, dks_ref = None, None
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    head, pair = pl.program_id(1), pl.program_id(2)
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
+    ki, qi = ki_ref[pair], qi_ref[pair]
+
+    @pl.when((pair == 0) & (head == 0))
+    def _init_kv_head():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(pair == 0)
+    def _init_query_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    q = q_ref[0]                                    # [BQ, D]
+    k = _keys(k_ref, ks_ref)                        # [BK, D]
+    do = do_ref[0]                                  # [BQ, Dv]
+    p, ds = _probs_and_ds(
+        q, k, v_ref[0], do, lse_ref[0][:, :1], delta_ref[0][:, :1],
+        qi, ki, scale, geometry)
+    ds = ds.astype(q.dtype)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    keys = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+    dv_scr[keys, :] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)         # [BK, Dv]
+    dk_scr[keys, :] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)         # [BK, D]
+    dq_scr[rows, :] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)         # [BQ, D]
+
+    last = pair == pl.num_programs(2) - 1
+
+    @pl.when(last)
+    def _finish_query_head():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+    @pl.when(last & (head == pl.num_programs(1) - 1))
+    def _finish_kv_head():
+        _write_dkv(dk_scr, dv_scr, dk_ref, dv_ref, dks_ref)
+
+
+def _pair_walk(nk: int, q_geometry: dict):
+    """The (kv-block, q-block) pairs that hold an allowed score, as two
+    tables in the order the one-kernel backward walks them: kv-blocks
+    outermost, so K and V are fetched once a run of pairs."""
+    pairs = []
+    for ki in range(nk):
+        lo, hi = _q_bounds(ki, **q_geometry)
+        pairs += [(ki, qi) for qi in range(lo, hi + 1)]
+    return tuple(jnp.asarray(np.asarray(t, np.int32)) for t in zip(*pairs))
+
+
+def _flash_bwd_fused(operands, dq_shape, dkv_shape, scale: float,
+                     group: int, h: int, geometry: dict, q_geometry: dict):
+    """The backward as one kernel over ``_pair_walk``'s pairs, with the
+    whole-sequence accumulators and output blocks that
+    ``_fused_bwd_vmem_bytes`` counts and the call asks VMEM for."""
+    shared = len(dkv_shape) == 3
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
+    (_, l, d), (bh_kv, lk, d_k) = dq_shape.shape, dkv_shape[0].shape
+    d_v = dkv_shape[1].shape[-1]
+
+    def q_map(kv_head, head, pair, ki_ref, qi_ref):
+        return kv_head * group + head, qi_ref[pair], 0
+
+    def kv_map(kv_head, head, pair, ki_ref, qi_ref, heads=1):
+        return kv_head // heads, ki_ref[pair], 0
+
+    def whole_q(kv_head, head, pair, ki_ref, qi_ref):
+        return kv_head * group + head, 0, 0
+
+    def whole_kv(kv_head, head, pair, ki_ref, qi_ref):
+        return kv_head, 0, 0
+
+    walk = _pair_walk(lk // block_k, q_geometry)
+    vmem = _fused_bwd_vmem_bytes(l, lk, d, d_k, d_v, dq_shape.dtype.itemsize,
+                                 block_q, block_k)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, shared=shared,
+                          geometry=geometry),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh_kv, group, walk[0].shape[0]),
+            in_specs=_bwd_in_specs(
+                geometry, d, d_k, d_v, q_map, kv_map,
+                functools.partial(kv_map, heads=h) if shared else None),
+            out_specs=[pl.BlockSpec((1, l, d), whole_q)] + [
+                pl.BlockSpec((1,) + s.shape[1:], whole_kv)
+                for s in dkv_shape],
+            scratch_shapes=[pltpu.VMEM((l, d), jnp.float32),
+                            pltpu.VMEM((lk, d), jnp.float32),
+                            pltpu.VMEM((lk, d_v), jnp.float32)]),
+        out_shape=[dq_shape] + dkv_shape,
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + _VMEM_SLACK),
+        interpret=_interpret(),
+    )(*walk, *operands)
+
+
+# What the one-kernel backward may ask of the chip's VMEM: three
+# quarters of a v5e's 128 MiB (a kernel that asks for nothing gets
+# 16 MiB). A call past it (d = 128 in bfloat16: beyond ~20k) takes the
+# two kernels, which hold only blocks. The shapes decide; no option does.
+FUSED_BWD_VMEM_BUDGET = 96 * 2 ** 20
+
+
+def _fused_bwd_vmem_bytes(l: int, lk: int, d: int, d_k: int, d_v: int,
+                          itemsize: int, block_q: int, block_k: int) -> int:
+    """VMEM the one-kernel backward holds, every width rounded up to
+    whole 128-lane tiles as VMEM lays it out: the three whole-sequence
+    float32 accumulators, its output blocks (whole sequences too, two
+    buffers each), the operand tiles (two buffers each), and the
+    [BQ, BK] intermediates (s, p, dp, ds in float32; p and ds again in
+    the operand dtype, each with a transposed copy for its product)."""
+    d, d_s, d_k, d_v = (-(-n // 128) * 128 for n in (d, d - d_k, d_k, d_v))
+    accumulators = 4 * (l * d + lk * d + lk * d_v)
+    outputs = 2 * (itemsize * (l * d + lk * d_k + lk * d_v) + 4 * lk * d_s)
+    tiles = 2 * (itemsize * (block_q * (d + d_v)
+                             + block_k * (d_k + d_s + d_v))
+                 + 2 * 4 * block_q * 128)
+    scores = (4 * 4 + 4 * itemsize) * block_q * block_k
+    return accumulators + outputs + tiles + scores
+
+
+def _bwd_blocks(l: int, lk: int, d: int, window: Optional[int]) -> tuple:
+    """The backward's (block_q, block_k) where the caller names none:
+    512, and 1024 where it measured faster (docs/kernels.md): at
+    d <= 64 from L 2048 on (every tile halves), and without a window
+    from L 8192 on, where the diagonal's whole blocks are a ninth of the
+    walk; a window 2,048 wide walks 92 block-units of 512^2 a head at
+    1024 where 512 walks 70. Below 2048 a side, 1024 would leave one
+    step a head and nothing to pipeline."""
+    short = min(l, lk)
+    big = short >= 2048 and (d <= 64 or (window is None and short >= 8192))
+    return tuple(_auto_block(n, 1024 if big else 512) for n in (l, lk))
+
+
+def flash_backward_path(l: int, lk: int, d: int, d_k: int, d_v: int,
+                        itemsize: int, window: Optional[int] = None,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None) -> str:
+    """``"fused"`` (one kernel; whole-sequence accumulators in VMEM) or
+    ``"split"`` (a dQ and a dK/dV kernel that each regenerate the
+    scores and hold only blocks). Decided while the step is traced,
+    from the shapes alone."""
+    auto_q, auto_k = _bwd_blocks(l, lk, d, window)
+    need = _fused_bwd_vmem_bytes(l, lk, d, d_k, d_v, itemsize,
+                                 block_q or auto_q, block_k or auto_k)
+    return "fused" if need <= FUSED_BWD_VMEM_BUDGET else "split"
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
@@ -424,8 +679,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
     d_v = v.shape[-1]
     group = h // h_kv
     shared = k_shared is not None
-    block_q = block_q or _auto_block(l, cap=_bwd_cap(l, d))
-    block_k = block_k or _auto_block(lk, cap=_bwd_cap(lk, d))
+    auto_q, auto_k = _bwd_blocks(l, lk, d, window)
+    block_q, block_k = block_q or auto_q, block_k or auto_k
     bh, bh_kv = b * h, b * h_kv
     nq, nk = l // block_q, lk // block_k
     qr = q.reshape(bh, l, d)
@@ -439,81 +694,26 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
     delta = jnp.broadcast_to(delta, (bh, l, 128))
     geometry = dict(block_q=block_q, block_k=block_k, nk=nk,
                     causal=causal, offset=lk - l, window=window)
+    # the q-blocks that read a kv-block: the walk of the fused kernel
+    # and of the dK/dV kernel
+    q_geometry = dict(block_q=block_q, block_k=block_k, nq=nq,
+                      causal=causal, offset=lk - l, window=window)
     operands = [qr, kr, vr, dor, lse, delta]
     if shared:
         operands.append(k_shared.reshape(b, lk, d - d_k))
-
-    def q_map(bh_, qi, step):
-        return bh_, qi, 0
-
-    def kv_map(bh_, qi, step, heads=group):
-        lo, hi = _traced(_kv_bounds, qi, **geometry)
-        return bh_ // heads, jnp.minimum(lo + step, hi), 0
-
-    q_spec = pl.BlockSpec((1, block_q, d), q_map)
-    do_spec = pl.BlockSpec((1, block_q, d_v), q_map)
-    k_spec = pl.BlockSpec((1, block_k, d_k), kv_map)
-    v_spec = pl.BlockSpec((1, block_k, d_v), kv_map)
-    row_spec = pl.BlockSpec((1, block_q, 128), q_map)
-    in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
-    if shared:
-        in_specs.append(pl.BlockSpec(
-            (1, block_k, d - d_k), functools.partial(kv_map, heads=h)))
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale, shared=shared,
-                          geometry=geometry),
-        grid=(bh, nq, _steps(_kv_bounds, nq, **geometry)),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), q_map),
-        out_shape=jax.ShapeDtypeStruct((bh, l, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_grid_semantics(),
-        interpret=_interpret(),
-    )(*operands)
-
-    # dk/dv walk kv-blocks in the outer grid dim; the sequential one
-    # covers (query head of the group) x (q-blocks that read the block)
-    q_geometry = dict(block_q=block_q, block_k=block_k, nq=nq,
-                      causal=causal, offset=lk - l, window=window)
-    q_steps = _steps(_q_bounds, nk, **q_geometry)
-
-    def q_map2(bh_, ki, step):
-        lo, hi = _traced(_q_bounds, ki, **q_geometry)
-        return (bh_ * group + step // q_steps,
-                jnp.maximum(jnp.minimum(lo + step % q_steps, hi), 0), 0)
-
-    def kv_map2(bh_, ki, step):
-        return bh_, ki, 0
-
-    q_spec2 = pl.BlockSpec((1, block_q, d), q_map2)
-    do_spec2 = pl.BlockSpec((1, block_q, d_v), q_map2)
-    k_spec2 = pl.BlockSpec((1, block_k, d_k), kv_map2)
-    v_spec2 = pl.BlockSpec((1, block_k, d_v), kv_map2)
-    row_spec2 = pl.BlockSpec((1, block_q, 128), q_map2)
-    in_specs = [q_spec2, k_spec2, v_spec2, do_spec2, row_spec2, row_spec2]
-    out_specs = [k_spec2, v_spec2]
-    out_shape = [jax.ShapeDtypeStruct((bh_kv, lk, d_k), k.dtype),
+    dkv_shape = [jax.ShapeDtypeStruct((bh_kv, lk, d_k), k.dtype),
                  jax.ShapeDtypeStruct((bh_kv, lk, d_v), v.dtype)]
     if shared:
-        in_specs.append(pl.BlockSpec((1, block_k, d - d_k),
-                                     lambda bh_, ki, step: (bh_ // h, ki, 0)))
         # each head's part of the shared head's gradient, in float32
-        out_specs.append(pl.BlockSpec((1, block_k, d - d_k), kv_map2))
-        out_shape.append(jax.ShapeDtypeStruct((bh_kv, lk, d - d_k),
+        dkv_shape.append(jax.ShapeDtypeStruct((bh_kv, lk, d - d_k),
                                               jnp.float32))
-    dk, dv, *dks = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, scale=scale, q_steps=q_steps,
-                          shared=shared, geometry=geometry,
-                          q_geometry=q_geometry),
-        grid=(bh_kv, nk, group * q_steps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
-        compiler_params=_grid_semantics(),
-        interpret=_interpret(),
-    )(*operands)
+    dq_shape = jax.ShapeDtypeStruct((bh, l, d), q.dtype)
+    path = flash_backward_path(l, lk, d, d_k, d_v, q.dtype.itemsize, window,
+                               block_q, block_k)
+    dq, dk, dv, *dks = (
+        _flash_bwd_fused if path == "fused" else _flash_bwd_split)(
+            operands, dq_shape, dkv_shape, scale, group, h, geometry,
+            q_geometry)
     grads = (dq.reshape(b, h, l, d), dk.reshape(b, h_kv, lk, d_k),
              dv.reshape(b, h_kv, lk, d_v))
     if not shared:

@@ -6,11 +6,19 @@ JAX's ``splash_attention``), forward + backward at the published
 widths. ``--latent`` times instead the latent-attention decoder's two:
 the flash kernels at [1, 16, 8192, 192 | 128] with the rotary key read
 as one shared head against the keys joined in HBM before the call, and
-the grouped products at 1,408-wide experts under several tilings. Run
+the grouped products at 1,408-wide experts under several tilings.
+``--backward`` times the flash backward alone (``_flash_bwd`` on a kept
+forward's output and logsumexp) at the three published shapes under
+several block choices, beside forward + backward through the
+dispatcher, and says how far its gradients lie from the two-kernel
+path's; ``--repo <checkout>`` imports the package from another checkout
+(the parent commit unpacked), for the other side of the table. Run
 through the chip tool; prints one JSON line per reading.
 
     python scripts/perf_sparse_decoder_kernels.py [--skip-splash] [--skip-grouped]
     python scripts/perf_sparse_decoder_kernels.py --latent
+    python scripts/perf_sparse_decoder_kernels.py --backward [--repo <checkout>]
+    JAX_PLATFORMS=cpu python scripts/perf_sparse_decoder_kernels.py --rehearse
 """
 
 import json
@@ -18,7 +26,10 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = (os.path.abspath(sys.argv[sys.argv.index("--repo") + 1])
+        if "--repo" in sys.argv
+        else os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
 
 import jax
 import jax.numpy as jnp
@@ -195,6 +206,82 @@ def latent_attention():
                    out.astype(jnp.float32) - base))))
 
 
+def backward(small: bool = False):
+    """The flash backward alone at the three published shapes (the
+    latent layer, the full and the window GQA layer), by block choice."""
+    from analytics_zoo_tpu.ops import pallas_attention as pa
+    from analytics_zoo_tpu.ops.attention import dot_product_attention
+
+    l = 1024 if small else L
+    window = 256 if small else WINDOW
+    causal_pairs = l * (l + 1) // 2
+    shapes = {  # name: (heads, kv heads, d, shared columns, d_v, window)
+        "latent": (16, 16, 192, 64, 128, None),
+        "full_gqa": (HEADS, KV_HEADS, HEAD_DIM, 0, HEAD_DIM, None),
+        "window_gqa": (HEADS, KV_HEADS, HEAD_DIM, 0, HEAD_DIM, window),
+    }
+    for name, (h, h_kv, d, d_s, d_v, win) in shapes.items():
+        ks = jax.random.split(jax.random.PRNGKey(3), 5)
+        q = jax.random.normal(ks[0], (1, h, l, d), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (1, h_kv, l, d - d_s), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (1, h_kv, l, d_v), jnp.bfloat16)
+        k_s = (jax.random.normal(ks[3], (1, 1, l, d_s), jnp.bfloat16)
+               if d_s else None)
+        g = jax.random.normal(ks[4], (1, h, l, d_v), jnp.bfloat16)
+        pairs = (causal_pairs if win is None
+                 else win * (win + 1) // 2 + (l - win) * win)
+        flops = pairs * 2 * (d + d_v) * h      # one pass; backward is two
+        scale = 1.0 / np.sqrt(d)
+        out, lse = jax.jit(lambda q, k, v, k_s: pa._flash_fwd(
+            q, k, v, True, scale, None, None, with_lse=True, window=win,
+            k_shared=k_s))(q, k, v, k_s)
+
+        def bwd(blocks):
+            return jax.jit(lambda q, k, v, out, lse, g, k_s: pa._flash_bwd(
+                q, k, v, out, lse, g, True, scale, *blocks, win, k_s))
+
+        def total(q, k, v, k_s):
+            return jnp.sum(dot_product_attention(
+                q, k, v, causal=True, window=win, k_shared=k_s).astype(
+                    jnp.float32))
+
+        ms = timed(jax.jit(jax.grad(total, argnums=(0, 1, 2))), q, k, v, k_s,
+                   reps=10)
+        report(what="flash_fwd_bwd", shape=name, ms=ms,
+               model_tflops=3 * flops / ms / 1e9)
+        grads = {}
+        for blocks in ((None, None), (512, 512), (512, 1024), (1024, 512),
+                       (1024, 1024), (256, 512), (256, 1024)):
+            try:
+                fn = bwd(blocks)
+                grads[blocks] = fn(q, k, v, out, lse, g, k_s)
+                ms = timed(fn, q, k, v, out, lse, g, k_s, reps=10)
+                report(what="flash_bwd", shape=name, blocks=blocks, ms=ms,
+                       model_tflops=2 * flops / ms / 1e9)
+            except Exception as e:  # blocks the compiler refuses
+                report(what="flash_bwd", shape=name, blocks=blocks,
+                       error=str(e)[:200])
+        if not hasattr(pa, "FUSED_BWD_VMEM_BUDGET"):
+            continue
+        budget, pa.FUSED_BWD_VMEM_BUDGET = pa.FUSED_BWD_VMEM_BUDGET, 0
+        try:
+            fn = bwd((None, None))
+            split = fn(q, k, v, out, lse, g, k_s)
+            ms = timed(fn, q, k, v, out, lse, g, k_s, reps=10)
+        finally:
+            pa.FUSED_BWD_VMEM_BUDGET = budget
+        report(what="flash_bwd", shape=name, blocks="two kernels", ms=ms,
+               model_tflops=2 * flops / ms / 1e9)
+        for blocks, got in grads.items():
+            worst = max(
+                float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32)))
+                      / jnp.max(jnp.abs(b.astype(jnp.float32))))
+                for a, b in zip(got, split) if a is not None)
+            report(what="flash_bwd_agreement", shape=name, blocks=blocks,
+                   worst_rel_diff_from_two_kernels=worst)
+
+
 def latent_grouped(rows: int = 49152, held: int = 6144):
     """SwiGLU over 8 experts [2048 -> 1408 -> 2048] with ``w1 | w3`` side
     by side, as ``DroplessExperts`` runs it: ``grouped_dot`` (each pass
@@ -252,8 +339,14 @@ def latent_grouped(rows: int = 49152, held: int = 6144):
 
 
 if __name__ == "__main__":
+    if "--rehearse" in sys.argv:   # the CPU, a tiny size: checks the script
+        backward(small=True)
+        raise SystemExit(0)
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("perf_sparse_decoder_kernels: needs a TPU")
+    if "--backward" in sys.argv:
+        backward()
+        raise SystemExit(0)
     if "--latent" in sys.argv:
         latent_attention()
         latent_grouped()

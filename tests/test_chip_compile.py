@@ -80,7 +80,32 @@ def test_owned_flash_compiles_forward_and_backward(one_chip, shape,
     bwd = jax.jit(jax.grad(lambda q, k, v: _scalar(attn(q, k, v)),
                            argnums=(0, 1, 2))).lower(
         *args).compile().as_text()
-    assert bwd.count("tpu_custom_call") == 3  # fwd+lse, dq, dk/dv
+    assert bwd.count("tpu_custom_call") == 2  # fwd+lse, one backward
+
+
+@pytest.mark.parametrize("length,path,kernels", [
+    (16384, "fused", 1),     # the whole-sequence accumulators fit
+    (32768, "split", 2),     # past the budget: dQ and dK/dV hold blocks
+])
+def test_backward_compiles_on_each_side_of_the_vmem_budget(
+        one_chip, length, path, kernels):
+    """The one-kernel backward asks for the VMEM its whole-sequence
+    accumulators need, computed from its buffers; past
+    ``FUSED_BWD_VMEM_BUDGET`` the two block-wise kernels take over. Both
+    compile at d = 128, and the rule is a function of the shapes."""
+    assert pallas_attention.flash_backward_path(
+        length, length, 128, 128, 128, 2) == path
+    q, k, v = _qkv((1, 2, length, 128), one_chip)
+    lse = jax.ShapeDtypeStruct((2, length, 128), jnp.float32,
+                               sharding=one_chip)
+
+    def backward(q, k, v, out, lse, g):
+        return pallas_attention._flash_bwd(q, k, v, out, lse, g, True,
+                                           0.088, None, None)
+
+    text = jax.jit(backward).lower(q, k, v, q, lse, q).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
+    assert f"{length},{length}" not in text     # no [L, L] tensor
 
 
 def test_dispatcher_padding_mask_path_compiles(one_chip):
@@ -120,7 +145,7 @@ def test_window_and_grouped_heads_compile_at_8k(one_chip, window):
     bwd = jax.jit(jax.grad(lambda q, k, v: _scalar(attn(q, k, v)),
                            argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    assert bwd.count("tpu_custom_call") == 3  # fwd+lse, dq, dk/dv
+    assert bwd.count("tpu_custom_call") == 2  # fwd+lse, one backward
     assert "8192,8192" not in bwd
     assert "bf16[1,32,8192,128]" in bwd       # q, and never K/V:
     assert "bf16[32,8192,128]" in bwd
@@ -131,9 +156,9 @@ def test_latent_attention_kernels_compile_at_8k(one_chip):
     """Latent attention at its published widths through the dispatcher:
     16 heads, queries and keys 192 wide (128 of the head's own + the 64
     of one rotary key head that every head reads), values 128 wide,
-    L8192. Forward + logsumexp, dQ and dK/dV compile; the rotary key
-    reaches them as one head (no [1, 16, 8192, 192] keys in HBM), the
-    values stay 128 wide, and no [L, L] tensor exists."""
+    L8192. Forward + logsumexp and the one backward kernel compile; the
+    rotary key reaches them as one head (no [1, 16, 8192, 192] keys in
+    HBM), the values stay 128 wide, and no [L, L] tensor exists."""
     def on(shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
@@ -149,7 +174,7 @@ def test_latent_attention_kernels_compile_at_8k(one_chip):
     bwd = jax.jit(jax.grad(lambda *a: _scalar(attn(*a)),
                            argnums=(0, 1, 2, 3))).lower(
         *args).compile().as_text()
-    assert bwd.count("tpu_custom_call") == 3  # fwd+lse, dq, dk/dv
+    assert bwd.count("tpu_custom_call") == 2  # fwd+lse, one backward
     assert "8192,8192" not in bwd
     assert "(attention_flash_latent)" in bwd
     kernels = [line for line in bwd.splitlines()
@@ -157,7 +182,7 @@ def test_latent_attention_kernels_compile_at_8k(one_chip):
     # the joined keys exist in VMEM only: no kernel reads or writes a
     # 192-wide key or a 192-wide value
     assert all("bf16[16,8192,192]" in line for line in kernels)   # q / dq
-    assert sum(line.count("bf16[16,8192,192]") for line in kernels) == 4
+    assert sum(line.count("bf16[16,8192,192]") for line in kernels) == 3
     assert all("bf16[1,8192,64]" in line for line in kernels)
 
 
@@ -197,13 +222,14 @@ def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
         assert loop + "/while/body" in text, loop
 
 
-def test_decoder_layers_backward_holds_three_kernels_a_layer(one_chip):
+def test_decoder_layers_backward_holds_two_kernels_a_layer(one_chip):
     """A window and a full layer of the sparse decoder at the published
     attention widths (32 query heads over 4 KV heads of 128, L8192),
     rematerialised as ``SparseDecoderModule`` declares it: the gradient
-    compiles and holds forward + logsumexp, dQ and dK/dV for each, and
-    no fourth: the second forward finds the kernel's output and its
-    logsumexp kept."""
+    compiles and holds forward + logsumexp and one backward kernel for
+    each, and no third: the second forward finds the kernel's output
+    and its logsumexp kept, and the backward regenerates the scores
+    once for dQ, dK and dV."""
     from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
         SparseDecoderModule, next_token_loss)
 
@@ -213,18 +239,48 @@ def test_decoder_layers_backward_holds_three_kernels_a_layer(one_chip):
         n_dense_layers=2, n_head=32, n_kv_head=4, head_dim=128,
         window=2048, dense_width=6144, expert_width=1024, n_routed=128,
         n_held=16, dtype=jnp.bfloat16)
-    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    kernels, text = _gradient_kernels(module, next_token_loss, one_chip)
+    assert len(kernels) == 4
+    for scope in ("attention_flash_window", "attention_flash"):
+        assert sum(f"/{scope}/" in line for line in kernels) == 2, scope
+    assert "8192,8192" not in text
+    assert all("bf16[4,8192,128]" in line for line in kernels)   # K/V: 4
+
+
+def test_latent_decoder_layers_backward_holds_two_kernels_a_layer(one_chip):
+    """Two dense layers of the latent-attention decoder at the published
+    attention widths (16 heads, 128 + 64 rotary | 128, latent 512,
+    L8192) under the same rematerialisation: forward + logsumexp and one
+    backward kernel a layer, the rotary key one head in HBM."""
+    from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
+        LatentDecoderModule, next_token_loss)
+
+    module = LatentDecoderModule(
+        vocab=1024, hidden_size=2048, n_layers=2, n_dense_layers=2,
+        n_head=16, nope_dim=128, rope_dim=64, v_dim=128, latent_dim=512,
+        dense_width=11264, expert_width=1408, n_routed=64, n_held=8,
+        dtype=jnp.bfloat16)
+    kernels, text = _gradient_kernels(module, next_token_loss, one_chip)
+    assert len(kernels) == 4
+    assert all("/attention_flash_latent/" in line for line in kernels)
+    assert "8192,8192" not in text
+    assert all("bf16[1,8192,64]" in line for line in kernels)
+    # 192 wide: q into the forward, q into and dq out of the backward;
+    # never a key or a key's gradient
+    assert sum(line.count("bf16[16,8192,192]") for line in kernels) == 2 * 3
+
+
+def _gradient_kernels(module, loss_of, sharding):
+    """The Pallas kernels in the compiled gradient of a decoder's
+    next-token loss at L8192, and the whole text."""
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=sharding)
     params = _shapes_on(jax.eval_shape(
         module.init, jax.random.PRNGKey(0),
-        jnp.zeros((1, 128), jnp.int32))["params"], one_chip)
+        jnp.zeros((1, 128), jnp.int32))["params"], sharding)
 
     def loss(params, ids):
-        return next_token_loss(module.apply({"params": params}, ids), ids)
+        return loss_of(module.apply({"params": params}, ids), ids)
 
     text = jax.jit(jax.grad(loss)).lower(params, ids).compile().as_text()
-    kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line]
-    assert len(kernels) == 6
-    for scope in ("attention_flash_window", "attention_flash"):
-        assert sum(f"/{scope}/" in line for line in kernels) == 3, scope
-    assert "8192,8192" not in text
+    return [line for line in text.splitlines()
+            if "tpu_custom_call" in line], text

@@ -19,7 +19,7 @@ from analytics_zoo_tpu.models.text import LatentDecoderLM
 from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
     LatentDecoderModule, next_token_loss)
 from analytics_zoo_tpu.obs.metrics import get_registry
-from analytics_zoo_tpu.ops import attention
+from analytics_zoo_tpu.ops import attention, pallas_attention
 from analytics_zoo_tpu.ops.attention import (
     attention_path, dot_product_attention, reference_attention)
 from analytics_zoo_tpu.ops.pallas_attention import pallas_flash_attention_fwd
@@ -96,16 +96,25 @@ def _explicit(q, k_nope, k_rot, v):
     return reference_attention(q, k, v, mask=jnp.asarray(keep)[None, None])
 
 
+@pytest.mark.parametrize("path", ["fused", "split"])
 @pytest.mark.parametrize("h,lq,lk,widths,blocks", [
     (2, 256, 256, (128, 64, 128), (128, 128)),   # the published widths
     (3, 128, 384, (64, 64, 192), (128, 128)),    # cross-length, wider values
     (2, 256, 256, (128, 64, 64), (256, 128)),    # one row block
+    (3, 512, 512, (128, 64, 128), (128, 128)),   # dQ over four kv-blocks
 ])
 def test_flash_two_widths_and_a_shared_key_match_explicit_mask(
-        h, lq, lk, widths, blocks):
+        monkeypatch, h, lq, lk, widths, blocks, path):
     """The owned kernels in interpret mode, values ``widths[2]`` wide
     under queries ``widths[0] + widths[1]`` wide whose last columns read
-    one key head shared by all: the output and all four gradients."""
+    one key head shared by all: the output and all four gradients, from
+    the one backward kernel and (its budget set to nothing) from the
+    two that hold only blocks."""
+    if path == "split":
+        monkeypatch.setattr(pallas_attention, "FUSED_BWD_VMEM_BUDGET", 0)
+    assert pallas_attention.flash_backward_path(
+        lq, lk, widths[0] + widths[1], widths[0], widths[2], 4, None,
+        *blocks) == path
     q, k_nope, k_rot, v, ct = _latent_operands(h, lq, lk, *widths)
 
     def flash(q, k_nope, k_rot, v):
@@ -122,6 +131,29 @@ def test_flash_two_widths_and_a_shared_key_match_explicit_mask(
     for g, w, name in zip(got, want, ("dq", "dk", "dk_shared", "dv")):
         assert g.shape == w.shape, name
         np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+def test_shared_key_gradients_in_bfloat16_over_several_blocks():
+    """192 | 128 in the compute dtype of the cell, blocks of 128 at
+    L512: the shared head's gradient is summed over the heads in
+    float32 and every gradient comes back in bfloat16, close to the
+    float32 reference's on the same rounded operands."""
+    operands = [a.astype(jnp.bfloat16)
+                for a in _latent_operands(4, 512, 512, 128, 64, 128)]
+    ct = operands.pop().astype(jnp.float32)
+
+    def flash(q, k_nope, k_rot, v):
+        return pallas_flash_attention_fwd(q, k_nope, v, True, None, 128, 128,
+                                          None, k_rot)
+
+    got = jax.grad(lambda *a: jnp.sum(flash(*a).astype(jnp.float32) * ct),
+                   argnums=(0, 1, 2, 3))(*operands)
+    want = jax.grad(lambda *a: jnp.sum(_explicit(*a) * ct),
+                    argnums=(0, 1, 2, 3))(
+        *(a.astype(jnp.float32) for a in operands))
+    for g, w, name in zip(got, want, ("dq", "dk", "dk_shared", "dv")):
+        assert g.shape == w.shape and g.dtype == jnp.bfloat16, name
+        assert _rel(g, w) < 1.5e-2, name
 
 
 def test_flash_two_widths_without_a_shared_key():
@@ -387,10 +419,10 @@ def test_model_has_two_norms_a_layer_and_no_gate():
 # ------------------------------------------------------------------ #
 # rematerialisation keeps the flash kernel's two results             #
 # ------------------------------------------------------------------ #
-def test_remat_layer_backward_holds_three_flash_kernels(monkeypatch):
-    """Forward + logsumexp, dQ, dK/dV a layer at L1024, which the flash
-    path takes: the layer's second forward holds no attention kernel,
-    because both results of the first are kept."""
+def test_remat_layer_backward_holds_two_flash_kernels(monkeypatch):
+    """Forward + logsumexp and one backward kernel a layer at L1024,
+    which the flash path takes: the layer's second forward holds no
+    attention kernel, because both results of the first are kept."""
     monkeypatch.setattr(attention, "_platform", lambda q: "tpu")
     module = LatentDecoderModule(
         vocab=64, hidden_size=64, n_layers=2, n_dense_layers=2, n_head=2,
@@ -405,7 +437,7 @@ def test_remat_layer_backward_holds_three_flash_kernels(monkeypatch):
                                jnp.roll(ids, -1, 1))
 
     text = str(jax.make_jaxpr(jax.grad(loss))(params))
-    assert text.count("pallas_call[") == 2 * 3
+    assert text.count("pallas_call[") == 2 * 2
     assert "name=flash_attention_out" in text
 
 

@@ -94,16 +94,25 @@ def _qkv(h, h_kv, lq, lk, d, seed=0):
             jax.random.normal(ks[3], (1, h, lq, d)))
 
 
+@pytest.mark.parametrize("path", ["fused", "split"])
 @pytest.mark.parametrize("h,h_kv,lq,lk,window,blocks", [
     (4, 2, 384, 384, 200, (128, 128)),     # L no multiple of the window
     (4, 1, 256, 512, 130, (128, 256)),     # cross-length, one KV head
     (2, 2, 384, 384, None, (128, 128)),    # full, heads not grouped
     (8, 2, 256, 256, 128, (128, 128)),     # window = one block
+    (8, 1, 512, 512, 200, (128, 128)),     # group 8; dQ over 3 kv-blocks
+    (8, 1, 256, 512, 100, (128, 128)),     # oldest kv-blocks out of reach
 ])
 def test_flash_window_and_grouped_heads_match_explicit_mask(
-        h, h_kv, lq, lk, window, blocks):
+        monkeypatch, h, h_kv, lq, lk, window, blocks, path):
     """The owned kernels in interpret mode against ``reference_attention``
-    with the mask written out: values and all three gradients."""
+    with the mask written out: values and all three gradients, from the
+    one backward kernel (dK/dV summed over the group's heads, dQ a head)
+    and, the budget set to nothing, from the two that hold only blocks."""
+    if path == "split":
+        monkeypatch.setattr(pallas_attention, "FUSED_BWD_VMEM_BUDGET", 0)
+    assert pallas_attention.flash_backward_path(
+        lq, lk, 64, 64, 64, 4, window, *blocks) == path
     q, k, v, ct = _qkv(h, h_kv, lq, lk, 64)
     group = h // h_kv
     mask = _explicit_mask(lq, lk, window)
@@ -760,21 +769,21 @@ def _without_policy(monkeypatch):
 
 
 @KINDS
-def test_remat_layer_backward_holds_three_flash_kernels(
+def test_remat_layer_backward_holds_two_flash_kernels(
         monkeypatch, on_the_chip, kind):
-    """Forward + logsumexp, dQ, dK/dV: the layer's second forward holds
-    no attention kernel, because both results of the first are kept.
-    Without the policy it holds a fourth; on the path that holds [L, L]
-    scores nothing carries the names and nothing but the input is
-    kept."""
+    """Forward + logsumexp and the one backward kernel: the layer's
+    second forward holds no attention kernel, because both results of
+    the first are kept. Without the policy it holds a third; on the path
+    that holds [L, L] scores nothing carries the names and nothing but
+    the input is kept."""
     params, loss = _one_layer(kind)
 
     def text():
         return str(jax.make_jaxpr(jax.grad(loss, has_aux=True))(params))
 
-    assert text().count("pallas_call[") == 3
+    assert text().count("pallas_call[") == 2
     _without_policy(monkeypatch)
-    assert text().count("pallas_call[") == 4
+    assert text().count("pallas_call[") == 3
     monkeypatch.undo()      # the policy again, and the dispatcher's own eyes
     monkeypatch.setattr(attention, "_platform", lambda q: "cpu")
     scores = text()
@@ -831,7 +840,7 @@ def test_names_outside_a_policy_change_no_program(monkeypatch, window):
         monkeypatch.setattr(pallas_attention, "checkpoint_name", name)
         texts.append(lowered())     # one call site: kernels carry theirs
     named, bare = texts
-    assert named.count("stablehlo.custom_call @tpu_custom_call") == 3
+    assert named.count("stablehlo.custom_call @tpu_custom_call") == 2
     assert named == bare
 
 

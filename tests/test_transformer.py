@@ -188,15 +188,22 @@ class TestPallasKernel:
                 err_msg=f"d{name} mismatch")
 
     def test_flash_backward_long_context_1024_blocks(self):
-        # the d<=64 / L>=2048 backward runs 1024-blocks (_bwd_cap);
+        # the d<=64 / L>=2048 backward runs 1024-blocks (_bwd_blocks);
         # grads through that geometry must still match the dense path
         from analytics_zoo_tpu.ops import (
             pallas_flash_attention_fwd, reference_attention)
-        from analytics_zoo_tpu.ops.pallas_attention import _bwd_cap
+        from analytics_zoo_tpu.ops.pallas_attention import _bwd_blocks
 
-        assert _bwd_cap(2048, 64) == 1024   # the branch under test
-        assert _bwd_cap(1024, 64) == 512    # pipelining guard
-        assert _bwd_cap(2048, 128) == 512   # VMEM guard
+        assert _bwd_blocks(2048, 2048, 64, None) == (1024, 1024)  # under test
+        assert _bwd_blocks(1024, 1024, 64, None) == (512, 512)  # pipelining
+        assert _bwd_blocks(1024, 2048, 64, None) == (512, 512)  # either side
+        assert _bwd_blocks(2048, 2048, 128, None) == (512, 512)
+        # d >= 128: 1024 from 8k on, and never under a window
+        assert _bwd_blocks(8192, 8192, 128, None) == (1024, 1024)
+        assert _bwd_blocks(8192, 8192, 192, None) == (1024, 1024)
+        assert _bwd_blocks(8192, 8192, 128, 2048) == (512, 512)
+        assert _bwd_blocks(4096, 8192, 128, None) == (512, 512)
+        assert _bwd_blocks(8192 + 128, 8192 + 128, 128, None) == (640, 640)
         rng = np.random.RandomState(5)
         b, h, l, d = 1, 1, 2048, 64
         q = jnp.asarray(rng.randn(b, h, l, d) * 0.2, jnp.float32)
@@ -214,6 +221,97 @@ class TestPallasKernel:
         for gf, gr in zip(g_flash, g_ref):
             np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                        atol=2e-4)
+
+    def test_backward_path_is_a_function_of_the_shapes(self):
+        # one kernel while its whole-sequence accumulators fit the
+        # budget beside the kernel, two block-wise kernels past it:
+        # (l, lk, d, d_k, d_v, itemsize); no option chooses
+        from analytics_zoo_tpu.ops.pallas_attention import (
+            FUSED_BWD_VMEM_BUDGET, _fused_bwd_vmem_bytes,
+            flash_backward_path)
+
+        assert flash_backward_path(8192, 8192, 128, 128, 128, 2) == "fused"
+        assert flash_backward_path(8192, 8192, 128, 128, 128, 2,
+                                   2048) == "fused"
+        assert flash_backward_path(8192, 8192, 192, 128, 128, 2) == "fused"
+        assert flash_backward_path(16384, 16384, 128, 128, 128, 2) == "fused"
+        assert flash_backward_path(32768, 32768, 128, 128, 128, 2) == "split"
+        assert flash_backward_path(16384, 16384, 128, 128, 128, 4) == "split"
+        assert flash_backward_path(16384, 16384, 192, 128, 128, 2) == "split"
+        assert flash_backward_path(384, 384, 64, 64, 64, 2) == "fused"
+        # Trinity-Mini's window layer at 512^2: 3 x 4 MiB of accumulators,
+        # 3 x 2 x 2 MiB of output blocks, tiles and intermediates;
+        # Moonlight's 192 columns take two 128-lane tiles
+        trinity = _fused_bwd_vmem_bytes(8192, 8192, 128, 128, 128, 2,
+                                        512, 512)
+        moonlight = _fused_bwd_vmem_bytes(8192, 8192, 192, 128, 128, 2,
+                                          1024, 1024)
+        assert 30 * 2 ** 20 <= trinity <= 34 * 2 ** 20
+        assert 70 * 2 ** 20 <= moonlight <= 76 * 2 ** 20
+        assert moonlight < FUSED_BWD_VMEM_BUDGET <= 100 * 2 ** 20
+        # blocks the caller names count: the intermediates grow with them
+        assert flash_backward_path(16384, 16384, 128, 128, 128, 4, None,
+                                   256, 256) == "fused"
+        assert (_fused_bwd_vmem_bytes(8192, 8192, 128, 128, 128, 2, 1024,
+                                      1024) - trinity) > 12e6
+
+    @pytest.mark.parametrize("case", [
+        # (causal, window, lq, lk, heads, kv heads, d, dtype)
+        (True, None, 512, 512, 2, 2, 128, "float32"),
+        (True, 100, 512, 512, 2, 2, 128, "float32"),
+        (True, None, 256, 512, 2, 2, 128, "float32"),   # Lq < Lk
+        (True, 300, 256, 512, 2, 1, 64, "float32"),
+        (False, None, 512, 512, 2, 2, 64, "float32"),
+        (False, None, 256, 512, 2, 2, 128, "float32"),
+        (True, None, 512, 512, 8, 1, 128, "float32"),   # group 8
+        (True, 100, 512, 512, 8, 1, 64, "float32"),
+        (True, None, 512, 512, 2, 2, 128, "bfloat16"),
+        (True, 100, 512, 512, 8, 1, 128, "bfloat16"),
+        (False, None, 512, 512, 8, 1, 128, "bfloat16"),
+    ], ids=lambda c: "-".join(str(x) for x in c))
+    @pytest.mark.parametrize("path", ["fused", "split"])
+    def test_backward_accumulates_over_blocks(self, monkeypatch, case,
+                                              path):
+        # blocks of 128 at L512: dQ accumulates over up to four
+        # kv-blocks while dK/dV accumulate over the q-blocks and the
+        # group's heads; the one kernel and (the budget set to nothing)
+        # the two kernels, each against reference_attention's gradients
+        from analytics_zoo_tpu.ops import (
+            pallas_attention, pallas_flash_attention_fwd,
+            reference_attention)
+
+        causal, window, lq, lk, h, h_kv, d, dtype = case
+        if path == "split":
+            monkeypatch.setattr(pallas_attention, "FUSED_BWD_VMEM_BUDGET", 0)
+        assert pallas_attention.flash_backward_path(
+            lq, lk, d, d, d, jnp.dtype(dtype).itemsize, window, 128,
+            128) == path
+        rng = np.random.RandomState(7)
+        q, k, v, ct = (jnp.asarray(rng.randn(*shape), dtype) for shape in (
+            (1, h, lq, d), (1, h_kv, lk, d), (1, h_kv, lk, d),
+            (1, h, lq, d)))
+
+        def loss(attend, *args):
+            return jnp.sum(attend(*args).astype(jnp.float32)
+                           * ct.astype(jnp.float32))
+
+        got = jax.grad(lambda *a: loss(
+            lambda q, k, v: pallas_flash_attention_fwd(
+                q, k, v, causal, None, 128, 128, window), *a),
+            argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: loss(
+            lambda q, k, v: reference_attention(
+                q, k, v, causal=causal, window=window), *a),
+            argnums=(0, 1, 2))(*(a.astype(jnp.float32) for a in (q, k, v)))
+        for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+            assert g.shape == w.shape and g.dtype == jnp.dtype(dtype), name
+            if dtype == "float32":
+                np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4,
+                                           err_msg=name)
+            else:
+                g, w = np.asarray(g, np.float32), np.asarray(w)
+                assert (np.linalg.norm(g - w) / np.linalg.norm(w)
+                        < 1.5e-2), name
 
     def test_flash_backward_cross_length_grads(self):
         from analytics_zoo_tpu.ops import (
