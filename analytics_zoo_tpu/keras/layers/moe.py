@@ -88,11 +88,13 @@ class SwiGLU(nn.Module):
 
     width: int
     dtype: Any = jnp.float32
+    kernel_init: Any = nn.linear.default_kernel_init
 
     @nn.compact
     def __call__(self, x):
         def dense(n, name):
-            return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
+            return nn.Dense(n, use_bias=False, dtype=self.dtype,
+                            kernel_init=self.kernel_init, name=name)
 
         h = nn.silu(dense(self.width, "w1")(x)) * dense(self.width, "w3")(x)
         return dense(x.shape[-1], "w2")(h)

@@ -41,16 +41,21 @@ BRANCH_SCALE_INIT = 0.1
 
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis,
-    statistics in float32."""
+    statistics in float32. With ``unit_offset`` the parameter is the
+    scale's distance from 1 (``y * (1 + w)``); either way the scale
+    starts at ``scale_init``."""
 
     eps: float = 1e-5
     dtype: Any = jnp.float32
     scale_init: float = 1.0
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.constant(
-            self.scale_init), (x.shape[-1],))
+            self.scale_init - self.unit_offset), (x.shape[-1],))
+        if self.unit_offset:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), -1, keepdims=True) + self.eps)

@@ -12,6 +12,7 @@ from analytics_zoo_tpu.models.text.bert_squad import (  # noqa: F401
     BERTSQuAD,
 )
 from analytics_zoo_tpu.models.text.sparse_decoder_lm import (  # noqa: F401
+    ByteDecoderLM,
     LatentDecoderLM,
     SparseDecoderLM,
 )

@@ -9,7 +9,12 @@ next token: leading dense layers and then routed experts.
 full grouped-KV attention mixed by layer, four norms, an output gate),
 ``LatentDecoderLM`` stacks ``LatentDecoderLayer`` blocks (latent
 attention, two norms); embedding, rematerialisation, final norm, head
-and loss are shared.
+and loss are shared. ``ByteDecoderLM`` is a dense decoder over bytes:
+``ByteDecoderLayer`` blocks (EVA attention, two unit-offset norms, a
+float32 residual stream) and ``n_pred_heads`` heads over the
+vocabulary, head ``n`` at position ``t`` predicting byte ``t + 1 + n``
+(``multi_byte_loss``); it shares the embedding, the rematerialisation
+and the final norm and head's code.
 
 The vocabulary and the experts may be one chip's share of a larger
 deployment: ``vocab`` rows of the table and the head, ``n_held`` of
@@ -32,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.keras.layers.byte_decoder import ByteDecoderLayer
 from analytics_zoo_tpu.keras.layers.latent_decoder import LatentDecoderLayer
 from analytics_zoo_tpu.keras.layers.sparse_decoder import (
     RMSNorm, SparseDecoderLayer)
@@ -49,16 +55,44 @@ def next_token_loss(logits, labels):
     return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
 
 
-def _embed(module, x, init_std: float = 0.02):
-    """Token ids -> [B, L, d] in ``module.dtype``, times sqrt(d) where
-    the module scales its embeddings."""
-    ids = x["input_ids"] if isinstance(x, dict) else x
+def _ids(x):
+    return (x["input_ids"] if isinstance(x, dict) else x).astype(jnp.int32)
+
+
+def _nll_ahead(logits, tokens, first: int):
+    """Cross-entropy of head ``i`` at position ``t`` on
+    ``tokens[t + first + i]``: ([B, L, n] float32, the [L, n] mask of
+    the pairs whose target lies in the row)."""
+    l, n = logits.shape[1:3]
+    ahead = jnp.arange(l)[:, None] + first + jnp.arange(n)[None]
+    targets = tokens[:, jnp.minimum(ahead, l - 1)]             # [B, L, n]
+    picked = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
+    return jax.nn.logsumexp(logits, axis=-1) - picked, ahead < l
+
+
+def multi_byte_loss(logits, labels):
+    """Cross-entropy of ``n`` heads that look 1..n positions ahead:
+    float32 ``logits`` [B, L, n, V], head ``i`` at position ``t``
+    predicting ``labels[t + i]`` (``labels`` [B, L] is each position's
+    next token, so that is token ``t + 1 + i``). The mean over the heads
+    and over the positions whose target lies in the row
+    (``t + i < L``), every such pair counting the same."""
+    nll, inside = _nll_ahead(logits.astype(jnp.float32),
+                             labels.astype(jnp.int32), 0)
+    return jnp.sum(jnp.where(inside, nll, 0.0)) / (
+        logits.shape[0] * jnp.sum(inside))
+
+
+def _embed(module, x, init_std: float = 0.02, dtype=None):
+    """Token ids -> [B, L, d] in ``dtype`` (``module.dtype`` where none
+    is given), times sqrt(d) where the module scales its embeddings."""
     d = module.hidden_size
+    dtype = module.dtype if dtype is None else dtype
     h = nn.Embed(module.vocab, d, name="embed",
                  embedding_init=nn.initializers.normal(init_std))(
-        ids.astype(jnp.int32)).astype(module.dtype)
+        _ids(x)).astype(dtype)
     if module.scale_embedding:
-        h = h * np.sqrt(d).astype(module.dtype)
+        h = h * np.sqrt(d).astype(dtype)
     return h
 
 
@@ -72,10 +106,14 @@ def _rematerialised(layer_cls):
             FLASH_OUT_NAME, FLASH_LSE_NAME))
 
 
-def _logits(module, h):
-    h = RMSNorm(module.eps, module.dtype, name="final_norm")(h)
-    head = module.param("head", nn.initializers.normal(0.02),
-                        (module.hidden_size, module.vocab))
+def _logits(module, h, heads: int = 1, init_std: float = 0.02,
+            unit_offset: bool = False):
+    """float32 logits [B, L, heads * vocab] of the final norm's output
+    under the untied head."""
+    h = RMSNorm(module.eps, module.dtype, unit_offset=unit_offset,
+                name="final_norm")(h)
+    head = module.param("head", nn.initializers.normal(init_std),
+                        (module.hidden_size, heads * module.vocab))
     return jnp.dot(h, head.astype(module.dtype),
                    preferred_element_type=jnp.float32)
 
@@ -171,6 +209,69 @@ class LatentDecoderModule(nn.Module):
         return _logits(self, h)
 
 
+class ByteDecoderModule(nn.Module):
+    vocab: int
+    hidden_size: int
+    n_layers: int
+    n_head: int
+    head_dim: int
+    window: int
+    chunk: int
+    dense_width: int
+    n_pred_heads: int = 1
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    init_std: float = 0.02
+    scale_embedding: bool = False
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        # the residual stream is float32 from the table on
+        h = _embed(self, x, self.init_std, jnp.float32)
+        attention = dict(n_head=self.n_head, head_dim=self.head_dim,
+                         window=self.window, chunk=self.chunk,
+                         rope_theta=self.rope_theta)
+        layer = _rematerialised(ByteDecoderLayer)
+        for i in range(self.n_layers):
+            h = layer(attention=attention, dense_width=self.dense_width,
+                      eps=self.eps, init_std=self.init_std,
+                      dtype=self.dtype, name=f"layer_{i}")(h, train)
+        with jax.named_scope("multibyte_head"):
+            logits = _logits(self, h, self.n_pred_heads, self.init_std,
+                             unit_offset=True)
+            logits = logits.reshape(logits.shape[:2]
+                                    + (self.n_pred_heads, self.vocab))
+            self._count(logits, _ids(x), train)
+        return logits
+
+    def _count(self, logits, ids, train: bool):
+        """Collection ``counters`` (cumulative int32, published by the
+        Estimator at each epoch's sync): each head's mean cross-entropy
+        of the step in millionths of a nat, over the targets that lie
+        in the row's own input (head ``i`` at ``t``: ``ids[t + 1 + i]``),
+        and the steps counted: their growths' ratio is a head's mean
+        loss over an epoch."""
+        n = self.n_pred_heads
+        counting = train and self.is_mutable_collection("counters")
+        if not (counting or self.is_initializing()):
+            return
+        adds = {"multibyte_head_steps": jnp.ones((), jnp.int32),
+                "multibyte_head_loss_micronats": jnp.zeros((n,), jnp.int32)}
+        if counting:
+            nll, inside = _nll_ahead(jax.lax.stop_gradient(logits), ids, 1)
+            mean = jnp.sum(jnp.where(inside, nll, 0.0), (0, 1)) / jnp.maximum(
+                ids.shape[0] * jnp.sum(inside, 0), 1)
+            adds["multibyte_head_loss_micronats"] = jnp.round(
+                1e6 * mean).astype(jnp.int32)
+        for name, add in adds.items():
+            counter = self.variable(
+                "counters", name,
+                lambda a=add: jnp.zeros(a.shape, jnp.int32))
+            if counting:
+                counter.value = counter.value + add
+
+
 class _DecoderLM(ZooModel):
     """fit expects x = {"input_ids": [B, L]} (or the array) and
     y = [B, L], each position's next token; predict returns float32
@@ -254,3 +355,33 @@ class LatentDecoderLM(_DecoderLM):
         c["shared_width"] = c.pop("n_shared") * c["expert_width"]
         c["dtype"] = jnp.dtype(c["dtype"])
         return LatentDecoderModule(**c)
+
+
+@register_model
+class ByteDecoderLM(_DecoderLM):
+    """A dense decoder over bytes: ``n_layers`` ``ByteDecoderLayer``
+    blocks (EVA attention over windows of ``window`` and chunks of
+    ``chunk``) and ``n_pred_heads`` heads over the vocabulary. fit
+    takes ``_DecoderLM``'s x and y = [B, L] (each position's next
+    byte; the further heads' targets are y shifted); predict returns
+    float32 logits [B, L, n_pred_heads, vocab]."""
+
+    default_loss = staticmethod(multi_byte_loss)
+
+    def __init__(self, vocab: int, hidden_size: int, n_layers: int,
+                 n_head: int, head_dim: int, window: int, chunk: int,
+                 dense_width: int, n_pred_heads: int = 1,
+                 rope_theta: float = 10000.0, eps: float = 1e-5,
+                 init_std: float = 0.02, scale_embedding: bool = False,
+                 dtype: str = "float32"):
+        super().__init__(
+            vocab=vocab, hidden_size=hidden_size, n_layers=n_layers,
+            n_head=n_head, head_dim=head_dim, window=window, chunk=chunk,
+            dense_width=dense_width, n_pred_heads=n_pred_heads,
+            rope_theta=rope_theta, eps=eps, init_std=init_std,
+            scale_embedding=scale_embedding, dtype=dtype)
+
+    def _build_module(self):
+        c = dict(self._config)
+        c["dtype"] = jnp.dtype(c["dtype"])
+        return ByteDecoderModule(**c)

@@ -22,6 +22,12 @@ call's shapes alone; the one taken names itself in ``op_name``:
 - ``attention_reference`` -- the same in plain ``jnp``, for attention
   dropout (flash kernels do not support it).
 
+``eva_attention`` is a second entry point under the same rule: exact
+causal attention inside each window and, in the same softmax, the
+chunk summaries of every earlier window (``attention_<path>_eva``;
+the owned kernels off the CPU, else ``einsum`` with the joint scores
+held).
+
 Replaces the reference's O(L^2)-materialized attention
 (ref: zoo/.../keras/layers/TransformerLayer.scala attn).
 """
@@ -233,3 +239,72 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
                                    scale=scale, window=window,
                                    dropout_rate=dropout_rate,
                                    dropout_rng=dropout_rng)
+
+
+def _eva_keep(l: int, window: int, per_window: int):
+    """[l, l / window * per_window + l] bool over the joined keys
+    (summaries first): row i of window ``w = i // window`` keeps the
+    summaries ``m < w * per_window`` and the token keys
+    ``w * window <= j <= i``."""
+    rows = jnp.arange(l)[:, None]
+    w = rows // window
+    summaries = jnp.arange(l // window * per_window)[None] < w * per_window
+    cols = jnp.arange(l)[None]
+    return jnp.concatenate(
+        [summaries, (cols <= rows) & (cols >= w * window)], axis=1)
+
+
+def _einsum_eva_attention(q, k, v, k_summary, v_summary, window: int,
+                          scale: float):
+    """The joint softmax with its [L, L / chunk + L] scores held:
+    float32 scores, probabilities back in the values' dtype."""
+    l = q.shape[2]
+    keep = _eva_keep(l, window, k_summary.shape[2] // (l // window))
+    keys = jnp.concatenate([k_summary, k], axis=2)
+    values = jnp.concatenate([v_summary, v], axis=2)
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys,
+                        preferred_element_type=jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(keep[None, None], logits, NEG_INF), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(values.dtype), values)
+
+
+def eva_attention_path(platform: str, l: int, window: int, chunk: int,
+                       head_dim: int, heads: int) -> str:
+    """``flash`` or ``einsum`` for an EVA call: the owned kernels where
+    ``attention_path`` gives them to a causal call of one window, the
+    sequence is whole windows and a window's summaries fill 128-row
+    blocks; else the path that holds the scores."""
+    whole = l % window == 0 and window % chunk == 0
+    if (whole and (window // chunk) % 128 == 0 and attention_path(
+            platform, window, window, head_dim, heads, heads,
+            causal=True) == "flash"):
+        return "flash"
+    return "einsum"
+
+
+def eva_attention(q, k, v, k_summary, v_summary, window: int,
+                  scale: Optional[float] = None):
+    """EVA attention (Zheng et al., ICLR 2023, as EvaByte runs it) on
+    q, k, v [B, H, L, D] and one summary a chunk of keys and of values,
+    k_summary, v_summary [B, H, L / chunk, D]: row i of window
+    ``w = i // window`` reads the token keys ``w * window <= j <= i``
+    exactly and the summaries of the chunks of every earlier window,
+    all under one softmax. Returns [B, H, L, D]; every op is named
+    ``attention_<path>_eva``."""
+    l, d = q.shape[2], q.shape[-1]
+    n_sum = k_summary.shape[2]
+    if l % window or n_sum == 0 or l % n_sum:
+        raise ValueError(f"length {l} is not whole windows of {window} "
+                         f"with {n_sum} summaries")
+    scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    path = eva_attention_path(_platform(q), l, window, l // n_sum, d,
+                              q.shape[1])
+    with jax.named_scope(f"attention_{path}_eva"):
+        if path == "flash":
+            from analytics_zoo_tpu.ops.pallas_attention import (
+                pallas_eva_attention)
+
+            return pallas_eva_attention(q, k, v, k_summary, v_summary,
+                                        window, scale)
+        return _einsum_eva_attention(q, k, v, k_summary, v_summary,
+                                     window, scale)

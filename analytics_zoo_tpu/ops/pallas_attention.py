@@ -49,6 +49,13 @@ Two variants ride the same kernels (docs/kernels.md):
   backward walks the query heads of a KV head's group in a sequential
   grid dimension and sums their dK/dV in VMEM.
 
+A third caller composes them: ``pallas_eva_attention`` (EVA: exact
+keys inside a window and chunk summaries of the earlier windows under
+one softmax) is the plain causal kernel over the windows counted as
+heads, one rectangular call over the summaries a later window, and a
+join by logsumexp; its backward hands each part the joint output and
+logsumexp (docs/kernels.md "EVA").
+
 The forward's grid is declared (parallel, parallel, arbitrary) so Mosaic
 pipelines the sequential kv accumulation dimension while batch and row
 blocks schedule freely; the backward's is (parallel, arbitrary,
@@ -777,3 +784,148 @@ def _vjp_bwd(causal, scale, block_q, block_k, window, res, g):
 
 
 pallas_flash_attention_fwd.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# ------------------------------------------------------------------ #
+# EVA: exact keys inside a window, chunk summaries of earlier windows #
+# ------------------------------------------------------------------ #
+def _eva_geometry(q, k_summary, window: int) -> tuple:
+    """(windows, summaries a window) of an EVA call, validated."""
+    l, n_sum = q.shape[2], k_summary.shape[2]
+    if l % window:
+        raise ValueError(f"length {l} is not whole windows of {window}")
+    n_win = l // window
+    if n_sum % n_win:
+        raise ValueError(f"{n_sum} summaries do not divide over {n_win} "
+                         "windows")
+    return n_win, n_sum // n_win
+
+
+def _eva_heads(x, n_win: int):
+    """[B, H, L, D] with the windows counted as heads,
+    [B, H * n_win, L / n_win, D]: a reshape, nothing moves."""
+    b, h, l, d = x.shape
+    return x.reshape(b, h * n_win, l // n_win, d)
+
+
+def _eva_rows(x, w: int, window: int):
+    """The rows of window ``w`` on the sequence axis of [B, H, L, ...]."""
+    return jax.lax.slice_in_dim(x, w * window, (w + 1) * window, axis=2)
+
+
+def _eva_fwd(q, k, v, k_summary, v_summary, window: int, scale: float):
+    """Out [B, H, L, D] and the joint softmax's row logsumexp
+    [B*H, L, 128]. The in-window part is the plain causal kernel with
+    the windows counted as heads (a reshape, nothing moves); window
+    ``w >= 1`` then reads the summaries of windows ``< w`` in a
+    rectangular call without a mask, and the two parts join by their
+    logsumexps."""
+    b, h, l, d = q.shape
+    n_win, per = _eva_geometry(q, k_summary, window)
+    out, lse = _flash_fwd(*(_eva_heads(x, n_win) for x in (q, k, v)), True,
+                          scale, None, None, with_lse=True)
+    out = out.reshape(b, h, l, d)
+    lse = lse.reshape(b, h, l, 128)
+    outs, lses = [_eva_rows(out, 0, window)], [_eva_rows(lse, 0, window)]
+    for w in range(1, n_win):
+        o_sum, lse_sum = _flash_fwd(
+            _eva_rows(q, w, window), k_summary[:, :, :w * per],
+            v_summary[:, :, :w * per], False, scale, None, None,
+            with_lse=True)
+        lse_win = _eva_rows(lse, w, window)
+        lse_sum = lse_sum.reshape(b, h, window, 128)
+        joint = jnp.logaddexp(lse_win, lse_sum)
+        outs.append((
+            _eva_rows(out, w, window).astype(jnp.float32)
+            * jnp.exp(lse_win - joint)[..., :1]
+            + o_sum.astype(jnp.float32)
+            * jnp.exp(lse_sum - joint)[..., :1]).astype(out.dtype))
+        lses.append(joint)
+    return (jnp.concatenate(outs, axis=2),
+            jnp.concatenate(lses, axis=2).reshape(b * h, l, 128))
+
+
+def _eva_bwd(q, k, v, k_summary, v_summary, out, lse, g, window: int,
+             scale: float):
+    """Gradients of the joint softmax. Every probability of row i,
+    token key or summary, is ``exp(s - lse_i)`` under the JOINT
+    logsumexp, and ``delta_i = do_i . o_i`` with the joint output: so
+    each part's backward is the plain kernel given the joint ``out``
+    and ``lse``, and the parts' dq add up. No part keeps its own
+    output or logsumexp."""
+    b, h, l, d = q.shape
+    n_win, per = _eva_geometry(q, k_summary, window)
+    qh, kh, vh, oh, gh = (_eva_heads(x, n_win) for x in (q, k, v, out, g))
+    dq, dk, dv, _ = _flash_bwd(
+        qh, kh, vh, oh, lse.reshape(b * h * n_win, window, 128), gh, True,
+        scale, None, None)
+    dq = dq.reshape(b, h, l, d)
+    dq_parts = [_eva_rows(dq, 0, window)]
+    dks = jnp.zeros(k_summary.shape, jnp.float32)
+    dvs = jnp.zeros(v_summary.shape, jnp.float32)
+    lse = lse.reshape(b, h, l, 128)
+    for w in range(1, n_win):
+        dq_w, dks_w, dvs_w, _ = _flash_bwd(
+            _eva_rows(q, w, window), k_summary[:, :, :w * per],
+            v_summary[:, :, :w * per], _eva_rows(out, w, window),
+            _eva_rows(lse, w, window).reshape(b * h, window, 128),
+            _eva_rows(g, w, window), False, scale, None, None)
+        dq_parts.append((_eva_rows(dq, w, window).astype(jnp.float32)
+                         + dq_w.astype(jnp.float32)).astype(dq.dtype))
+        dks = dks.at[:, :, :w * per].add(dks_w.astype(jnp.float32))
+        dvs = dvs.at[:, :, :w * per].add(dvs_w.astype(jnp.float32))
+    return (jnp.concatenate(dq_parts, axis=2), dk.reshape(k.shape),
+            dv.reshape(v.shape), dks.astype(k_summary.dtype),
+            dvs.astype(v_summary.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def pallas_eva_attention(q, k, v, k_summary, v_summary, window: int,
+                         scale: Optional[float] = None):
+    """EVA attention on q, k, v [B, H, L, D] and the chunk summaries
+    k_summary, v_summary [B, H, L / chunk, D]: row i of window
+    ``w = i // window`` reads, under ONE softmax, the token keys of its
+    own window up to itself and the summaries of every earlier window
+    (``m < w * window / chunk``). Out [B, H, L, D]. ``window`` is a
+    multiple of 128, and so is the number of summaries a window
+    (docs/kernels.md "EVA")."""
+    return _eva_fwd(q, k, v, k_summary, v_summary, window,
+                    _resolve_scale(scale, q))[0]
+
+
+def _eva_vjp_fwd(q, k, v, k_summary, v_summary, window, scale):
+    s = _resolve_scale(scale, q)
+    out, lse = _eva_fwd(q, k, v, k_summary, v_summary, window, s)
+    out = checkpoint_name(out, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
+    return out, (q, k, v, k_summary, v_summary, out, lse, s)
+
+
+def _eva_vjp_bwd(window, scale, res, g):
+    q, k, v, k_summary, v_summary, out, lse, s = res
+    return _eva_bwd(q, k, v, k_summary, v_summary, out, lse, g, window, s)
+
+
+pallas_eva_attention.defvjp(_eva_vjp_fwd, _eva_vjp_bwd)
+
+
+def eva_pairs(l: int, window: int, per_window: int, d: int = 128) -> dict:
+    """Score entries of one head and sequence: ``allowed`` by EVA's
+    mask (in-window causal pairs and query x earlier summaries), and
+    ``computed`` by the blocks the kernels above walk, forward and
+    backward (the in-window walks at their block sizes; the summary
+    calls are exact rectangles)."""
+    n_win = l // window
+    summaries = sum(window * w * per_window for w in range(n_win))
+    fq = fk = _auto_block(window)
+    bq, bk = _bwd_blocks(window, window, d, None)
+
+    def walked(block_q, block_k):
+        return n_win * block_q * block_k * sum(
+            hi - lo + 1 for lo, hi in (
+                _kv_bounds(i, block_q, block_k, window // block_k, True, 0,
+                           None) for i in range(window // block_q)))
+
+    return {"allowed": n_win * window * (window + 1) // 2 + summaries,
+            "computed_forward": walked(fq, fk) + summaries,
+            "computed_backward": walked(bq, bk) + summaries}

@@ -11,13 +11,17 @@ the grouped products at 1,408-wide experts under several tilings.
 forward's output and logsumexp) at the three published shapes under
 several block choices, beside forward + backward through the
 dispatcher, and says how far its gradients lie from the two-kernel
-path's; ``--repo <checkout>`` imports the package from another checkout
+path's; ``--eva`` times the byte decoder's EVA attention at
+[1, 32, 8192, 128] with windows of 2,048 and chunks of 16: the joint
+call, its in-window part alone, the rectangular calls over the chunk
+summaries alone, and the pooling; ``--repo <checkout>`` imports the package from another checkout
 (the parent commit unpacked), for the other side of the table. Run
 through the chip tool; prints one JSON line per reading.
 
     python scripts/perf_sparse_decoder_kernels.py [--skip-splash] [--skip-grouped]
     python scripts/perf_sparse_decoder_kernels.py --latent
     python scripts/perf_sparse_decoder_kernels.py --backward [--repo <checkout>]
+    python scripts/perf_sparse_decoder_kernels.py --eva
     JAX_PLATFORMS=cpu python scripts/perf_sparse_decoder_kernels.py --rehearse
 """
 
@@ -338,14 +342,72 @@ def latent_grouped(rows: int = 49152, held: int = 6144):
                    held=held, error=str(e)[:200])
 
 
+def eva(small: bool = False):
+    """EVA attention forward and forward + backward, whole and by part:
+    what the joint call costs over its in-window kernel (the calls over
+    the summaries, the join by logsumexp, the slices), and the pooling
+    that feeds it."""
+    from analytics_zoo_tpu.keras.layers.byte_decoder import chunk_summaries
+    from analytics_zoo_tpu.ops import pallas_attention as pa
+
+    heads, length, window, chunk, d = ((2, 512, 256, 2, 64) if small else
+                                       (HEADS, L, WINDOW, 16, HEAD_DIM))
+    dtype = jnp.float32 if small else jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    q, k, v = (jax.random.normal(key, (1, heads, length, d), dtype)
+               for key in ks[:3])
+    phi, mu = (jax.random.normal(key, (heads, d)) * d ** -0.5
+               for key in ks[3:])
+    n_win, per = length // window, window // chunk
+
+    def pool(k, v, phi, mu):
+        k_sum, v_sum = chunk_summaries(k, v, phi, mu, chunk, d ** -0.5)
+        return k_sum.astype(dtype), v_sum.astype(dtype)
+
+    k_sum, v_sum = jax.jit(pool)(k, v, phi, mu)
+
+    def joint(q, k, v, k_sum, v_sum):
+        return pa.pallas_eva_attention(q, k, v, k_sum, v_sum, window, None)
+
+    def in_window(q, k, v):
+        fold = (1, heads * n_win, window, d)
+        return pa.pallas_flash_attention_fwd(
+            q.reshape(fold), k.reshape(fold), v.reshape(fold), True)
+
+    def summaries(q, k_sum, v_sum):
+        return [pa.pallas_flash_attention_fwd(
+            q[:, :, w * window:(w + 1) * window], k_sum[:, :, :w * per],
+            v_sum[:, :, :w * per], False) for w in range(1, n_win)]
+
+    def total(fn):
+        return lambda *a: sum(
+            jnp.sum(o.astype(jnp.float32))
+            for o in jax.tree_util.tree_leaves(fn(*a)))
+
+    for name, fn, args in (
+            ("eva_joint", joint, (q, k, v, k_sum, v_sum)),
+            ("eva_in_window_part", in_window, (q, k, v)),
+            ("eva_summary_part", summaries, (q, k_sum, v_sum)),
+            ("eva_chunk_summaries", pool, (k, v, phi, mu))):
+        fwd = timed(jax.jit(fn), *args)
+        both = timed(jax.jit(jax.grad(
+            total(fn), argnums=tuple(range(len(args))))), *args)
+        report(what=name, shape=[1, heads, length, d], window=window,
+               chunk=chunk, fwd_ms=fwd, fwd_bwd_ms=both)
+
+
 if __name__ == "__main__":
     if "--rehearse" in sys.argv:   # the CPU, a tiny size: checks the script
         backward(small=True)
+        eva(small=True)
         raise SystemExit(0)
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("perf_sparse_decoder_kernels: needs a TPU")
     if "--backward" in sys.argv:
         backward()
+        raise SystemExit(0)
+    if "--eva" in sys.argv:
+        eva()
         raise SystemExit(0)
     if "--latent" in sys.argv:
         latent_attention()

@@ -186,6 +186,38 @@ def test_latent_attention_kernels_compile_at_8k(one_chip):
     assert all("bf16[1,8192,64]" in line for line in kernels)
 
 
+def test_eva_attention_compiles_at_8k(one_chip):
+    """EVA attention at the byte decoder's published widths through the
+    dispatcher: 32 heads of 128, L8192 = four windows of 2,048, 512
+    chunk summaries. Forward: the in-window causal kernel (the windows
+    counted as heads) and one rectangular call over the summaries for
+    each of windows 1-3; backward: as many again, each one kernel, given
+    the joint output and logsumexp. No [L, L] and no [L, L / 16] score
+    array exists, and every kernel answers to ``attention_flash_eva``."""
+    def on(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    args = (on((1, 32, 8192, 128)),) * 3 + (on((1, 32, 512, 128)),) * 2
+
+    def attn(*a):
+        return attention.eva_attention(*a, window=2048)
+
+    fwd = jax.jit(attn).lower(*args).compile().as_text()
+    assert fwd.count("tpu_custom_call") == 4
+    bwd = jax.jit(jax.grad(lambda *a: _scalar(attn(*a)),
+                           argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    kernels = [line for line in bwd.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 8
+    assert all("attention_flash_eva" in line for line in kernels)
+    for scores in ("8192,8192", "8192,8704", "8192,512]", "2048,2048",
+                   "2048,384]"):
+        assert scores not in bwd, scores
+    # the windows are heads of the in-window calls: a reshape, no copy
+    assert sum("bf16[128,2048,128]" in line for line in kernels) == 2
+
+
 def test_expert_layer_compiles_at_published_widths(one_chip, monkeypatch):
     """16 of 128 experts at d 2048 / width 1024 over 8,192 tokens,
     forward and backward: the grouped products are Pallas kernels under
