@@ -35,15 +35,29 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.keras.layers.moe import SwiGLU
-from analytics_zoo_tpu.keras.layers.sparse_decoder import RMSNorm, rope
+from analytics_zoo_tpu.keras.layers.sparse_decoder import (
+    ATTENTION_OUT_NAME, RMSNorm, kernel_ready, rope)
 from analytics_zoo_tpu.obs.metrics import get_registry
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention, eva_attention, eva_attention_path)
 from analytics_zoo_tpu.ops.pallas_attention import eva_pairs
 
 __all__ = ["EvaAttention", "ByteDecoderLayer", "chunk_summaries"]
+
+# The MLP's input (the second norm's output), kept by a caller that
+# rematerialises the layer: left to be computed again it has no
+# consumer but the two weight-gradient products of SwiGLU, and XLA then
+# folds the norm into their operands, which costs them a third of their
+# speed at 11,008 columns (docs/kernels.md "Named results").
+MLP_IN_NAME = "mlp_in"
+# The chunk summaries as the attention call reads them (8 MB a layer
+# for both at [1, 32, 512, 128]): kept, the second forward runs no
+# pooling; its backward still computes the chunk softmax from the kept k.
+EVA_K_SUMMARY_NAME = "eva_k_summary"
+EVA_V_SUMMARY_NAME = "eva_v_summary"
 
 _M_PAIRS = get_registry().gauge(
     "zoo_model_attention_eva_pairs_computed_ratio",
@@ -98,9 +112,10 @@ class EvaAttention(nn.Module):
 
         q, k, v = (proj(h * hd, name)(x).reshape(b, l, h, hd)
                    for name in ("q", "k", "v"))
-        q = rope(q, self.rope_theta).transpose(0, 2, 1, 3)
-        k = rope(k, self.rope_theta).transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
+        q, k, v = kernel_ready(
+            rope(q, self.rope_theta).transpose(0, 2, 1, 3),
+            rope(k, self.rope_theta).transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3))
         phi = self.param("adaptive_phi", _clamped_normal(scale), (h, hd))
         mu = self.param("adaptive_mu_k", _clamped_normal(scale), (h, hd))
         if l <= self.window:
@@ -111,10 +126,13 @@ class EvaAttention(nn.Module):
                 k_sum, v_sum = chunk_summaries(k, v, phi, mu, self.chunk,
                                                scale)
             self._publish_pairs(l, hd)
-            o = eva_attention(q, k, v, k_sum.astype(self.dtype),
-                              v_sum.astype(self.dtype), self.window, scale)
+            o = eva_attention(
+                q, k, v,
+                checkpoint_name(k_sum.astype(self.dtype), EVA_K_SUMMARY_NAME),
+                checkpoint_name(v_sum.astype(self.dtype), EVA_V_SUMMARY_NAME),
+                self.window, scale)
         o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd)
-        return proj(d, "out")(o)
+        return checkpoint_name(proj(d, "out")(o), ATTENTION_OUT_NAME)
 
     def _publish_pairs(self, l: int, hd: int) -> None:
         """The gauge of the docstring above: what the kernels' blocks
@@ -153,4 +171,5 @@ class ByteDecoderLayer(nn.Module):
         return h + SwiGLU(
             self.dense_width, dtype=self.dtype,
             kernel_init=nn.initializers.normal(self.init_std),
-            name="mlp")(norm("pre_mlp_norm")(h)).astype(jnp.float32)
+            name="mlp")(checkpoint_name(norm("pre_mlp_norm")(h),
+                                        MLP_IN_NAME)).astype(jnp.float32)

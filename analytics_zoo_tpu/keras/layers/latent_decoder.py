@@ -29,12 +29,19 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.keras.layers.moe import DroplessExperts, SwiGLU
-from analytics_zoo_tpu.keras.layers.sparse_decoder import RMSNorm, rope
+from analytics_zoo_tpu.keras.layers.sparse_decoder import (
+    ATTENTION_OUT_NAME, RMSNorm, kernel_ready, rope)
 from analytics_zoo_tpu.ops.attention import dot_product_attention
 
 __all__ = ["LatentAttention", "LatentDecoderLayer"]
+
+# The one rotary key head every query head reads, as the attention call
+# reads it: kept with ``sparse_decoder.kernel_ready``'s three by a
+# caller that rematerialises the layer.
+ATTENTION_K_ROT_NAME = "attention_k_rot"
 
 
 class LatentAttention(nn.Module):
@@ -71,14 +78,17 @@ class LatentAttention(nn.Module):
             q = jnp.concatenate(
                 [q[..., :nope], rope(q[..., nope:], self.rope_theta)],
                 axis=-1).transpose(0, 2, 1, 3)
-            k_rot = rope(down[:, :, None, self.latent_dim:],
-                         self.rope_theta).transpose(0, 2, 1, 3)
-            k_nope = kv[..., :nope].transpose(0, 2, 1, 3)
-            v = kv[..., nope:].transpose(0, 2, 1, 3)
+            k_rot = checkpoint_name(
+                rope(down[:, :, None, self.latent_dim:],
+                     self.rope_theta).transpose(0, 2, 1, 3),
+                ATTENTION_K_ROT_NAME)
+            q, k_nope, v = kernel_ready(
+                q, kv[..., :nope].transpose(0, 2, 1, 3),
+                kv[..., nope:].transpose(0, 2, 1, 3))
         o = dot_product_attention(q, k_nope, v, causal=True,
                                   k_shared=k_rot)
         o = o.transpose(0, 2, 1, 3).reshape(b, l, h * self.v_dim)
-        return proj(d, "out")(o)
+        return checkpoint_name(proj(d, "out")(o), ATTENTION_OUT_NAME)
 
 
 class LatentDecoderLayer(nn.Module):

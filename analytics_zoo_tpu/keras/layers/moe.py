@@ -64,6 +64,7 @@ from typing import Any, NamedTuple, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.keras.activations import get as get_activation
 from analytics_zoo_tpu.keras.layers.base import KerasLayer
@@ -83,6 +84,16 @@ __all__ = ["MoEFFN", "MoE", "MoETransformerBlock", "DroplessExperts",
            "SwiGLU"]
 
 
+# SwiGLU's two pre-activations, named for ``jax.checkpoint`` policies
+# (``save_only_these_names``): a caller that rematerialises the layer
+# round this module and keeps them runs none of the three products a
+# second time (the backward pass needs ``x W1`` and ``x W3`` themselves
+# and rebuilds ``silu(.) * .`` from them). Outside such a policy a name
+# is the identity.
+SWIGLU_GATE_NAME = "swiglu_gate"
+SWIGLU_UP_NAME = "swiglu_up"
+
+
 class SwiGLU(nn.Module):
     """``(silu(x W1) * (x W3)) W2`` without biases."""
 
@@ -96,8 +107,9 @@ class SwiGLU(nn.Module):
             return nn.Dense(n, use_bias=False, dtype=self.dtype,
                             kernel_init=self.kernel_init, name=name)
 
-        h = nn.silu(dense(self.width, "w1")(x)) * dense(self.width, "w3")(x)
-        return dense(x.shape[-1], "w2")(h)
+        gate = checkpoint_name(dense(self.width, "w1")(x), SWIGLU_GATE_NAME)
+        up = checkpoint_name(dense(self.width, "w3")(x), SWIGLU_UP_NAME)
+        return dense(x.shape[-1], "w2")(nn.silu(gate) * up)
 
 
 # rows of the sorted buffer in one tile of the grouped products' kernels

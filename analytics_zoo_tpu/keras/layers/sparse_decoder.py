@@ -21,14 +21,36 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.keras.layers.moe import DroplessExperts, SwiGLU
 from analytics_zoo_tpu.ops.attention import dot_product_attention
 
-__all__ = ["RMSNorm", "rope", "GatedGroupedAttention", "SparseDecoderLayer",
-           "SLIDING", "FULL"]
+__all__ = ["RMSNorm", "rope", "kernel_ready", "GatedGroupedAttention",
+           "SparseDecoderLayer", "SLIDING", "FULL"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# Values of an attention branch, named for ``jax.checkpoint`` policies
+# (``save_only_these_names``; the decoders' ``nn.remat`` in
+# ``models/text/sparse_decoder_lm.py`` keeps them): q, k and v as the
+# attention call reads them, after the norms, RoPE and the heads-first
+# transpose (``kernel_ready``; the three decoders' attention modules),
+# and the output projection's result. Of this module's own: the query
+# projection's result, which QK-norm's backward reads, and the output
+# gate's pre-activation. Outside such a policy a name is the identity.
+ATTENTION_Q_NAME = "attention_q"
+ATTENTION_K_NAME = "attention_k"
+ATTENTION_V_NAME = "attention_v"
+ATTENTION_OUT_NAME = "attention_out"
+ATTENTION_Q_PROJ_NAME = "attention_q_proj"
+ATTENTION_GATE_NAME = "attention_gate"
+# The MLP branch's result (the dense SwiGLU's or the expert layer's)
+# before the norm that closes the branch, [B, L, d] whatever the
+# experts' held load. That norm's backward reads it, so left to be
+# computed again it brings back ``w2``, the shared expert's ``w2`` and
+# the expert layer's combine. It is named here, outside the ``moe_*``
+# scopes, under which nothing is kept.
+MLP_OUT_NAME = "mlp_out"
 # Initial scale of the two norms that close a residual branch. At 1, a
 # freshly initialised stack adds to every position the same unit-RMS
 # vector per attention branch (near-uniform attention over thousands of
@@ -75,6 +97,14 @@ def rope(x, theta: float):
     return (x32 * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
 
 
+def kernel_ready(q, k, v):
+    """q, k, v [B, H, L, D] as they enter the attention call, under
+    their names."""
+    return (checkpoint_name(q, ATTENTION_Q_NAME),
+            checkpoint_name(k, ATTENTION_K_NAME),
+            checkpoint_name(v, ATTENTION_V_NAME))
+
+
 class GatedGroupedAttention(nn.Module):
     """Causal self-attention, ``n_head`` query heads over ``n_kv_head``
     KV heads, QK-norm, RoPE when ``window`` is set (the sliding kind),
@@ -97,20 +127,23 @@ class GatedGroupedAttention(nn.Module):
             return nn.Dense(n, use_bias=False, dtype=self.dtype,
                             name=name)(x)
 
-        q = proj(h * hd, "q").reshape(b, l, h, hd)
+        q = checkpoint_name(proj(h * hd, "q"),
+                            ATTENTION_Q_PROJ_NAME).reshape(b, l, h, hd)
         k = proj(h_kv * hd, "k").reshape(b, l, h_kv, hd)
         v = proj(h_kv * hd, "v").reshape(b, l, h_kv, hd)
-        gate = proj(h * hd, "gate")
+        gate = checkpoint_name(proj(h * hd, "gate"), ATTENTION_GATE_NAME)
         q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
         k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
         if self.window is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
-        o = dot_product_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, window=self.window)
+        q, k, v = kernel_ready(q.transpose(0, 2, 1, 3),
+                               k.transpose(0, 2, 1, 3),
+                               v.transpose(0, 2, 1, 3))
+        o = dot_product_attention(q, k, v, causal=True, window=self.window)
         o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd)
-        return nn.Dense(d, use_bias=False, dtype=self.dtype, name="out")(
-            o * jax.nn.sigmoid(gate))
+        return checkpoint_name(
+            nn.Dense(d, use_bias=False, dtype=self.dtype, name="out")(
+                o * jax.nn.sigmoid(gate)), ATTENTION_OUT_NAME)
 
 
 class SparseDecoderLayer(nn.Module):
@@ -149,4 +182,5 @@ class SparseDecoderLayer(nn.Module):
         else:
             f = DroplessExperts(**self.experts, dtype=self.dtype,
                                 name="moe")(m, train=train)
-        return h + norm("post_mlp_norm", BRANCH_SCALE_INIT)(f)
+        return h + norm("post_mlp_norm", BRANCH_SCALE_INIT)(
+            checkpoint_name(f, MLP_OUT_NAME))

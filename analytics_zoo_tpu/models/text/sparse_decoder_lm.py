@@ -20,12 +20,18 @@ The vocabulary and the experts may be one chip's share of a larger
 deployment: ``vocab`` rows of the table and the head, ``n_held`` of
 ``n_routed`` experts (``keras/layers/moe.DroplessExperts``). Each layer
 is rematerialised for the backward pass, which keeps its input and the
-two results of the flash attention kernel (its output and its
-logsumexp, 201 MB a layer at [1, 32, 8192, 128]): the second forward
-runs the projections, the norms and the expert layer again, and no
-attention kernel. On the paths that hold [L, L] scores (the CPU's,
-short sequences) nothing carries those names and only the input is
-kept.
+values named in ``KEPT_NAMES``, each O(L) and dear to compute again:
+the two results of the flash attention kernel (its output and its
+logsumexp, 201 MB a layer at [1, 32, 8192, 128]), q, k and v as the
+kernel reads them, the output projection's result, SwiGLU's two
+pre-activations (the dense layer's and the shared expert's), and what
+one layer type alone has (the sparse decoder's query product, gate
+and MLP branch; the latent decoder's rotary key; the byte decoder's
+chunk summaries and MLP input). The second forward runs the norms, the
+narrow products a norm's backward reads and the routed experts' passes
+again: no attention kernel and no wide product.
+On the paths that hold [L, L] scores (the CPU's, short sequences)
+nothing carries the kernel's two names; the others are kept there too.
 """
 
 from __future__ import annotations
@@ -37,9 +43,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from analytics_zoo_tpu.keras.layers.byte_decoder import ByteDecoderLayer
-from analytics_zoo_tpu.keras.layers.latent_decoder import LatentDecoderLayer
+from analytics_zoo_tpu.keras.layers.byte_decoder import (
+    EVA_K_SUMMARY_NAME, EVA_V_SUMMARY_NAME, MLP_IN_NAME, ByteDecoderLayer)
+from analytics_zoo_tpu.keras.layers.latent_decoder import (
+    ATTENTION_K_ROT_NAME, LatentDecoderLayer)
+from analytics_zoo_tpu.keras.layers.moe import (
+    SWIGLU_GATE_NAME, SWIGLU_UP_NAME)
 from analytics_zoo_tpu.keras.layers.sparse_decoder import (
+    ATTENTION_GATE_NAME, ATTENTION_K_NAME, ATTENTION_OUT_NAME,
+    ATTENTION_Q_NAME, ATTENTION_Q_PROJ_NAME, ATTENTION_V_NAME, MLP_OUT_NAME,
     RMSNorm, SparseDecoderLayer)
 from analytics_zoo_tpu.models.common import ZooModel, register_model
 from analytics_zoo_tpu.ops.pallas_attention import (
@@ -96,14 +108,27 @@ def _embed(module, x, init_std: float = 0.02, dtype=None):
     return h
 
 
+# What the backward pass of a rematerialised layer finds kept, beside
+# the layer's input: values that are cheap to hold and dear to compute
+# again (docs/kernels.md "Named results for a caller that
+# rematerialises" has each one's bytes and price). Each name is defined
+# beside the value it names; a layer keeps those of them it carries.
+KEPT_NAMES = (
+    FLASH_OUT_NAME, FLASH_LSE_NAME,
+    SWIGLU_GATE_NAME, SWIGLU_UP_NAME,
+    ATTENTION_Q_NAME, ATTENTION_K_NAME, ATTENTION_V_NAME,
+    ATTENTION_K_ROT_NAME, EVA_K_SUMMARY_NAME, EVA_V_SUMMARY_NAME,
+    ATTENTION_Q_PROJ_NAME, ATTENTION_GATE_NAME, ATTENTION_OUT_NAME,
+    MLP_IN_NAME, MLP_OUT_NAME)
+
+
 def _rematerialised(layer_cls):
-    """The backward pass keeps the layer's input and, where the flash
-    kernel ran, its output and logsumexp (the names exist on no other
-    attention path); all else is computed again."""
+    """The backward pass keeps the layer's input and the values named
+    in ``KEPT_NAMES`` (the flash kernel's two exist only where it ran);
+    all else is computed again."""
     return nn.remat(
         layer_cls, static_argnums=(2,),
-        policy=jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT_NAME, FLASH_LSE_NAME))
+        policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
 
 
 def _logits(module, h, heads: int = 1, init_std: float = 0.02,

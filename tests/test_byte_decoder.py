@@ -404,6 +404,12 @@ def test_remat_layer_backward_keeps_the_joint_output(monkeypatch):
     text = str(jaxpr)
     assert text.count("pallas_call[") == 2 * 6
     assert "name=flash_attention_out" in text
+    # q, k, v and the chunk summaries as the kernels read them, the
+    # branch's output, the MLP's input and its two pre-activations
+    for name in ("attention_q", "attention_k", "attention_v",
+                 "eva_k_summary", "eva_v_summary", "attention_out",
+                 "mlp_in", "swiglu_gate", "swiglu_up"):
+        assert text.count(f"name={name}]") == 2, name
     assert "attention_flash_eva" in jaxpr.pretty_print(name_stack=True)
 
 

@@ -10,6 +10,7 @@ off and the dispatcher is told its platform.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs under /tmp
 
@@ -302,9 +303,54 @@ def test_latent_decoder_layers_backward_holds_two_kernels_a_layer(one_chip):
     assert sum(line.count("bf16[16,8192,192]") for line in kernels) == 2 * 3
 
 
-def _gradient_kernels(module, loss_of, sharding):
-    """The Pallas kernels in the compiled gradient of a decoder's
-    next-token loss at L8192, and the whole text."""
+def _one_layer_of(kind):
+    """One dense layer of each decoder at its published widths (the
+    vocabulary cut to 1,024 rows), its loss, and the products its second
+    forward still holds."""
+    from analytics_zoo_tpu.models.text import sparse_decoder_lm as lm
+
+    sparse = dict(vocab=1024, hidden_size=2048, n_dense_layers=1,
+                  dtype=jnp.bfloat16)
+    if kind == "gated":        # Trinity-Mini; QK-norm's backward reads k
+        return lm.SparseDecoderModule(
+            **sparse, layer_types=("sliding_attention",), n_head=32,
+            n_kv_head=4, head_dim=128, window=2048, dense_width=6144,
+            expert_width=1024, n_routed=128, n_held=16), \
+            lm.next_token_loss, {"attention/k"}
+    if kind == "latent":       # Moonlight; the latent norm's reads kv_down
+        return lm.LatentDecoderModule(
+            **sparse, n_layers=1, n_head=16, nope_dim=128, rope_dim=64,
+            v_dim=128, latent_dim=512, dense_width=11264, expert_width=1408,
+            n_routed=64, n_held=8), lm.next_token_loss, {"attention/kv_down"}
+    return lm.ByteDecoderModule(           # EvaByte
+        vocab=320, hidden_size=4096, n_layers=1, n_head=32, head_dim=128,
+        window=2048, chunk=16, dense_width=11008, n_pred_heads=8,
+        dtype=jnp.bfloat16), lm.multi_byte_loss, set()
+
+
+@pytest.mark.parametrize("kind", ["gated", "latent", "eva"])
+def test_second_forward_holds_no_kept_product(one_chip, kind):
+    """One rematerialised layer of each type at L8192, compiled with
+    its gradient: the second forward holds none of the products whose
+    results the layer keeps by name (SwiGLU's three, q, v, gate, out,
+    ``kv_up``), only the narrow ones a norm's backward reads, and no
+    attention kernel. Prints the temporaries' bytes, which is how much
+    one layer's kept values and backward cost on the chip."""
+    module, loss_of, left = _one_layer_of(kind)
+    compiled = _gradient(module, loss_of, one_chip)
+    text = compiled.as_text()
+    assert set(re.findall(
+        r'rematted_computation/layer_0/([\w/]+)/dot_general"', text)) == left
+    assert not any("tpu_custom_call" in line and "rematted_computation" in line
+                   for line in text.splitlines())
+    memory = compiled.memory_analysis()
+    print(f"{kind}: one layer's gradient at L8192 holds "
+          f"{memory.temp_size_in_bytes / 1e6:.0f} MB of temporaries")
+    assert memory.temp_size_in_bytes < 4e9
+
+
+def _gradient(module, loss_of, sharding):
+    """The compiled gradient of a decoder's loss at L8192."""
     ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=sharding)
     params = _shapes_on(jax.eval_shape(
         module.init, jax.random.PRNGKey(0),
@@ -313,6 +359,12 @@ def _gradient_kernels(module, loss_of, sharding):
     def loss(params, ids):
         return loss_of(module.apply({"params": params}, ids), ids)
 
-    text = jax.jit(jax.grad(loss)).lower(params, ids).compile().as_text()
+    return jax.jit(jax.grad(loss)).lower(params, ids).compile()
+
+
+def _gradient_kernels(module, loss_of, sharding):
+    """The Pallas kernels in the compiled gradient of a decoder's
+    next-token loss at L8192, and the whole text."""
+    text = _gradient(module, loss_of, sharding).as_text()
     return [line for line in text.splitlines()
             if "tpu_custom_call" in line], text

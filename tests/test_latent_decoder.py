@@ -439,6 +439,9 @@ def test_remat_layer_backward_holds_two_flash_kernels(monkeypatch):
     text = str(jax.make_jaxpr(jax.grad(loss))(params))
     assert text.count("pallas_call[") == 2 * 2
     assert "name=flash_attention_out" in text
+    # the shared rotary key is kept as one head, beside q, k_nope and v
+    assert text.count("name=attention_k_rot]") == 2
+    assert "bf16" not in text       # float32 stays float32 when kept
 
 
 # ------------------------------------------------------------------ #

@@ -4,19 +4,24 @@ and full attention over grouped heads, the dropless expert layer and
 its share of a deployment, the router's bias step, and the whole model
 through ``Estimator``."""
 
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from analytics_zoo_tpu.keras.layers.byte_decoder import ByteDecoderLayer
+from analytics_zoo_tpu.keras.layers.latent_decoder import LatentDecoderLayer
 from analytics_zoo_tpu.keras.layers.moe import DroplessExperts, grouped_dot
 from analytics_zoo_tpu.keras.layers.sparse_decoder import (
-    FULL, SLIDING, GatedGroupedAttention)
+    FULL, SLIDING, GatedGroupedAttention, SparseDecoderLayer)
 from analytics_zoo_tpu.learn.optim import AdamWeightDecay
 from analytics_zoo_tpu.models.text import SparseDecoderLM
 from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
-    SparseDecoderModule, next_token_loss)
+    KEPT_NAMES, ByteDecoderModule, LatentDecoderModule, SparseDecoderModule,
+    _rematerialised, multi_byte_loss, next_token_loss)
 from analytics_zoo_tpu.obs.metrics import get_registry
 from analytics_zoo_tpu.ops import attention, pallas_attention
 from analytics_zoo_tpu.ops.attention import (
@@ -728,7 +733,7 @@ def test_model_matches_reference_in_bfloat16():
 
 
 # ------------------------------------------------------------------ #
-# rematerialisation keeps the flash kernel's two results             #
+# what a rematerialised layer keeps                                  #
 # ------------------------------------------------------------------ #
 KINDS = pytest.mark.parametrize("kind", [SLIDING, FULL])
 
@@ -742,20 +747,34 @@ def on_the_chip(monkeypatch):
 
 
 def _one_layer(kind):
-    """``SparseDecoderModule`` with one dense layer of ``kind`` at
-    L1024, which the flash path takes (2 query heads over 1 KV head of
-    64, window 256), its parameters, and a loss of them."""
-    module = SparseDecoderModule(
-        vocab=64, hidden_size=64, layer_types=(kind,), n_dense_layers=1,
-        n_head=2, n_kv_head=1, head_dim=64, window=256, dense_width=96,
-        expert_width=16, n_routed=8, n_held=4)
-    ids = np.random.default_rng(0).integers(0, 64, (1, 1024)).astype(
+    """A decoder with one dense layer of ``kind`` (a sparse decoder's
+    window or full kind, ``"latent"`` or ``"byte"``) at a length the
+    flash path takes, its parameters, and a loss of them."""
+    common = dict(vocab=64, dense_width=96)
+    sparse = dict(hidden_size=64, n_dense_layers=1, n_head=2, expert_width=16,
+                  n_routed=8, n_held=4)
+    length, loss_of = 1024, next_token_loss
+    if kind == "latent":
+        module = LatentDecoderModule(
+            **common, **sparse, n_layers=1, nope_dim=64, rope_dim=64,
+            v_dim=64, latent_dim=32)
+    elif kind == "byte":
+        # three windows of 1,024 with 128 summaries each
+        module = ByteDecoderModule(
+            **common, hidden_size=128, n_layers=1, n_head=2, head_dim=64,
+            window=1024, chunk=8, n_pred_heads=2)
+        length, loss_of = 3072, multi_byte_loss
+    else:
+        module = SparseDecoderModule(
+            **common, **sparse, layer_types=(kind,), n_kv_head=1,
+            head_dim=64, window=256)
+    ids = np.random.default_rng(0).integers(0, 64, (1, length)).astype(
         np.int32)
     params = module.init(jax.random.PRNGKey(0), ids)["params"]
 
     def loss(params):
         logits = module.apply({"params": params}, ids)
-        return next_token_loss(logits, jnp.roll(ids, -1, 1)), logits
+        return loss_of(logits, jnp.roll(ids, -1, 1)), logits
 
     return params, loss
 
@@ -774,8 +793,8 @@ def test_remat_layer_backward_holds_two_flash_kernels(
     """Forward + logsumexp and the one backward kernel: the layer's
     second forward holds no attention kernel, because both results of
     the first are kept. Without the policy it holds a third; on the path
-    that holds [L, L] scores nothing carries the names and nothing but
-    the input is kept."""
+    that holds [L, L] scores nothing carries the flash names (the
+    operands' and SwiGLU's, which are O(L), are carried there too)."""
     params, loss = _one_layer(kind)
 
     def text():
@@ -788,28 +807,167 @@ def test_remat_layer_backward_holds_two_flash_kernels(
     monkeypatch.setattr(attention, "_platform", lambda q: "cpu")
     scores = text()
     assert "pallas_call[" not in scores and "name=flash_" not in scores
+    assert "name=attention_q]" in scores and "name=swiglu_up]" in scores
 
 
-@KINDS
-def test_remat_policy_changes_no_bit(monkeypatch, on_the_chip, kind):
-    """The kept values are what the kernel would produce again: loss,
-    logits and every gradient leaf equal those of the layer rematerialised
-    whole."""
-    params, loss = _one_layer(kind)
+REMAT_KINDS = pytest.mark.parametrize(
+    "kind", [SLIDING, FULL, "latent", "byte"])
+_EPS32 = float(np.finfo(np.float32).eps)
 
-    def run():
-        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
 
-    (kept_loss, kept_logits), kept_grads = run()
+def _kept_and_whole(monkeypatch, kind, run):
+    """``run(value_and_grad of the loss, parameters)`` of one layer of
+    ``kind`` under the policy, and of the same layer rematerialised
+    whole: two ((loss, logits), gradients)."""
+    def once():
+        params, loss = _one_layer(kind)
+        return run(jax.value_and_grad(loss, has_aux=True), params)
+
+    kept = once()
     _without_policy(monkeypatch)
-    (loss_, logits), grads = run()
-    assert float(kept_loss) == float(loss_)
-    np.testing.assert_array_equal(kept_logits, logits)
-    for (path, g), k in zip(jax.tree_util.tree_leaves_with_path(grads),
-                            jax.tree_util.tree_leaves(kept_grads),
-                            strict=True):
-        assert np.abs(np.asarray(g)).max() > 0, jax.tree_util.keystr(path)
-        np.testing.assert_array_equal(k, g, jax.tree_util.keystr(path))
+    return kept, once()
+
+
+def _assert_same(kept, whole, eps=0):
+    """Loss, logits and every gradient leaf, none of them all zero:
+    equal to the bit, or within ``eps`` float32 roundings of the leaf's
+    largest magnitude."""
+    kept, whole = (dict(jax.tree_util.tree_leaves_with_path(side))
+                   for side in (kept, whole))
+    assert kept.keys() == whole.keys()
+    for path, want in whole.items():
+        largest = float(jnp.max(jnp.abs(want)))
+        assert largest > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(
+            kept[path], want, rtol=0, atol=eps * _EPS32 * largest,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@REMAT_KINDS
+def test_remat_policy_changes_no_bit(monkeypatch, on_the_chip, kind):
+    """The kept values are what the second forward would produce again:
+    compiled as one program in float32, loss, logits and every gradient
+    leaf equal those of the layer rematerialised whole, to the bit for
+    the full, latent and byte kinds.
+
+    The sliding kind's two compiled programs differ already in their
+    *first* forward (PR 34): keeping ``attention_q`` makes XLA:CPU cut
+    QK-norm + RoPE + transpose into other fusions (the rotated half
+    arrives from a fusion of its own), the HLO arithmetic is the same
+    ``a * b + c * d``, and LLVM contracts it into another fused
+    multiply-add. Measured here: logits within 3 roundings of the
+    largest logit, gradients within 5 of each leaf's largest entry;
+    held to 8. No other name does it, and with the backend's
+    optimisations off the sliding kind's programs agree to the bit too
+    (``test_remat_policy_changes_no_bit_before_llvm``)."""
+    kept, whole = _kept_and_whole(
+        monkeypatch, kind, lambda grad, params: jax.jit(grad)(params))
+    _assert_same(kept, whole, eps=8 if kind == SLIDING else 0)
+
+
+def test_remat_policy_changes_no_bit_before_llvm(monkeypatch, on_the_chip):
+    """The sliding kind compiled with XLA:CPU's backend optimisations
+    off (no contraction into fused multiply-adds): equal to the bit, so
+    what the compiled programs differ by is the compiler's arithmetic
+    and not a kept value."""
+    def run(grad, params):
+        return jax.jit(grad).lower(params).compile(
+            compiler_options={"xla_backend_optimization_level": 0})(params)
+
+    _assert_same(*_kept_and_whole(monkeypatch, SLIDING, run))
+
+
+@REMAT_KINDS
+def test_remat_policy_changes_no_bit_op_by_op(monkeypatch, on_the_chip, kind):
+    """The same comparison with every operation evaluated on its own,
+    so no compiler's fusion stands between a kept value and its
+    recomputation: equal to the bit for every kind."""
+    def run(grad, params):
+        with jax.disable_jit():
+            return grad(params)
+
+    _assert_same(*_kept_and_whole(monkeypatch, kind, run))
+
+
+_EXPERTS = dict(width=16, n_routed=8, n_held=4, top_k=2, shared_width=32)
+_GATED = dict(n_head=2, n_kv_head=1, head_dim=64, window=256, dense_width=96)
+_BF16, _F32 = jnp.bfloat16, jnp.float32
+# the gated attention branch and the MLP branch of a sparse decoder layer
+_GATED_KEPT = {
+    "attention_q_proj": (1, 1024, 128), "attention_gate": (1, 1024, 128),
+    "attention_q": (1, 2, 1024, 64), "attention_k": (1, 1, 1024, 64),
+    "attention_v": (1, 1, 1024, 64), "flash_attention_out": (1, 2, 1024, 64),
+    "flash_attention_lse": (2, 1024, 128), "attention_out": (1, 1024, 64),
+    "mlp_out": (1, 1024, 64)}
+# layer class, its arguments, width d, length L, and every value the
+# backward pass finds kept beside the layer's input: name -> shape
+# (bfloat16 as the forward makes them; the logsumexp float32)
+KEPT = {
+    "sliding": (SparseDecoderLayer, dict(kind=SLIDING, **_GATED), 64, 1024, {
+        **_GATED_KEPT,
+        "swiglu_gate": (1, 1024, 96), "swiglu_up": (1, 1024, 96)}),
+    # the shared expert's SwiGLU (two experts' width in one); nothing
+    # under moe_route / moe_dispatch / moe_experts / moe_combine
+    "full_experts": (SparseDecoderLayer,
+                     dict(kind=FULL, **_GATED, experts=_EXPERTS), 64, 1024, {
+        **_GATED_KEPT,
+        "swiglu_gate": (1024, 32), "swiglu_up": (1024, 32)}),
+    "latent": (LatentDecoderLayer, dict(
+        attention=dict(n_head=2, nope_dim=64, rope_dim=64, v_dim=64,
+                       latent_dim=32), dense_width=96), 64, 1024, {
+        "attention_q": (1, 2, 1024, 128), "attention_k": (1, 2, 1024, 64),
+        "attention_k_rot": (1, 1, 1024, 64), "attention_v": (1, 2, 1024, 64),
+        "flash_attention_out": (1, 2, 1024, 64),
+        "flash_attention_lse": (2, 1024, 128), "attention_out": (1, 1024, 64),
+        "swiglu_gate": (1, 1024, 96), "swiglu_up": (1, 1024, 96)}),
+    "byte": (ByteDecoderLayer, dict(
+        attention=dict(n_head=2, head_dim=64, window=1024, chunk=8),
+        dense_width=96), 128, 3072, {
+        "attention_q": (1, 2, 3072, 64), "attention_k": (1, 2, 3072, 64),
+        "attention_v": (1, 2, 3072, 64),
+        "flash_attention_out": (1, 2, 3072, 64),
+        "flash_attention_lse": (2, 3072, 128), "attention_out": (1, 3072, 128),
+        "eva_k_summary": (1, 2, 384, 64), "eva_v_summary": (1, 2, 384, 64),
+        "mlp_in": (1, 3072, 128),
+        "swiglu_gate": (1, 3072, 96), "swiglu_up": (1, 3072, 96)}),
+}
+
+
+@pytest.mark.parametrize("layer", sorted(KEPT))
+def test_rematerialised_layer_keeps_exactly_the_named_values(
+        on_the_chip, capsys, layer):
+    """One layer of each type under ``_rematerialised``, traced in
+    bfloat16 on the flash path: the names it carries are the intended
+    ones at the intended shapes, in the dtype the forward made them, all
+    of them are in the policy's one tuple, and the residuals of its
+    gradient are the layer's arguments and those values, no other."""
+    cls, kwargs, d, length, want = KEPT[layer]
+    module = _rematerialised(cls)(**kwargs, dtype=_BF16)
+    h = jax.ShapeDtypeStruct((1, length, d), _BF16)
+    variables = jax.eval_shape(
+        lambda key, x: module.init(key, x, False), jax.random.PRNGKey(0), h)
+    params = variables.pop("params")
+
+    def scalar(params, h):
+        return jnp.sum(module.apply({"params": params, **variables}, h,
+                                    False).astype(_F32))
+
+    want = {name: ("f32" if name.endswith("_lse") else "bf16")
+            + str(list(shape)).replace(" ", "")
+            for name, shape in want.items()}
+    # every ``name`` equation of the gradient, as the jaxpr prints it
+    # (the kernel's two are named in its custom_vjp's forward rule)
+    named = {name: value for value, name in re.findall(
+        r":(\w+\[[\d,]*\]) = name\[name=(\w+)\]",
+        str(jax.make_jaxpr(jax.grad(scalar))(params, h)))}
+    assert named == want
+    assert set(named) <= set(KEPT_NAMES)
+    jax.ad_checkpoint.print_saved_residuals(scalar, params, h)
+    kept = sorted(line.split()[0]
+                  for line in capsys.readouterr().out.splitlines()
+                  if "from the argument" not in line
+                  and "from a constant" not in line)    # RoPE's frequencies
+    assert kept == sorted(want.values())
 
 
 @pytest.mark.parametrize("window", [256, None])
@@ -842,6 +1000,49 @@ def test_names_outside_a_policy_change_no_program(monkeypatch, window):
     named, bare = texts
     assert named.count("stablehlo.custom_call @tpu_custom_call") == 2
     assert named == bare
+
+
+@pytest.mark.parametrize("which", ["swiglu", "gated", "latent", "eva"])
+def test_layer_names_outside_a_policy_change_no_program(monkeypatch, which):
+    """The values a layer names: a differentiated ``SwiGLU`` and each
+    attention module under no ``jax.checkpoint`` (``MoEFFN``'s users,
+    serving, a model without ``nn.remat``) lower to the same StableHLO
+    with the names and without them."""
+    from analytics_zoo_tpu.keras.layers import (
+        byte_decoder, latent_decoder, moe, sparse_decoder)
+
+    module, files, shape = {
+        "swiglu": (moe.SwiGLU(96, dtype=_BF16), (moe,), (1, 64, 32)),
+        "gated": (GatedGroupedAttention(2, 1, 16, window=8, dtype=_BF16),
+                  (sparse_decoder,), (1, 64, 32)),
+        "latent": (latent_decoder.LatentAttention(
+            n_head=2, nope_dim=16, rope_dim=8, v_dim=16, latent_dim=8,
+            dtype=_BF16), (latent_decoder, sparse_decoder), (1, 64, 32)),
+        "eva": (byte_decoder.EvaAttention(
+            n_head=2, head_dim=16, window=16, chunk=4, dtype=_BF16),
+            (byte_decoder, sparse_decoder), (1, 64, 32)),
+    }[which]
+    x = jax.ShapeDtypeStruct(shape, _BF16)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+
+    def traced():
+        def scalar(params, x):      # anew: a trace is cached by function
+            return jnp.sum(module.apply(params, x).astype(_F32))
+
+        return jax.jit(jax.grad(scalar, (0, 1))).trace(params, x)
+
+    named = traced()
+    assert "= name[" in str(named.jaxpr)
+    for file in files:
+        monkeypatch.setattr(file, "checkpoint_name", lambda x, name: x)
+    bare = traced()
+    assert "= name[" not in str(bare.jaxpr)
+
+    def text(traced):
+        # private functions are numbered in the order they were traced
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", traced.lower().as_text())
+
+    assert text(bare) == text(named)
 
 
 def test_fit_updates_router_state_and_publishes_counters(monkeypatch,
