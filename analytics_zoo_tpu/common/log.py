@@ -101,13 +101,18 @@ class Timer:
             yield
         finally:
             elapsed = time.perf_counter() - start
-            with self._lock:
-                stat = self._stats.setdefault(name, TimerStat(name))
-                stat.record(elapsed)
-            if self._mirror is not None:
-                self._mirror.labels(stage=name).observe(elapsed)
+            self.record(name, elapsed)
             if log is not None:
                 log.info("%s took %.2f ms", name, elapsed * 1e3)
+
+    def record(self, name: str, elapsed: float) -> None:
+        """One duration that the caller timed itself (``timing`` is this
+        plus the two clock readings)."""
+        with self._lock:
+            stat = self._stats.setdefault(name, TimerStat(name))
+            stat.record(elapsed)
+        if self._mirror is not None:
+            self._mirror.labels(stage=name).observe(elapsed)
 
     def stat(self, name: str) -> Optional[TimerStat]:
         with self._lock:

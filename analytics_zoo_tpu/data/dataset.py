@@ -24,6 +24,7 @@ import os
 import queue
 import tempfile
 import threading
+import time
 from typing import Any, Callable, Iterator, Optional, Tuple
 
 import jax
@@ -31,6 +32,7 @@ import numpy as np
 
 from analytics_zoo_tpu.common.config import get_config
 from analytics_zoo_tpu.common.log import get_logger
+from analytics_zoo_tpu.obs.tracing import get_tracer
 
 logger = get_logger(__name__)
 
@@ -295,7 +297,8 @@ class ZooDataset:
     def device_iterator(self, batch_size: int, mesh=None, shuffle: bool = True,
                         seed: int = 0, epoch: int = 0,
                         drop_remainder: bool = True, with_mask: bool = False,
-                        prefetch: Optional[int] = None
+                        prefetch: Optional[int] = None,
+                        spans: Optional[Tuple[str, int]] = None
                         ) -> Iterator[Tuple[Any, ...]]:
         """``batches`` + mesh placement + background prefetch.
 
@@ -303,6 +306,14 @@ class ZooDataset:
         (default: the ``zoo.data.prefetch_buffer`` config key) while
         the consumer runs the train step -- the analog of FeatureSet's
         cached-RDD prefetch, but across the host->HBM boundary.
+
+        ``spans`` = ``(trace_id, index of the first batch)``: the
+        producer records, per batch, a span ``host_batch`` (one
+        ``next()`` of ``batches``: slicing, shuffling, stacking on the
+        host) and a span ``shard_batch`` (the placement of its parts),
+        each with the batch's index ``i``, in the process's span ring
+        (``Estimator.fit`` passes its call's id and step index, so a
+        step's wait joins the batch that caused it by ``i``).
         """
         if prefetch is None:
             prefetch = int(get_config().get("zoo.data.prefetch_buffer",
@@ -330,12 +341,28 @@ class ZooDataset:
 
         def produce():
             try:
-                for item in self.batches(batch_size, shuffle, seed, epoch,
-                                         drop_remainder, mesh,
-                                         with_mask=with_mask):
+                host = self.batches(batch_size, shuffle, seed, epoch,
+                                    drop_remainder, mesh,
+                                    with_mask=with_mask)
+                if spans is not None:
+                    tracer = get_tracer()
+                    trace_id, i = spans
+                while True:
+                    t0 = time.perf_counter()
+                    item = next(host, _SENTINEL)
+                    if item is _SENTINEL:
+                        return
+                    t1 = time.perf_counter()
                     placed = tuple(
                         shard_batch(part, mesh) if part is not None else None
                         for part in item)
+                    if spans is not None:
+                        tracer.add_span("host_batch", trace_id, t0, t1,
+                                        cat="train", i=i)
+                        tracer.add_span("shard_batch", trace_id, t1,
+                                        time.perf_counter(), cat="train",
+                                        i=i)
+                        i += 1
                     if not put(placed):
                         return
             except BaseException as e:  # surface in consumer
@@ -343,7 +370,8 @@ class ZooDataset:
             finally:
                 put(_SENTINEL)
 
-        t = threading.Thread(target=produce, daemon=True)
+        t = threading.Thread(target=produce, daemon=True,
+                             name="zoo-input-producer")
         t.start()
         try:
             while True:

@@ -16,9 +16,8 @@ mirrors InternalDistriOptimizer.train (ref: Topology.scala:1255-1332).
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import inspect
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -37,6 +36,7 @@ from analytics_zoo_tpu.learn.objectives import resolve_loss
 from analytics_zoo_tpu.learn.optim import resolve_optimizer
 from analytics_zoo_tpu.obs.events import emit, instrument_compiles
 from analytics_zoo_tpu.obs.metrics import get_registry
+from analytics_zoo_tpu.obs.tracing import get_tracer, new_trace_id
 from analytics_zoo_tpu.parallel import sharding
 from analytics_zoo_tpu.parallel.mesh import default_mesh
 from analytics_zoo_tpu.parallel.sharding import replicated
@@ -86,11 +86,60 @@ def _call_args(x) -> tuple:
     return (x,)
 
 
-def _stage(profiler, name: str):
-    """Profiler stage context (nullcontext when profiling is off)."""
-    if profiler is not None:
-        return profiler.timing(name)
-    return contextlib.nullcontext()
+class _FitCall:
+    """What the spans of one ``fit`` call share: the collector, the
+    call's ``trace_id``, its start, the profiler where ``profile=True``
+    asked for one, and ``i``, the index in the call of the step that is
+    next (it runs on across the call's epochs)."""
+
+    __slots__ = ("tracer", "trace_id", "t0", "profiler", "i", "_prepared")
+
+    def __init__(self):
+        self.t0 = time.perf_counter()
+        self.tracer = get_tracer()
+        self.trace_id = new_trace_id()
+        self.profiler = None
+        self.i = 0
+        self._prepared = False
+
+    def span(self, name: str, t0: float, t1: float, **args) -> None:
+        self.tracer.add_span(name, self.trace_id, t0, t1, cat="train",
+                             **args)
+
+    def prepared(self) -> None:
+        """Everything before the call's first ``data_wait`` is
+        ``fit_prepare``; called where each epoch's loop is about to
+        start, it records the span the first time."""
+        if not self._prepared:
+            self._prepared = True
+            self.span("fit_prepare", self.t0, time.perf_counter())
+
+
+# the stages ``fit(profile=True)`` sums (``TrainingProfiler.summary``)
+_PROFILED_STAGES = ("data_wait", "train_step")
+
+
+class _stage:
+    """One timed region of a ``fit`` call (a context manager). The clock
+    is read once at entry and once at exit; that one pair feeds the span
+    ring, always, and the call's ``TrainingProfiler`` where there is
+    one."""
+
+    __slots__ = ("call", "name", "args", "t0", "t1")
+
+    def __init__(self, call: _FitCall, name: str, **args):
+        self.call, self.name, self.args = call, name, args
+
+    def __enter__(self) -> "_stage":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        call = self.call
+        call.span(self.name, self.t0, self.t1, **self.args)
+        if call.profiler is not None and self.name in _PROFILED_STAGES:
+            call.profiler.record(self.name, self.t1 - self.t0)
 
 
 # sow-style collections: written fresh per apply, never carried as
@@ -559,74 +608,93 @@ class Estimator:
         into ``self.last_profile`` (a ``TrainingProfiler``; the Ray
         runners' profile=True analog, ref: pytorch_ray_estimator.py:
         150-190); ``trace_dir`` additionally captures a jax.profiler
-        device trace viewable in TensorBoard.
+        device trace viewable in TensorBoard, and writes the call's
+        spans beside it (``fit_spans.<trace id>.trace.json``, Chrome
+        trace JSON on the same clock).
+
+        Every call records its spans in the process's span ring
+        (``obs.tracing.get_tracer()``), with no switch: ``fit`` itself,
+        ``fit_prepare``, each step's ``data_wait`` and ``train_step``
+        (and the producer thread's ``host_batch`` and ``shard_batch``)
+        with the step's index ``i``, ``log_sync``, ``epoch_sync`` and
+        ``publish_counters``, under one ``trace_id`` a call
+        (docs/observability.md "Training spans").
         """
-        cfg = get_config()
-        dataset = _as_dataset(data)
-        val_dataset = (_as_dataset(validation_data)
-                       if validation_data is not None else None)
-        validation_trigger = validation_trigger or EveryEpoch()
-        checkpoint_trigger = checkpoint_trigger or EveryEpoch()
-        self._ensure_built(self._probe_example(dataset, batch_size))
-        if resume and checkpoint_dir and \
-                ckpt_lib.latest_step(checkpoint_dir) is not None:
-            self._restore(checkpoint_dir)
-        profiler = None
-        if profile or trace_dir:
-            from analytics_zoo_tpu.learn.profiler import TrainingProfiler
-
-            profiler = TrainingProfiler(trace_dir=trace_dir)
-            self.last_profile = profiler
-            profiler.start_trace()
-        emit("train_start", "learn", epochs=epochs,
-             batch_size=batch_size, device_cache=bool(device_cache))
+        call = _FitCall()
         try:
-            if device_cache:
-                if jax.process_count() > 1:
-                    raise ValueError("device_cache supports "
-                                     "single-process runs only")
-                return self._fit_device_cached(
-                    dataset, val_dataset, batch_size, epochs,
-                    validation_trigger, checkpoint_trigger,
-                    checkpoint_dir, log_dir, profiler)
+            cfg = get_config()
+            dataset = _as_dataset(data)
+            val_dataset = (_as_dataset(validation_data)
+                           if validation_data is not None else None)
+            validation_trigger = validation_trigger or EveryEpoch()
+            checkpoint_trigger = checkpoint_trigger or EveryEpoch()
+            self._ensure_built(self._probe_example(dataset, batch_size))
+            if resume and checkpoint_dir and \
+                    ckpt_lib.latest_step(checkpoint_dir) is not None:
+                self._restore(checkpoint_dir)
+            profiler = None
+            if profile or trace_dir:
+                from analytics_zoo_tpu.learn.profiler import TrainingProfiler
 
-            train_step = self._build_train_step()
-            writer = self._make_writer(log_dir)
-            log_every = cfg.get("zoo.train.log_every_n_steps")
-            retry_times = cfg.get("zoo.train.failure.retry_times")
-            retry_interval = cfg.get("zoo.train.failure.retry_interval_s")
-            failures: List[float] = []
-            history: List[Dict[str, float]] = []
-            state = TriggerState(epoch=self.epoch,
-                                 iteration=self.global_step)
-            steps_per_epoch = dataset.steps_per_epoch(batch_size)
+                profiler = call.profiler = TrainingProfiler(
+                    trace_dir=trace_dir)
+                self.last_profile = profiler
+                profiler.start_trace()
+            emit("train_start", "learn", epochs=epochs,
+                 batch_size=batch_size, device_cache=bool(device_cache))
             try:
-                return self._fit_loop(
-                    dataset, val_dataset, batch_size, epochs, train_step,
-                    writer, log_every, retry_times, retry_interval,
-                    validation_trigger, checkpoint_trigger,
-                    checkpoint_dir, failures, history, state,
-                    steps_per_epoch, profiler)
+                if device_cache:
+                    if jax.process_count() > 1:
+                        raise ValueError("device_cache supports "
+                                         "single-process runs only")
+                    return self._fit_device_cached(
+                        dataset, val_dataset, batch_size, epochs,
+                        validation_trigger, checkpoint_trigger,
+                        checkpoint_dir, log_dir, call)
+
+                train_step = self._build_train_step()
+                writer = self._make_writer(log_dir)
+                log_every = cfg.get("zoo.train.log_every_n_steps")
+                retry_times = cfg.get("zoo.train.failure.retry_times")
+                retry_interval = cfg.get("zoo.train.failure.retry_interval_s")
+                failures: List[float] = []
+                history: List[Dict[str, float]] = []
+                state = TriggerState(epoch=self.epoch,
+                                     iteration=self.global_step)
+                steps_per_epoch = dataset.steps_per_epoch(batch_size)
+                try:
+                    return self._fit_loop(
+                        dataset, val_dataset, batch_size, epochs, train_step,
+                        writer, log_every, retry_times, retry_interval,
+                        validation_trigger, checkpoint_trigger,
+                        checkpoint_dir, failures, history, state,
+                        steps_per_epoch, call)
+                finally:
+                    if writer:
+                        writer.close()
             finally:
-                if writer:
-                    writer.close()
+                emit("train_stop", "learn", epochs_run=self.epoch,
+                     global_step=self.global_step)
+                if profiler is not None:
+                    profiler.stop_trace()
+                    logger.info("training profile: %s", profiler.summary())
         finally:
-            emit("train_stop", "learn", epochs_run=self.epoch,
-                 global_step=self.global_step)
-            if profiler is not None:
-                profiler.stop_trace()
-                logger.info("training profile: %s", profiler.summary())
+            call.span("fit", call.t0, time.perf_counter(),
+                      epoch=self.epoch, steps=call.i)
+            if trace_dir:
+                call.tracer.dump_chrome_trace(
+                    os.path.join(trace_dir,
+                                 f"fit_spans.{call.trace_id}.trace.json"),
+                    call.trace_id)
 
     def _fit_loop(self, dataset, val_dataset, batch_size, epochs,
                   train_step, writer, log_every, retry_times,
                   retry_interval, validation_trigger, checkpoint_trigger,
                   checkpoint_dir, failures, history, state,
-                  steps_per_epoch, profiler=None
+                  steps_per_epoch, call: _FitCall
                   ) -> List[Dict[str, float]]:
-        stage = functools.partial(_stage, profiler)
-
         while self.epoch < epochs:
-            epoch_start = time.time()
+            epoch_start = time.perf_counter()
             # placed like the step's own loss_sum output: an unplaced
             # scalar has another type than the mesh-resident one that
             # comes back, and step 2 would trace and compile again
@@ -637,15 +705,17 @@ class Estimator:
             try:
                 batches = iter(dataset.device_iterator(
                     batch_size, mesh=self.mesh, shuffle=True,
-                    seed=self.seed, epoch=self.epoch))
+                    seed=self.seed, epoch=self.epoch,
+                    spans=(call.trace_id, call.i)))
+                call.prepared()
                 for step_in_epoch in range(steps_per_epoch):
-                    with stage("data_wait"):
+                    with _stage(call, "data_wait", i=call.i):
                         try:
                             x, y = next(batches)
                         except StopIteration:
                             break
                     self._rng, step_rng = jax.random.split(self._rng)
-                    with stage("train_step"):
+                    with _stage(call, "train_step", i=call.i):
                         (self.variables, self.opt_state, loss_sum,
                          loss) = train_step(self.variables,
                                             self.opt_state, loss_sum,
@@ -655,7 +725,8 @@ class Estimator:
                     _M_STEPS.inc()
                     if (self.global_step % log_every == 0 or
                             self.global_step == 1):
-                        lf = float(loss)
+                        with _stage(call, "log_sync", i=call.i):
+                            lf = float(loss)
                         # loss reaches triggers at log cadence only: a
                         # per-step float() would force a host sync every
                         # step and kill async dispatch
@@ -670,6 +741,7 @@ class Estimator:
                     # epoch boundaries count steps *within* this epoch, so
                     # they stay correct after a mid-epoch restore shifts
                     # global_step off the modulo grid.
+                    call.i += 1
                     finishing = step_in_epoch == steps_per_epoch - 1
                     state.iteration = self.global_step
                     state.epoch = self.epoch + (1 if finishing else 0)
@@ -691,13 +763,16 @@ class Estimator:
                 self.epoch += 1
                 _M_EPOCHS.inc()
                 state.epoch = self.epoch
+                with _stage(call, "epoch_sync", epoch=self.epoch) as sync:
+                    mean_loss = (float(loss_sum) / n_steps if n_steps
+                                 else float("nan"))
                 entry: Dict[str, float] = {
                     "epoch": self.epoch,
-                    "loss": (float(loss_sum) / n_steps if n_steps
-                             else float("nan")),
-                    "seconds": time.time() - epoch_start,
+                    "loss": mean_loss,
+                    "seconds": sync.t1 - epoch_start,
                 }
-                self._publish_counters()
+                with _stage(call, "publish_counters"):
+                    self._publish_counters()
                 if last_val is not None:
                     entry.update({f"val_{k}": v for k, v in last_val.items()})
                 history.append(entry)
@@ -767,11 +842,9 @@ class Estimator:
 
     def _fit_device_cached(self, dataset, val_dataset, batch_size,
                            epochs, validation_trigger, checkpoint_trigger,
-                           checkpoint_dir, log_dir, profiler=None
+                           checkpoint_dir, log_dir, call: _FitCall
                            ) -> List[Dict[str, float]]:
         from jax.sharding import NamedSharding, PartitionSpec as P
-
-        stage = functools.partial(_stage, profiler)
 
         cfg = get_config()
         n = dataset.num_samples
@@ -802,7 +875,7 @@ class Estimator:
                 step_before = self.global_step
                 try:
                     self._rng, erng = jax.random.split(self._rng)
-                    with stage("train_step"):
+                    with _stage(call, "train_step"):
                         (self.variables, self.opt_state,
                          mean_loss) = epoch_fn(
                             self.variables, self.opt_state, x_all,
@@ -818,6 +891,7 @@ class Estimator:
                     continue
                 self.epoch += 1
                 self.global_step += n_steps
+                call.i += n_steps
                 _M_EPOCHS.inc()
                 _M_STEPS.inc(n_steps)
                 self._publish_counters()

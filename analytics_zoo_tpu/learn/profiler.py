@@ -5,19 +5,29 @@ Round-1 gap (VERDICT row 29): the reference profiles serving via
 ``profile=True`` per-epoch time stats
 (ref: zoo/.../serving/engine/Timer.scala:24-90,
 pyzoo/zoo/orca/learn/pytorch/pytorch_ray_estimator.py:150-190,
-torch_runner.py:308-316). Here training profiling has two layers:
+torch_runner.py:308-316). Here training profiling has three layers
+that share one pair of clock readings per stage (``estimator._stage``):
 
-- ``TrainingProfiler``: host-side stage timers (data wait vs step
-  dispatch vs epoch wall time) with the same count/avg/max/min summary
-  shape as the serving Timer -- answers "am I input-bound?". Since
-  ISSUE-2 every stage duration also lands in the process-wide obs
-  registry (``zoo_learn_stage_duration_seconds{stage=...}``), so
-  training and serving share one scrape vocabulary.
+- The span ring (``obs/tracing.Tracer``), always on: every ``fit``
+  call's ``data_wait`` / ``train_step`` and the seven spans around them,
+  each with its start, its end and the step's index, on the clock a
+  device trace is on (docs/observability.md "Training spans"). It is
+  what says *which* waits left the chip idle.
+- ``TrainingProfiler`` (``fit(profile=True)``): the same two stages'
+  durations summed, with the same count/avg/max/min summary shape as
+  the serving Timer. ``input_bound_fraction`` is the share of the
+  caller's loop spent in ``next(batches)``: it counts waits the device
+  never felt (steps already queued) with the ones it did, so it is an
+  upper bound on "am I input-bound?", not the answer. Since ISSUE-2
+  every stage duration also lands in the process-wide obs registry
+  (``zoo_learn_stage_duration_seconds{stage=...}``), so training and
+  serving share one scrape vocabulary.
 - XLA device tracing: a device-only ``jax.profiler`` trace written to
   a TensorBoard-loadable directory when ``trace_dir`` is set -- answers
   "what is the chip doing?", op by op, each named by its module path
   and scope (the reference has no analog; BigDL had no device
-  profiler).
+  profiler). ``fit`` writes the call's spans beside it as Chrome trace
+  JSON on the same clock.
 """
 
 from __future__ import annotations
@@ -45,6 +55,11 @@ class TrainingProfiler:
     def timing(self, stage: str):
         """Host timer for the stage (a context manager)."""
         return self.timer.timing(stage)
+
+    def record(self, stage: str, elapsed: float) -> None:
+        """A stage duration the caller timed itself (``fit`` reads the
+        clock once for the span ring and for this)."""
+        self.timer.record(stage, elapsed)
 
     # ------------------------------------------------------- device trace --
     def start_trace(self) -> None:

@@ -1,17 +1,31 @@
-"""End-to-end request tracing for the serving pipeline.
+"""Spans: one bounded process-wide collector for the serving pipeline
+and the train path.
 
-A request that enters the data plane under an active trace context
-carries its trace id through the ``AZT1`` wire blob (``__trace__`` meta
-key, serving/queues.py), and each pipeline stage the request crosses --
-``decode``, ``dispatch``, ``finalize`` in the worker, ``http_request``
-in the frontend -- records a span against that id. Spans land in a
-bounded process-wide collector and export as Chrome trace-event JSON
+**Serving.** A request that enters the data plane under an active trace
+context carries its trace id through the ``AZT1`` wire blob
+(``__trace__`` meta key, serving/queues.py), and each pipeline stage the
+request crosses -- ``decode``, ``dispatch``, ``finalize`` in the worker,
+``http_request`` in the frontend -- records a span against that id.
+Request tracing is config-gated (``zoo.obs.trace.enabled``, default
+**false**) and designed so the disabled path costs nothing measurable:
+producers only read a thread-local (no config lookup per request), and
+the worker skips span emission entirely for requests that carry no
+trace id. ``maybe_trace`` and the request spans obey that switch.
+
+**Training.** ``Estimator.fit`` records its spans **always**, with no
+switch (learn/estimator.py; docs/observability.md "Training spans"):
+one ``trace_id`` a call, the step's index ``i`` on the caller's and the
+input producer's thread, at most four spans a step. The ring is the
+flight recorder's ``spans.json`` (obs/flight.py), so a crashed trainer's
+postmortem shows its last steps.
+
+**One clock.** Spans are stamped with ``time.perf_counter()`` (monotonic,
+one cheap call). The collector keeps one anchor pair read together,
+``(time.time_ns(), time.perf_counter())``, and :meth:`Tracer.wall_ns`
+puts a span on CLOCK_REALTIME: the axis a ``jax.profiler`` trace is on,
+so host spans and device operations join with the host tracer off.
+Spans export as Chrome trace-event JSON (timestamps on that wall clock)
 loadable in perfetto / chrome://tracing.
-
-Tracing is config-gated (``zoo.obs.trace.enabled``, default **false**)
-and designed so the disabled path costs nothing measurable: producers
-only read a thread-local (no config lookup per request), and the worker
-skips span emission entirely for requests that carry no trace id.
 
 Usage::
 
@@ -30,7 +44,7 @@ import threading
 import time
 import uuid
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from analytics_zoo_tpu.common.config import get_config
 
@@ -91,10 +105,10 @@ class Tracer:
     """Bounded collector of finished spans.
 
     A span is a dict: ``name``, ``trace_id``, ``t0``/``t1`` (module
-    perf_counter seconds), ``thread`` (recording thread's name), plus
-    free-form args. The ring holds ``max_spans`` (config
-    ``zoo.obs.trace.max_spans``); older spans fall off -- tracing is a
-    flight recorder, not an archive."""
+    perf_counter seconds), ``thread`` (recording thread's name), ``cat``
+    where one was given, plus free-form args. The ring holds
+    ``max_spans`` (config ``zoo.obs.trace.max_spans``); older spans fall
+    off -- tracing is a flight recorder, not an archive."""
 
     def __init__(self, max_spans: Optional[int] = None):
         if max_spans is None:
@@ -103,16 +117,26 @@ class Tracer:
         self._spans: collections.deque = collections.deque(
             maxlen=max_spans)
         self._lock = threading.Lock()
-        # perf_counter anchor so exported timestamps start near zero
-        self._epoch = time.perf_counter()
+        #: spans that have fallen off the ring: 0 means the ring still
+        #: holds the process's first span
+        self.dropped = 0
+        # the one anchor pair, read together: what puts a perf_counter
+        # stamp on CLOCK_REALTIME (both clocks tick at the same rate;
+        # only a stepped wall clock moves one against the other)
+        self._anchor: Tuple[int, float] = (time.time_ns(),
+                                           time.perf_counter())
 
     def add_span(self, name: str, trace_id: str, t0: float, t1: float,
-                 **args) -> None:
+                 cat: Optional[str] = None, **args) -> None:
         span = {"name": name, "trace_id": trace_id, "t0": t0, "t1": t1,
                 "thread": threading.current_thread().name}
+        if cat is not None:
+            span["cat"] = cat
         if args:
             span["args"] = args
         with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
             self._spans.append(span)
 
     def spans(self, trace_id: Optional[str] = None) -> List[Dict]:
@@ -124,15 +148,26 @@ class Tracer:
 
     def clear(self) -> None:
         with self._lock:
+            self.dropped += len(self._spans)
             self._spans.clear()
+
+    def wall_ns(self, span: Dict) -> Tuple[int, int]:
+        """The span's start and end as CLOCK_REALTIME nanoseconds (what
+        ``time.time_ns()`` read then): the clock of a ``jax.profiler``
+        trace's ``profile_start_time``."""
+        wall, mono = self._anchor
+        return (wall + round((span["t0"] - mono) * 1e9),
+                wall + round((span["t1"] - mono) * 1e9))
 
     # --------------------------------------------------------- export --
     def chrome_trace(self, trace_id: Optional[str] = None
                      ) -> Dict[str, Any]:
         """Chrome trace-event JSON (the ``{"traceEvents": [...]}``
         object format): complete events ("ph": "X") with microsecond
-        timestamps, one row per recording thread, trace ids in args.
-        Load in chrome://tracing or https://ui.perfetto.dev."""
+        timestamps on the wall clock (:meth:`wall_ns`), one row per
+        recording thread, trace ids in args, ``cat`` from the span
+        (``"serving"`` where it has none). Load in chrome://tracing or
+        https://ui.perfetto.dev."""
         events: List[Dict[str, Any]] = []
         threads: Dict[str, int] = {}
         for s in self.spans(trace_id):
@@ -141,9 +176,9 @@ class Tracer:
             args["trace_id"] = s["trace_id"]
             events.append({
                 "name": s["name"],
-                "cat": "serving",
+                "cat": s.get("cat", "serving"),
                 "ph": "X",
-                "ts": round((s["t0"] - self._epoch) * 1e6, 3),
+                "ts": self.wall_ns(s)[0] / 1e3,
                 "dur": round((s["t1"] - s["t0"]) * 1e6, 3),
                 "pid": 1,
                 "tid": tid,
