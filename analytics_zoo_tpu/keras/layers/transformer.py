@@ -26,7 +26,7 @@ import numpy as np
 
 from analytics_zoo_tpu.keras.layers.base import KerasLayer
 from analytics_zoo_tpu.ops.activations import gelu_exact
-from analytics_zoo_tpu.ops.attention import dot_product_attention
+from analytics_zoo_tpu.ops.attention import packed_attention
 
 _zigzag_shape_warned = False
 
@@ -125,19 +125,21 @@ class MultiHeadSelfAttention(nn.Module):
                     dropout_rng=ring_rng,
                 ).reshape(b, l, self.hidden_size)
         if out is None:
-            def heads(t):
-                return t.reshape(b, l, self.n_head,
-                                 hd).transpose(0, 2, 1, 3)
-
             rng = (self.make_rng("dropout")
                    if train and self.attn_dropout > 0 else None)
-            out = dot_product_attention(
-                heads(q), heads(k), heads(v), mask=mask,
+            from analytics_zoo_tpu.parallel.mesh import (
+                config_axis, traced_mesh)
+
+            # q, k, v stay as the projection wrote them: the short-row
+            # kernels read that layout (and are told the mesh: GSPMD
+            # cannot partition them), every other path transposes
+            out = packed_attention(
+                q, k, v, self.n_head, mask=mask,
                 key_padding_mask=key_padding_mask, causal=self.causal,
                 dropout_rate=self.attn_dropout if train else 0.0,
-                dropout_rng=rng)
-            out = out.transpose(0, 2, 1, 3).reshape(b, l,
-                                                    self.hidden_size)
+                dropout_rng=rng, mesh=traced_mesh(),
+                batch_axis=config_axis("data"),
+                head_axis=config_axis("model"))
         return nn.Dense(self.hidden_size, dtype=self.dtype,
                         name="proj")(out)
 

@@ -38,7 +38,7 @@ from analytics_zoo_tpu.obs.events import emit, instrument_compiles
 from analytics_zoo_tpu.obs.metrics import get_registry
 from analytics_zoo_tpu.obs.tracing import get_tracer, new_trace_id
 from analytics_zoo_tpu.parallel import sharding
-from analytics_zoo_tpu.parallel.mesh import default_mesh
+from analytics_zoo_tpu.parallel.mesh import default_mesh, traced_under
 from analytics_zoo_tpu.parallel.sharding import replicated
 
 logger = get_logger(__name__)
@@ -377,9 +377,10 @@ class Estimator:
         extra = {k: v for k, v in variables.items() if k != "params"}
 
         def compute_loss(p, xb, yb, step_rng):
-            preds, new_extra = adapter.apply(
-                {"params": p, **extra}, xb, training=True,
-                rng=step_rng)
+            with traced_under(self.mesh):
+                preds, new_extra = adapter.apply(
+                    {"params": p, **extra}, xb, training=True,
+                    rng=step_rng)
             with jax.named_scope("loss"):
                 loss = loss_fn(preds, yb)
                 for coll in aux_colls:
@@ -549,18 +550,17 @@ class Estimator:
         from analytics_zoo_tpu.learn.metrics import Loss
 
         def step(variables, x, y, w, states):
+            with traced_under(self.mesh):
+                preds, extra = adapter.apply(
+                    variables, x, training=False,
+                    **({"want_sown": True} if want_sown else {}))
+            aux = None
             if want_sown:
-                preds, extra = adapter.apply(variables, x,
-                                             training=False,
-                                             want_sown=True)
                 aux = jnp.zeros((), jnp.float32)
                 for coll in aux_colls:
                     for leaf in jax.tree_util.tree_leaves(
                             extra.get(coll, {})):
                         aux = aux + jnp.sum(leaf)
-            else:
-                preds, _ = adapter.apply(variables, x, training=False)
-                aux = None
             out = []
             for m, s in zip(metrics, states):
                 s = m.update(s, preds, y, weights=w)
@@ -969,11 +969,13 @@ class Estimator:
         self._ensure_built(self._probe_example(dataset, batch_size))
         adapter = self.adapter
 
+        def forward(variables, x):
+            with traced_under(self.mesh):
+                return adapter.apply(variables, x, training=False)[0]
+
         if "predict" not in self._predict_fns:
             self._predict_fns["predict"] = instrument_compiles(
-                jax.jit(lambda variables, x: adapter.apply(
-                    variables, x, training=False)[0]),
-                "estimator.predict", subsystem="learn")
+                jax.jit(forward), "estimator.predict", subsystem="learn")
         fn = self._predict_fns["predict"]
 
         # globally-sharded outputs are not fully addressable per host;

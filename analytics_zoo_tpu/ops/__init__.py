@@ -7,6 +7,7 @@ ops here are Pallas TPU kernels with jnp fallbacks for CPU tracing/tests.
 
 from analytics_zoo_tpu.ops.attention import (  # noqa: F401
     dot_product_attention,
+    packed_attention,
     reference_attention,
 )
 from analytics_zoo_tpu.ops.pallas_attention import (  # noqa: F401

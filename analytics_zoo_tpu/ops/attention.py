@@ -1,8 +1,10 @@
-"""Attention dispatch. One entry point, ``dot_product_attention``, and
-four paths, chosen by ``attention_path`` from the platform and the
-call's shapes alone; the one taken names itself in ``op_name``:
+"""Attention dispatch. One entry point, ``dot_product_attention``
+(and ``packed_attention``, the same call on operands laid out
+``[B, L, H x D]``), and five paths, chosen by ``attention_path`` from
+the platform and the call's shapes alone; the one taken names itself
+in ``op_name``:
 
-- ``attention_flash`` -- the framework's own Pallas kernels
+- ``attention_flash`` -- the framework's own blockwise Pallas kernels
   (``pallas_attention.pallas_flash_attention_fwd``, exact custom_vjp):
   off the CPU, no mask or dropout, both lengths multiples of 128,
   head_dim (and the values' width) a multiple of 64, and a sequence
@@ -12,13 +14,20 @@ call's shapes alone; the one taken names itself in ``op_name``:
   without materialising any of them (docs/kernels.md); a window call
   is named ``attention_flash_window``, one whose values' width is not
   the queries' (latent attention) ``attention_flash_latent``;
+- ``attention_flash_short`` -- the framework's short-row kernels
+  (``pallas_short_attention``; the whole row of a head in one tile,
+  several heads a grid step, operands ``[B, L, H x 64]`` where the
+  projection wrote them): off the CPU, plain self-attention (no mask,
+  dropout, ``causal``, window or shared key head; as many KV heads as
+  query heads, an even number of them) with heads of 64 and one length,
+  a multiple of 128 up to ``FLASH_MIN_SEQ`` (BERT at L384);
 - ``attention_stock_pallas`` -- JAX's fused fwd+bwd kernel: the same
   conditions with one head_dim <= 128, for key-padding masks (lowered
   to segment ids) and head sizes the owned kernel refuses;
 - ``attention_einsum`` -- batched matmuls with an f32 softmax and the
-  [L, L] scores in HBM: the CPU's path, short sequences on the chip
-  (BERT at L384), arbitrary 4-D masks. With a window:
-  ``attention_einsum_window``;
+  [L, L] scores in HBM: the CPU's path, arbitrary 4-D masks, and on
+  the chip the short sequences the short-row kernels refuse. With a
+  window: ``attention_einsum_window``;
 - ``attention_reference`` -- the same in plain ``jnp``, for attention
   dropout (flash kernels do not support it).
 
@@ -39,6 +48,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 NEG_INF = -1e30
 
@@ -120,13 +130,18 @@ def _platform(q) -> str:
     return jax.default_backend()
 
 
-# max(Lq, Lk) up to which the [L, L] scores in HBM are cheaper than the
-# blockwise kernels. Measured on the v5e (docs/kernels.md "Measured
-# crossover": h12 d64 bf16, forward + backward): the owned kernel ties
-# einsum at L384 (7.85 against 7.92 ms), the two trade places at L512
-# from one window to the next, and flash wins 1.37x at L1024; so the
-# gate is inclusive. The benchmark has a cell on each side: BERT at
-# L384 (einsum) and Trinity-Mini at L8192 (flash).
+# The border between the two owned kernels: up to it a head's whole
+# [L, L] tile fits VMEM and the short-row kernels serve the shapes they
+# take (``attention_path``), past it the blockwise kernels walk
+# kv-blocks; every other call up to it keeps the [L, L] scores in HBM
+# (einsum). Measured on the v5e (docs/kernels.md "Measured crossover",
+# PR 36: h12 d64 bf16, forward + backward from the fused projection's
+# output, tokens held at 32 x 384): at L384 einsum 2.23 ms, the
+# blockwise kernel 2.09 (a short head is one grid step: flat in L),
+# the short-row kernels 1.04; at 512 3.24 / 2.11 / 1.19; at 128 they
+# tie einsum (0.82 / 0.80); at 1,024 the blockwise kernel wins 2.1x
+# (2.91 against 6.14). The benchmark has a cell on each side: BERT at
+# L384 (short rows) and Trinity-Mini at L8192 (blockwise).
 FLASH_MIN_SEQ = 512
 
 
@@ -134,14 +149,23 @@ def attention_path(platform: str, lq: int, lk: int, head_dim: int,
                    q_heads: int, kv_heads: int, *,
                    value_dim: Optional[int] = None, mask: bool = False,
                    key_padding_mask: bool = False, dropout: bool = False,
-                   causal: bool = False, window: bool = False) -> str:
-    """Which path serves a call: ``flash``, ``stock_pallas``,
-    ``einsum`` or ``reference``. A pure function of what the call site
-    can observe: the platform, the shapes (``value_dim`` is the
-    values' width where it is not ``head_dim``), and which of a 4-D
-    mask, a key-padding mask, dropout, ``causal`` and a window are
-    present."""
+                   causal: bool = False, window: bool = False,
+                   k_shared: bool = False) -> str:
+    """Which path serves a call: ``flash``, ``flash_short``,
+    ``stock_pallas``, ``einsum`` or ``reference``. A pure function of
+    what the call site can observe: the platform, the shapes
+    (``value_dim`` is the values' width where it is not ``head_dim``),
+    and which of a 4-D mask, a key-padding mask, dropout, ``causal``, a
+    window and a shared key head are present."""
     value_dim = head_dim if value_dim is None else value_dim
+    # plain self-attention over short rows: the one shape the short-row
+    # kernels are written for (two heads of 64 share a lane tile)
+    if (platform != "cpu" and lq == lk and lq % 128 == 0
+            and lq <= FLASH_MIN_SEQ and head_dim == value_dim == 64
+            and kv_heads == q_heads and q_heads % 2 == 0
+            and not (mask or key_padding_mask or dropout or causal
+                     or window or k_shared)):
+        return "flash_short"
     # the einsum path is the CPU's; any other platform compiles the
     # kernels (or fails loudly), whatever it calls itself
     kernels = (platform != "cpu" and max(lq, lk) > FLASH_MIN_SEQ
@@ -199,7 +223,7 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
         value_dim=v.shape[-1], mask=mask is not None,
         key_padding_mask=key_padding_mask is not None,
         dropout=dropout_rate != 0.0, causal=causal,
-        window=window is not None)
+        window=window is not None, k_shared=k_shared is not None)
     scope = jax.named_scope(
         f"attention_{path}" + ("" if v.shape[-1] == d else "_latent")
         + ("" if window is None else "_window"))
@@ -210,6 +234,12 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
         with scope:
             return pallas_flash_attention_fwd(q, k, v, causal, scale,
                                               None, None, window, k_shared)
+    if path == "flash_short":
+        # a heads-first caller pays the transposes ``packed_attention``'s
+        # callers do not
+        with scope:
+            return _heads_first(_short_attention(
+                *map(_packed, (q, k, v)), q.shape[1], scale), q.shape[1])
     if k_shared is not None:
         # the paths that hold [L, L] scores hold the joined keys too
         with scope:
@@ -239,6 +269,77 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
                                    scale=scale, window=window,
                                    dropout_rate=dropout_rate,
                                    dropout_rng=dropout_rng)
+
+
+def _heads_first(t, heads: int):
+    """[B, L, heads x D] -> [B, heads, L, D]."""
+    b, l, width = t.shape
+    return t.reshape(b, l, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+def _packed(t):
+    """[B, heads, L, D] -> [B, L, heads x D]."""
+    b, heads, l, d = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(b, l, heads * d)
+
+
+def _short_attention(q, k, v, heads: int, scale: float, mesh=None,
+                     batch_axis: Optional[str] = None,
+                     head_axis: Optional[str] = None):
+    """The short-row kernels on ``[B, L, heads x 64]``. A ``pallas_call``
+    is opaque to the partitioner, which would gather its operands onto
+    every device and run it whole on each: under a ``mesh`` of several
+    devices the call is a ``shard_map``, the batch over ``batch_axis``
+    and the head pairs over ``head_axis`` where the mesh has the axis
+    and it divides them, ``L`` and a pair's 128 lanes whole. Each device
+    runs the kernels on its own rows and heads; no collective."""
+    from analytics_zoo_tpu.ops.pallas_short_attention import (
+        pallas_short_attention)
+    from analytics_zoo_tpu.parallel.mesh import shard_map
+
+    if mesh is None or mesh.size == 1:
+        return pallas_short_attention(q, k, v, heads, scale)
+
+    def over(axis: Optional[str], n: int):
+        fits = axis in mesh.axis_names and n % mesh.shape[axis] == 0
+        return axis if fits else None
+
+    rows, pairs = over(batch_axis, q.shape[0]), over(head_axis, heads // 2)
+    spec = PartitionSpec(rows, None, pairs)
+    local = heads // (mesh.shape[pairs] if pairs else 1)
+    return shard_map(
+        lambda q, k, v: pallas_short_attention(q, k, v, local, scale),
+        mesh, in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
+
+
+def packed_attention(q, k, v, heads: int, mask=None, key_padding_mask=None,
+                     causal: bool = False, scale: Optional[float] = None,
+                     dropout_rate: float = 0.0, dropout_rng=None, mesh=None,
+                     batch_axis: str = "data", head_axis: str = "model"):
+    """``dot_product_attention`` on q, k, v ``[B, L, heads x D]``, each
+    head's columns side by side as a fused projection writes them;
+    returns ``[B, L, heads x D]``, where the output projection reads
+    it. Where ``attention_path`` answers ``flash_short`` the kernels
+    read and write this layout and nothing is transposed (``mesh``: the
+    mesh the traced program is partitioned over and its ``batch_axis``
+    / ``head_axis``, see ``_short_attention``); every other path is
+    ``dot_product_attention`` between the heads-first transposes."""
+    d = q.shape[-1] // heads
+    path = attention_path(
+        _platform(q), q.shape[1], k.shape[1], d, heads, heads,
+        value_dim=v.shape[-1] // heads, mask=mask is not None,
+        key_padding_mask=key_padding_mask is not None,
+        dropout=dropout_rate != 0.0, causal=causal)
+    if path == "flash_short":
+        with jax.named_scope("attention_flash_short"):
+            return _short_attention(
+                q, k, v, heads,
+                scale if scale is not None else 1.0 / np.sqrt(d), mesh,
+                batch_axis, head_axis)
+    return _packed(dot_product_attention(
+        *(_heads_first(t, heads) for t in (q, k, v)), mask=mask,
+        key_padding_mask=key_padding_mask, causal=causal, scale=scale,
+        dropout_rate=dropout_rate, dropout_rng=dropout_rng))
 
 
 def _eva_keep(l: int, window: int, per_window: int):

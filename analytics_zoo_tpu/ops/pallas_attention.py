@@ -25,10 +25,17 @@ the flash backward recurrence of Dao et al., re-derived for the TPU
 memory hierarchy. Replaces the reference's O(L^2)-materialized attention
 (ref: zoo/.../keras/layers/TransformerLayer.scala attn).
 
-Constraints: seq % block == 0, head_dim % 64 == 0 (64 keeps the MXU at
-half lane-width on the QK/PV contractions -- the same geometry every
-d=64 attention pays, incl. XLA's einsum -- while 128-multiples ride it
-full); callers fall back to the jnp path otherwise. Causal masking
+Constraints: seq % block == 0, head_dim % 64 == 0 (a 64-deep
+contraction and a 64-wide result each fill half of a 128 x 128 MXU
+pass, here with tiles that fill 64 of 128 lanes; 128-multiples ride it
+full); callers fall back to the jnp path otherwise. These kernels are
+the dispatcher's for sequences longer than ``attention.FLASH_MIN_SEQ``;
+up to it plain self-attention with heads of 64 goes to
+``pallas_short_attention``, which pays the same half-filled passes but
+reads two heads a 128-lane tile from the projection's own layout and
+holds a head's whole row in one tile (1.04 ms against this kernel's
+2.09 a BERT-base layer at L384, forward + backward: docs/kernels.md
+"Measured crossover"). Causal masking
 aligns the diagonal bottom-right (tril k=lk-lq) to match
 ``reference_attention``; causal with len(q) > len(kv) is rejected.
 

@@ -13,6 +13,8 @@ crosses DCN -- the layout recommended by the scaling playbook.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -112,6 +114,30 @@ def default_mesh() -> Mesh:
     if ctx is not None:
         return ctx.mesh
     return create_mesh()
+
+
+_TRACED = threading.local()
+
+
+@contextlib.contextmanager
+def traced_under(mesh: Mesh):
+    """Declares, for the ``with`` block, the mesh the program being
+    traced will be partitioned over: the ``Estimator`` wraps its model
+    calls in it, so a layer that must say how an opaque kernel call is
+    sharded (``traced_mesh``) learns the mesh of *this* program, not
+    the context's."""
+    before = traced_mesh()
+    _TRACED.mesh = mesh
+    try:
+        yield
+    finally:
+        _TRACED.mesh = before
+
+
+def traced_mesh() -> Optional[Mesh]:
+    """The mesh declared by the innermost ``traced_under``, else
+    ``None``: nobody has said what the program is partitioned over."""
+    return getattr(_TRACED, "mesh", None)
 
 
 def mesh_axis_size(mesh: Mesh, name: str) -> int:

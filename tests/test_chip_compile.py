@@ -19,18 +19,23 @@ import jax.numpy as jnp
 import pytest
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from analytics_zoo_tpu.ops import attention, pallas_attention
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -82,6 +87,60 @@ def test_owned_flash_compiles_forward_and_backward(one_chip, shape,
                            argnums=(0, 1, 2))).lower(
         *args).compile().as_text()
     assert bwd.count("tpu_custom_call") == 2  # fwd+lse, one backward
+
+
+@pytest.mark.parametrize("shape,heads", [
+    ((32, 384, 768), 12),       # BERT-base b32 / L384: one grid step a row
+    ((8, 512, 1024), 16),       # BERT-large at 512: two groups of 8 heads
+])
+def test_short_row_kernels_compile_forward_and_backward(one_chip, shape,
+                                                        heads):
+    """The short-row kernels through the dispatcher at the projection's
+    own layout: one kernel forward, two with the backward, the VMEM they
+    ask for granted, and no [L, L] tensor outside them."""
+    args = _qkv(shape, one_chip)
+    length = shape[1]
+
+    def attn(q, k, v):
+        return attention.packed_attention(q, k, v, heads)
+
+    fwd = jax.jit(attn).lower(*args).compile().as_text()
+    assert fwd.count("tpu_custom_call") == 1
+    assert "attention_flash_short" in fwd
+    bwd = jax.jit(jax.grad(lambda q, k, v: _scalar(attn(q, k, v)),
+                           argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert bwd.count("tpu_custom_call") == 2
+    assert f"{length},{length}" not in fwd + bwd
+
+
+def test_short_row_kernels_partition_over_four_chips(topo):
+    """One GSPMD program over a described 2x2 host, the batch over
+    ``data`` and the weights replicated (the dp4 cell's layout): told
+    the mesh, every chip runs the kernels on its own 32 rows and the
+    program gathers nothing; the kernels keep the caller's scopes."""
+    mesh = Mesh(topo.devices, ("data",))
+    rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((128, 384, 768), jnp.bfloat16, sharding=rows)
+    w = jax.ShapeDtypeStruct((768, 3, 768), jnp.bfloat16, sharding=whole)
+
+    def loss(w, x):
+        with jax.named_scope("layer"):
+            qkv = jnp.einsum("blh,hpw->blpw", x, w)
+            out = attention.packed_attention(
+                qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], 12, mesh=mesh)
+        return _scalar(out)
+
+    text = jax.jit(jax.grad(loss)).lower(w, x).compile().as_text()
+    assert "all-gather" not in text
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    assert all("bf16[32,384," in line and "bf16[128," not in line
+               for line in kernels)
+    assert all("(layer))/attention_flash_short/" in line
+               or "(layer)/attention_flash_short/" in line
+               for line in kernels)
 
 
 @pytest.mark.parametrize("length,path,kernels", [

@@ -18,7 +18,8 @@ from analytics_zoo_tpu.learn.estimator import Estimator
 from analytics_zoo_tpu.ops import attention
 
 PROGRAM_SCOPES = ("optimizer", "loss", "grad_accum")
-ATTENTION_SCOPES = ("attention_flash", "attention_stock_pallas",
+ATTENTION_SCOPES = ("attention_flash", "attention_flash_short",
+                    "attention_stock_pallas",
                     "attention_einsum", "attention_reference",
                     "attention_flash_window", "attention_einsum_window",
                     "attention_reference_window")
@@ -144,8 +145,11 @@ def _lowered_for_tpu(monkeypatch, on_tpu: bool, length: int,
     ("attention_flash", True, 1024, {}),
     ("attention_stock_pallas", True, 1024,
      {"key_padding_mask": jax.ShapeDtypeStruct((1, 1024), jnp.int32)}),
-    # BERT at L384: short sequences take the einsum path on the chip too
-    ("attention_einsum", True, 384, {}),
+    # BERT at L384: short rows of heads of 64 have a kernel of their own
+    # on the chip (PR 36); with a mask they keep the einsum path
+    ("attention_flash_short", True, 384, {}),
+    ("attention_einsum", True, 384,
+     {"key_padding_mask": jax.ShapeDtypeStruct((1, 384), jnp.int32)}),
     ("attention_einsum", False, 1024, {}),
     ("attention_reference", True, 1024,
      {"dropout_rate": 0.1,
@@ -166,8 +170,25 @@ def test_attention_path_names_itself(monkeypatch, scope, on_tpu, length,
 # ``ops/attention.py:139-164``), not off ``attention_path``. Columns:
 # platform, Lq, Lk, head_dim, query heads, KV heads, what is present.
 @pytest.mark.parametrize("path,platform,lq,lk,d,hq,hkv,present", [
-    # the gate is inclusive: 512 is still the einsum's
-    ("einsum", "tpu", 512, 512, 64, 12, 12, ()),
+    # the gate is inclusive: 512 is still short. Since PR 36 plain
+    # self-attention with heads of 64 has the short-row kernels there
+    ("flash_short", "tpu", 512, 512, 64, 12, 12, ()),
+    ("flash_short", "tpu", 384, 384, 64, 12, 12, ()),
+    ("flash_short", "tpu", 128, 128, 64, 2, 2, ()),
+    # ... and every neighbouring case keeps the answer it had
+    ("einsum", "tpu", 384, 384, 64, 12, 12, ("causal",)),
+    ("einsum", "tpu", 384, 384, 64, 12, 12, ("causal", "window")),
+    ("einsum", "tpu", 384, 384, 64, 12, 12, ("key_padding_mask",)),
+    ("einsum", "tpu", 384, 384, 64, 12, 12, ("mask",)),
+    ("reference", "tpu", 384, 384, 64, 12, 12, ("dropout",)),
+    ("einsum", "tpu", 384, 384, 64, 12, 12, ("k_shared",)),
+    ("einsum", "tpu", 384, 384, 128, 12, 12, ()),
+    ("einsum", "tpu", 384, 384, 32, 12, 12, ()),
+    ("einsum", "tpu", 128, 384, 64, 12, 12, ()),
+    ("einsum", "tpu", 320, 320, 64, 12, 12, ()),
+    ("einsum", "tpu", 384, 384, 64, 12, 4, ()),
+    ("einsum", "tpu", 384, 384, 64, 3, 3, ()),      # two heads share a tile
+    ("einsum", "cpu", 384, 384, 64, 12, 12, ()),
     ("flash", "tpu", 640, 640, 64, 12, 12, ()),
     # the longer side decides
     ("flash", "tpu", 128, 1024, 64, 12, 12, ()),
