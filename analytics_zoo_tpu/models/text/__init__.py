@@ -16,3 +16,6 @@ from analytics_zoo_tpu.models.text.sparse_decoder_lm import (  # noqa: F401
     LatentDecoderLM,
     SparseDecoderLM,
 )
+from analytics_zoo_tpu.models.text.looped_decoder_lm import (  # noqa: F401
+    LoopedDecoderLM,
+)

@@ -122,13 +122,13 @@ KEPT_NAMES = (
     MLP_IN_NAME, MLP_OUT_NAME)
 
 
-def _rematerialised(layer_cls):
+def _rematerialised(layer_cls, names=KEPT_NAMES):
     """The backward pass keeps the layer's input and the values named
-    in ``KEPT_NAMES`` (the flash kernel's two exist only where it ran);
+    in ``names`` (the flash kernel's two exist only where it ran);
     all else is computed again."""
     return nn.remat(
         layer_cls, static_argnums=(2,),
-        policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def _logits(module, h, heads: int = 1, init_std: float = 0.02,

@@ -427,3 +427,39 @@ def _gradient_kernels(module, loss_of, sharding):
     text = _gradient(module, loss_of, sharding).as_text()
     return [line for line in text.splitlines()
             if "tpu_custom_call" in line], text
+
+
+def test_looped_decoder_step_holds_one_body_and_no_whole_logits(one_chip):
+    """One layer of the looped decoder applied four times at L8192 and
+    the published widths, compiled with the exit-weighted loss's
+    gradient: the passes are one loop (3 kernels for the layer --
+    forward, the forward again, one backward -- not 4 x 3), the heads
+    run in row blocks (no [8192, 49152] float32 array of any pass in
+    the program), and the temporaries fit beside the state."""
+    from analytics_zoo_tpu.models.text import looped_decoder_lm as looped
+
+    module = looped.LoopedDecoderModule(
+        vocab=49152, hidden_size=2048, n_layers=1, n_passes=4, n_head=16,
+        head_dim=128, dense_width=5632, rope_theta=1e6, eps=1e-6,
+        dtype=jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    params = _shapes_on(jax.eval_shape(
+        module.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 128), jnp.int32))["params"], one_chip)
+
+    def loss(params, ids):
+        return looped.exit_weighted_loss(
+            module.apply({"params": params}, ids, train=True), ids)
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 3
+    assert sum("rematted_computation" in line for line in kernels) == 1
+    assert not re.search(r"f32\[(\d+,)*8192,49152\]", text)
+    assert re.search(r"f32\[1024,49152\]", text)       # a block of the heads
+    memory = compiled.memory_analysis()
+    print("looped: one layer x four passes at L8192 holds "
+          f"{memory.temp_size_in_bytes / 1e6:.0f} MB of temporaries")
+    assert memory.temp_size_in_bytes < 4e9
