@@ -29,6 +29,7 @@ import numpy as np
 
 from analytics_zoo_tpu.automl import metrics as automl_metrics
 from analytics_zoo_tpu.common.log import get_logger
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 logger = get_logger(__name__)
 
@@ -46,10 +47,10 @@ class VanillaLSTM(nn.Module):
     def __call__(self, x, train: bool = False):
         h = nn.RNN(nn.OptimizedLSTMCell(self.lstm_1_units),
                    name="lstm_1")(x)
-        h = nn.Dropout(self.dropout_1, deterministic=not train)(h)
+        h = Dropout(self.dropout_1, deterministic=not train)(h)
         h = nn.RNN(nn.OptimizedLSTMCell(self.lstm_2_units),
                    name="lstm_2")(h)[:, -1]
-        h = nn.Dropout(self.dropout_2, deterministic=not train)(h)
+        h = Dropout(self.dropout_2, deterministic=not train)(h)
         return nn.Dense(self.output_dim, name="head")(h)
 
 
@@ -70,7 +71,7 @@ class Seq2SeqForecaster(nn.Module):
                           return_carry=True, name="encoder")(x)
         cell = nn.OptimizedLSTMCell(self.latent_dim, name="decoder_cell")
         head = nn.Dense(self.target_dim, name="decoder_head")
-        drop = nn.Dropout(self.dropout, deterministic=not train)
+        drop = Dropout(self.dropout, deterministic=not train)
         # first decoder input: the last observed target values
         step_in = x[:, -1, :self.target_dim]
         outs = []
@@ -98,9 +99,9 @@ class _MTNetEncoder(nn.Module):
         h = nn.Conv(self.cnn_hidden, kernel_size=(self.cnn_height,),
                     padding="VALID", name="conv")(w)
         h = nn.relu(h)
-        h = nn.Dropout(self.cnn_dropout, deterministic=not train)(h)
+        h = Dropout(self.cnn_dropout, deterministic=not train)(h)
         seq = nn.RNN(nn.GRUCell(self.rnn_hidden), name="gru")(h)
-        seq = nn.Dropout(self.rnn_dropout, deterministic=not train)(seq)
+        seq = Dropout(self.rnn_dropout, deterministic=not train)(seq)
         # attention pooling over the conv-time axis
         score = nn.Dense(1, name="attn")(nn.tanh(seq))
         alpha = jax.nn.softmax(score, axis=1)
@@ -185,8 +186,7 @@ class TCN(nn.Module):
                             kernel_dilation=dilation, padding="VALID",
                             name=f"conv_{i}_{j}")(hp)
                 h = nn.relu(h)
-                h = nn.Dropout(self.dropout,
-                               deterministic=not train)(h)
+                h = Dropout(self.dropout, deterministic=not train)(h)
             if res.shape[-1] != self.hidden:
                 res = nn.Dense(self.hidden, name=f"res_{i}")(res)
             h = nn.relu(h + res)

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from analytics_zoo_tpu.keras import activations
 from analytics_zoo_tpu.keras.layers.base import FnModule, KerasLayer
+from analytics_zoo_tpu.ops import dropout as ops_dropout
 
 
 class _DenseModule(nn.Module):
@@ -53,7 +54,7 @@ class _DropoutModule(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        return nn.Dropout(self.rate, deterministic=not train)(x)
+        return ops_dropout.Dropout(self.rate, deterministic=not train)(x)
 
 
 class Dropout(KerasLayer):

@@ -68,6 +68,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.keras.activations import get as get_activation
 from analytics_zoo_tpu.keras.layers.base import KerasLayer
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 
 def resolve_expert_axis(value: Optional[str]) -> Optional[str]:
@@ -893,8 +894,7 @@ class MoETransformerBlock(nn.Module):
             name="attention")(x, mask=mask,
                               key_padding_mask=key_padding_mask,
                               train=train)
-        attn = nn.Dropout(self.hidden_dropout,
-                          deterministic=not train)(attn)
+        attn = Dropout(self.hidden_dropout, deterministic=not train)(attn)
         x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
                          name="ln_attn")(x + attn)
         h = MoEFFN(hidden_size=self.hidden_size,
@@ -905,6 +905,6 @@ class MoETransformerBlock(nn.Module):
                    activation=self.activation,
                    aux_weight=self.aux_weight, dtype=self.dtype,
                    name="moe_ffn")(x, train=train)
-        h = nn.Dropout(self.hidden_dropout, deterministic=not train)(h)
+        h = Dropout(self.hidden_dropout, deterministic=not train)(h)
         return nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
                             name="ln_ffn")(x + h)

@@ -27,6 +27,7 @@ import numpy as np
 from analytics_zoo_tpu.keras.layers.base import KerasLayer
 from analytics_zoo_tpu.ops.activations import gelu_exact
 from analytics_zoo_tpu.ops.attention import packed_attention
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 _zigzag_shape_warned = False
 
@@ -177,8 +178,7 @@ class TransformerBlock(nn.Module):
             seq_axis=self.seq_axis, name="attention")(
                 x, mask=mask, key_padding_mask=key_padding_mask,
                 train=train)
-        attn = nn.Dropout(self.hidden_dropout,
-                          deterministic=not train)(attn)
+        attn = Dropout(self.hidden_dropout, deterministic=not train)(attn)
         x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
                          name="ln_attn")(x + attn)
         h = nn.Dense(self.intermediate_size, dtype=self.dtype,
@@ -186,7 +186,7 @@ class TransformerBlock(nn.Module):
         h = act(h)
         h = nn.Dense(self.hidden_size, dtype=self.dtype,
                      name="ffn_out")(h)
-        h = nn.Dropout(self.hidden_dropout, deterministic=not train)(h)
+        h = Dropout(self.hidden_dropout, deterministic=not train)(h)
         return nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
                             name="ln_ffn")(x + h)
 
@@ -216,7 +216,7 @@ class TransformerModule(nn.Module):
                          nn.initializers.normal(0.01),
                          (self.seq_len, self.hidden_size))
         h = tok + pos[None, :l]
-        h = nn.Dropout(self.hidden_dropout, deterministic=not train)(h)
+        h = Dropout(self.hidden_dropout, deterministic=not train)(h)
         outs = []
         inter = self.intermediate_size or 4 * self.hidden_size
         for i in range(self.n_block):
@@ -268,7 +268,7 @@ class BERTModule(nn.Module):
             h = h + nn.Embed(self.type_vocab, self.hidden_size,
                              name="segment_embed")(segs.astype(jnp.int32))
         h = nn.LayerNorm(epsilon=1e-12, name="embed_ln")(h)
-        h = nn.Dropout(self.hidden_dropout, deterministic=not train)(h)
+        h = Dropout(self.hidden_dropout, deterministic=not train)(h)
 
         # padding mask stays [B, L]: flash-kernel-compatible (lowered to
         # segment ids) instead of a materialized 4-D mask

@@ -58,7 +58,9 @@ def training_prng_key(seed: int):
     shuffles): the hardware RBG generator on the TPU, where threefry2x32
     dropout mask generation costs ~23 ms/step on BERT-base (b32, L384,
     v5e) and RBG is near-free; elsewhere the default threefry stream,
-    so CPU runs stay bit-reproducible across jax versions."""
+    so CPU runs stay bit-reproducible across jax versions. XLA does not
+    partition RBG's generator: under a data mesh each device draws its
+    own rows' bits (``ops.dropout``)."""
     if jax.devices()[0].platform == "tpu":
         return jax.random.key(seed, impl="rbg")
     return jax.random.PRNGKey(seed)

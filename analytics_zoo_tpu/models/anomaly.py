@@ -14,6 +14,7 @@ import flax.linen as nn
 import numpy as np
 
 from analytics_zoo_tpu.models.common import ZooModel, register_model
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 
 class AnomalyDetectorNet(nn.Module):
@@ -26,7 +27,7 @@ class AnomalyDetectorNet(nn.Module):
         for i, (units, rate) in enumerate(
                 zip(self.hidden_layers, self.dropouts)):
             h = nn.RNN(nn.OptimizedLSTMCell(units), name=f"lstm_{i}")(h)
-            h = nn.Dropout(rate, deterministic=not train)(h)
+            h = Dropout(rate, deterministic=not train)(h)
         return nn.Dense(1, name="head")(h[:, -1])
 
 

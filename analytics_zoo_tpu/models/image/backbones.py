@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from analytics_zoo_tpu.keras.layers.normalization import (
     batch_norm as _norm)
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 
 class InceptionBlock(nn.Module):
@@ -108,7 +109,7 @@ class InceptionV1(nn.Module):
             x = InceptionBlock(*_INCEPTION_CFG[key], dtype=self.dtype,
                                name=f"mixed{key}")(x, train=train)
         x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
+        x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = x.astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32,
                         name="head")(x)
@@ -159,7 +160,7 @@ class MobileNetV1(nn.Module):
                                 dtype=self.dtype,
                                 name=f"block{i + 1}")(x, train=train)
         x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
+        x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = x.astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32,
                         name="head")(x)
@@ -189,8 +190,7 @@ class VGG16(nn.Module):
         for i in (6, 7):
             x = nn.Dense(4096, dtype=self.dtype, name=f"fc{i}")(x)
             x = nn.relu(x)
-            x = nn.Dropout(self.dropout_rate,
-                           deterministic=not train)(x)
+            x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = x.astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32,
                         name="head")(x)
@@ -216,8 +216,7 @@ class VGG19(VGG16):
         for i in (6, 7):
             x = nn.Dense(4096, dtype=self.dtype, name=f"fc{i}")(x)
             x = nn.relu(x)
-            x = nn.Dropout(self.dropout_rate,
-                           deterministic=not train)(x)
+            x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = x.astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32,
                         name="head")(x)
@@ -253,8 +252,7 @@ class AlexNet(nn.Module):
         for i in (6, 7):
             x = nn.Dense(4096, dtype=self.dtype, name=f"fc{i}")(x)
             x = nn.relu(x)
-            x = nn.Dropout(self.dropout_rate,
-                           deterministic=not train)(x)
+            x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = x.astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32,
                         name="head")(x)
@@ -311,7 +309,7 @@ class SqueezeNet(nn.Module):
                                       (64, 256)]):
             x = _FireModule(sq, ex, dtype=self.dtype,
                             name=f"fire{i + 6}")(x, train=train)
-        x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
+        x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = nn.Conv(self.num_classes, (1, 1), dtype=jnp.float32,
                     name="head_conv")(x.astype(jnp.float32))
         return jnp.mean(nn.relu(x), axis=(1, 2))
@@ -598,7 +596,7 @@ class InceptionV3(nn.Module):
             x = _MixedE(dtype=self.dtype,
                         name=f"mixedE{i}")(x, train=train)
         x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
+        x = Dropout(self.dropout_rate, deterministic=not train)(x)
         x = x.astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32,
                         name="head")(x)

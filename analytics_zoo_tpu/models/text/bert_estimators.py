@@ -18,6 +18,7 @@ import numpy as np
 
 from analytics_zoo_tpu.keras.layers.transformer import BERTModule
 from analytics_zoo_tpu.models.common import ZooModel, register_model
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 
 class _BERTHeadModule(nn.Module):
@@ -45,8 +46,7 @@ class _BERTHeadModule(nn.Module):
             hidden_dropout=self.hidden_dropout, attn_dropout=0.0,
             dtype=self.dtype, name="bert")(x, train=train)
         h = seq if self.per_token else pooled
-        h = nn.Dropout(self.hidden_dropout,
-                       deterministic=not train)(h)
+        h = Dropout(self.hidden_dropout, deterministic=not train)(h)
         return nn.Dense(self.num_classes, name="head")(
             h.astype(jnp.float32))
 

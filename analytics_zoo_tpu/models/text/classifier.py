@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from analytics_zoo_tpu.models.common import ZooModel, register_model
+from analytics_zoo_tpu.ops.dropout import Dropout
 
 
 class TextClassifierNet(nn.Module):
@@ -41,7 +42,7 @@ class TextClassifierNet(nn.Module):
                        name="gru")(h)[:, -1]
         else:
             raise ValueError(f"unknown encoder {self.encoder!r}")
-        h = nn.Dropout(0.2, deterministic=not train)(h)
+        h = Dropout(0.2, deterministic=not train)(h)
         h = nn.relu(nn.Dense(128, name="fc")(h))
         return nn.Dense(self.class_num, name="head")(h)
 

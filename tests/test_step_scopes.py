@@ -325,6 +325,40 @@ def test_bert_step_program_is_what_it_was(fresh_compiles):
         == BERT_STEP[1]
 
 
+def _bert_one_chip_step_text() -> str:
+    from analytics_zoo_tpu.learn.optim import AdamWeightDecay
+    from analytics_zoo_tpu.models.text.bert_squad import BERTSQuAD
+    from analytics_zoo_tpu.parallel.mesh import create_mesh
+
+    model = BERTSQuAD(vocab=128, hidden_size=32, n_block=2, n_head=2,
+                      intermediate_size=64, max_position_len=64,
+                      dtype="bfloat16")
+    model.compile(optimizer=AdamWeightDecay(lr=1e-4),
+                  mesh=create_mesh(devices=jax.devices()[:1]))
+    est = model.estimator
+    x = {"input_ids": np.zeros((8, 16), np.int32)}
+    est._ensure_built(x)
+    return jax.jit(lambda *args: est._step_math(*args)).lower(
+        est.variables, est.opt_state, x, np.zeros((8, 2), np.int32),
+        jax.random.key(0, impl="rbg")).compile().as_text()
+
+
+def test_one_chip_dropout_is_flax_dropout(fresh_compiles, monkeypatch):
+    """On one chip the package's dropout (ISSUE 39) compiles to the
+    program ``flax.linen.Dropout`` gave: its ``dropout_bits`` scope is
+    metadata, and the rows' bits are drawn as flax draws them."""
+    from analytics_zoo_tpu.keras.layers import transformer
+    from analytics_zoo_tpu.models.text import bert_estimators
+
+    ours = _bert_one_chip_step_text()
+    assert "/dropout_bits/" in ours
+    for site in (transformer, bert_estimators):
+        monkeypatch.setattr(site, "Dropout", nn.Dropout)
+    flax_s = _bert_one_chip_step_text()
+    assert "/dropout_bits/" not in flax_s
+    assert _strip_metadata(ours) == _strip_metadata(flax_s)
+
+
 # The same pin for the two other programs the benchmark's cells run,
 # both recorded on the parent of PR 29 (156435e), before the attention
 # dispatcher lost its config keys and ``batch_norm()`` its sampled
