@@ -461,8 +461,8 @@ _gated.defvjp(
 
 
 class DroplessExperts(nn.Module):
-    """Sigmoid-routed SwiGLU experts, the share held here: x [B, L, d]
-    -> [B, L, d] (module docstring).
+    """Routed SwiGLU experts (sigmoid scores by default), the share held
+    here: x [B, L, d] -> [B, L, d] (module docstring).
 
     Args:
       width: each routed expert's SwiGLU width.
@@ -484,6 +484,19 @@ class DroplessExperts(nn.Module):
         (0.001: scores within ~0.01 of each other), ``bias_step``
         balances a fresh router's load within a few steps instead of
         hundreds.
+      score: ``"sigmoid"`` (above) or ``"softmax"``: the scores are a
+        float32 softmax over the ``n_routed`` experts and the top-k are
+        chosen by them alone -- no bias steps, and ``bias`` stays at 0
+        (it is declared all the same, for readers that rank experts by
+        score + bias: the order is the logits' either way).
+      shared_gate: the shared expert's output is scaled by
+        ``sigmoid(x w)`` a token (``w`` [d, 1], param ``shared_gate``).
+      aux_loss_coef: with the softmax score, sow ``coef * E * sum_e f_e
+        P_e`` into ``losses`` (``moe_aux_loss``, which the Estimator adds
+        to the loss): ``f_e`` the share of this apply's tokens that chose
+        expert ``e`` (summing to ``top_k``), ``P_e`` its mean probability,
+        over all ``E = n_routed`` experts (Switch Transformer's balance
+        loss; only ``P`` carries a gradient). 0: nothing is sown.
 
     Collection ``counters`` holds cumulative int32 counts, updated on
     training applies and published by the Estimator at each epoch's
@@ -503,6 +516,9 @@ class DroplessExperts(nn.Module):
     shared_width: int = 0
     bias_step: float = 0.001
     router_init_std: Optional[float] = None
+    score: str = "sigmoid"
+    shared_gate: bool = False
+    aux_loss_coef: float = 0.0
     dtype: Any = jnp.float32
 
     def _route(self, m, train: bool):
@@ -510,13 +526,20 @@ class DroplessExperts(nn.Module):
         e = self.n_routed
         init = (nn.linear.default_kernel_init if self.router_init_std is None
                 else nn.initializers.normal(self.router_init_std))
-        scores = jax.nn.sigmoid(nn.Dense(
-            e, use_bias=False, dtype=jnp.float32, kernel_init=init,
-            name="router")(m.astype(jnp.float32)))           # [n, E]
+        logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                          kernel_init=init, name="router")(
+            m.astype(jnp.float32))                           # [n, E]
         bias = self.variable("router_state", "bias",
                              lambda: jnp.zeros((e,), jnp.float32))
-        _, idx = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias.value), self.top_k)
+        if self.score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, idx = jax.lax.top_k(scores, self.top_k)
+        elif self.score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias.value), self.top_k)
+        else:
+            raise ValueError(f"unknown router score {self.score!r}")
         # the chosen scores and the counts by comparison with every
         # expert's index: on the TPU a gather and a scatter-add of
         # n * k scalars cost three times this select and sum
@@ -525,7 +548,14 @@ class DroplessExperts(nn.Module):
         counts = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
         weights = chosen / (jnp.sum(chosen, -1, keepdims=True)
                             + 1e-20) * self.route_scale
-        if train and self.is_mutable_collection("router_state"):
+        if self.aux_loss_coef:
+            if self.score != "softmax":
+                raise ValueError("the balance loss needs score='softmax'")
+            share = counts.astype(jnp.float32) / m.shape[0]
+            self.sow("losses", "moe_aux_loss", self.aux_loss_coef * e
+                     * jnp.sum(share * jnp.mean(scores, axis=0)))
+        if (self.score == "sigmoid" and train
+                and self.is_mutable_collection("router_state")):
             load = counts.astype(jnp.float32)
             bias.value = bias.value + self.bias_step * jnp.sign(
                 jnp.mean(load) - load)
@@ -590,8 +620,13 @@ class DroplessExperts(nn.Module):
             out = _rows_back(ys, weights, plan)
         if self.shared_width:
             with jax.named_scope("moe_shared"):
-                out = out + SwiGLU(self.shared_width, dtype=self.dtype,
-                                   name="shared")(m)
+                shared = SwiGLU(self.shared_width, dtype=self.dtype,
+                                name="shared")(m)
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype,
+                        name="shared_gate")(m))
+                out = out + shared
         return out.reshape(x.shape).astype(x.dtype)
 
 

@@ -108,7 +108,10 @@ def kernel_ready(q, k, v):
 class GatedGroupedAttention(nn.Module):
     """Causal self-attention, ``n_head`` query heads over ``n_kv_head``
     KV heads, QK-norm, RoPE when ``window`` is set (the sliding kind),
-    and ``sigmoid(x Wg)`` on the heads' output before ``Wo``."""
+    and ``sigmoid(x Wg)`` on the heads' output before ``Wo``. With
+    ``rope_dims`` RoPE turns the leading ``rope_dims`` of each head on
+    every layer and leaves the rest alone (partial rotary); with
+    ``unit_offset`` the QK-norms are zero-centred (``RMSNorm``)."""
 
     n_head: int
     n_kv_head: int
@@ -117,6 +120,8 @@ class GatedGroupedAttention(nn.Module):
     rope_theta: float = 10000.0
     eps: float = 1e-5
     dtype: Any = jnp.float32
+    rope_dims: Optional[int] = None
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -132,9 +137,15 @@ class GatedGroupedAttention(nn.Module):
         k = proj(h_kv * hd, "k").reshape(b, l, h_kv, hd)
         v = proj(h_kv * hd, "v").reshape(b, l, h_kv, hd)
         gate = checkpoint_name(proj(h * hd, "gate"), ATTENTION_GATE_NAME)
-        q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
-        k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
-        if self.window is not None:
+        q = RMSNorm(self.eps, self.dtype, unit_offset=self.unit_offset,
+                    name="q_norm")(q)
+        k = RMSNorm(self.eps, self.dtype, unit_offset=self.unit_offset,
+                    name="k_norm")(k)
+        if self.rope_dims is not None:
+            q, k = (jnp.concatenate(
+                [rope(x[..., :self.rope_dims], self.rope_theta),
+                 x[..., self.rope_dims:]], axis=-1) for x in (q, k))
+        elif self.window is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         q, k, v = kernel_ready(q.transpose(0, 2, 1, 3),
                                k.transpose(0, 2, 1, 3),

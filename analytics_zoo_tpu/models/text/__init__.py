@@ -19,3 +19,6 @@ from analytics_zoo_tpu.models.text.sparse_decoder_lm import (  # noqa: F401
 from analytics_zoo_tpu.models.text.looped_decoder_lm import (  # noqa: F401
     LoopedDecoderLM,
 )
+from analytics_zoo_tpu.models.text.hybrid_decoder_lm import (  # noqa: F401
+    HybridDecoderLM,
+)

@@ -463,3 +463,56 @@ def test_looped_decoder_step_holds_one_body_and_no_whole_logits(one_chip):
     print("looped: one layer x four passes at L8192 holds "
           f"{memory.temp_size_in_bytes / 1e6:.0f} MB of temporaries")
     assert memory.temp_size_in_bytes < 4e9
+
+
+def test_hybrid_decoder_step_fits_with_its_optimizer_state(one_chip):
+    """One period of the hybrid decoder (three Gated DeltaNet layers, one
+    gated attention layer) at the published widths and the cell's cut
+    (32 of 512 experts, 18,992 vocabulary rows), L8192, compiled with
+    the next-token loss's gradient: the flash kernels run at heads of
+    256 with 8 query heads a KV head in one forward and one backward
+    kernel, the delta rule is a loop under its scope with no [L, L]
+    tensor, and the gradient's temporaries fit beside the parameters and
+    Adam's two moments under the compiler's 15.75 GB."""
+    from analytics_zoo_tpu.models.text import hybrid_decoder_lm as hybrid
+    from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
+        next_token_loss)
+
+    module = hybrid.HybridDecoderModule(
+        vocab=18992, hidden_size=2048, n_layers=4,
+        full_attention_interval=4, n_head=16, n_kv_head=2, head_dim=256,
+        partial_rotary_factor=0.25, n_k_head=16, n_v_head=32,
+        head_k_dim=128, head_v_dim=128, expert_width=512, n_routed=512,
+        n_held=32, top_k=10, shared_width=512, aux_loss_coef=0.001,
+        rope_theta=1e7, eps=1e-6, dtype=jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    variables = _shapes_on(jax.eval_shape(
+        module.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 128), jnp.int32)), one_chip)
+    params = variables.pop("params")
+    variables.pop("losses", None)
+
+    def loss(params, state, ids):
+        logits, sown = module.apply(
+            {"params": params, **state}, ids, train=True,
+            mutable=["losses", "counters", "router_state"])
+        return next_token_loss(logits, ids) + sum(
+            jnp.sum(a) for a in jax.tree_util.tree_leaves(sown["losses"]))
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, variables,
+                                             ids).compile()
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "/attention_flash/" in line]
+    assert len(flash) == 2
+    assert all("bf16[2,8192,256]" in line for line in flash)   # K/V: 2
+    assert "/linear_mixer/delta_rule/while/body" in text
+    # no heads' [L, L] scores (the convolution's [1, L, 8,192 channels]
+    # is no such tensor)
+    assert not re.search(r"\[(1,)?(2|16|32),8192,8192\]", text)
+    memory = compiled.memory_analysis()
+    state = 3 * memory.argument_size_in_bytes     # + Adam's mu and nu
+    print(f"hybrid: the gradient at L8192 holds "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB of temporaries beside "
+          f"{state / 1e9:.2f} GB of parameters and moments")
+    assert state + memory.temp_size_in_bytes < 15.75e9
