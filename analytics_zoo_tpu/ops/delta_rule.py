@@ -23,14 +23,18 @@ log-decay inside a chunk, ``Gamma_ij = exp(G_i - G_j)`` for ``j <= i``,
     S <- e^{G_C} S + (K * e^{G_C - G})^T V'
 
 Everything that does not read ``S`` is computed for all chunks at once;
-a ``lax.scan`` over the chunks carries ``S`` alone. ``T`` comes from the
-nilpotency of ``A`` (``A^C = 0``): ``(I + A)^-1`` is the product of
-``I + (-A)^(2^j)`` for ``j < log2 C``, ten small matrix products
-instead of ``C`` dependent row updates. The state and the log-decays
-are float32; every product takes its operands in the inputs' dtype and
-accumulates in float32. The backward pass is JAX's
-own through the scan (a decoder layer's ``nn.remat`` computes the
-forward again for it).
+a ``lax.scan`` over the chunks carries ``S`` alone. ``T`` is solved by
+forward substitution in float32: ``T_i = e_i - sum_{j<i} A_ij T_j``
+row by row inside diagonal blocks of 16, then block rows joined by
+``T_ij = -T_ii A_i,<i T_<i,<i`` (FLA's ``solve_tril``). Every term is
+bounded (``T`` is the WY form of a product of contractions), so nothing
+cancels; a power series for ``(I + A)^-1`` is no substitute, its terms
+grow as binomials where decay is weak and keys aligned, the regime of
+trained heads. Its gradient is ``-T^T dT T^T``, in float32 too. The
+state and the log-decays are float32; every other product takes its
+operands in the inputs' dtype and accumulates in float32. The backward
+pass is JAX's own through the scan (a decoder layer's ``nn.remat``
+computes the forward again for it).
 """
 
 from __future__ import annotations
@@ -64,31 +68,53 @@ def _dot(spec, a, b, dtype):
                       precision=precision, preferred_element_type=_F32)
 
 
-def _unit_lower_inverse(a, dtype):
-    """``(I + a)^-1`` for ``a`` [..., C, C] strictly lower triangular:
-    ``sum_k (-a)^k`` as a product of ``I + (-a)^(2^j)``, the products'
-    operands in ``dtype``, float32 sums (in bfloat16 the delta rule's
-    error moves by 0.2 % of itself against float32 products, which cost
-    six MXU passes for one: PERF.md, PR 40)."""
+_BLOCK = 16
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` [..., C, C] strictly lower triangular,
+    float32, by forward substitution (module docstring)."""
     size = a.shape[-1]
-    eye = jnp.eye(size, dtype=_F32)
-    power = -a
-    inverse = eye + power
-    for _ in range(max(size - 1, 1).bit_length() - 1):
-        power = _dot("...ij,...jk->...ik", power, power, dtype)
-        inverse = inverse + _dot("...ij,...jk->...ik", inverse, power, dtype)
-    return inverse
+    block = _BLOCK if size % _BLOCK == 0 else size
+    n = size // block
+    diag = jnp.stack([a[..., i * block:(i + 1) * block,
+                        i * block:(i + 1) * block] for i in range(n)], -3)
+    inv = jnp.broadcast_to(jnp.eye(block, dtype=_F32), diag.shape)
+    for i in range(1, block):        # row i reads the finished rows < i
+        row = -jnp.sum(diag[..., i, :, None] * inv, axis=-2)
+        inv = inv.at[..., i, :].add(row)
+    t = inv[..., 0, :, :]
+    for i in range(1, n):
+        t_ii = inv[..., i, :, :]
+        rows = a[..., i * block:(i + 1) * block, :i * block]
+        left = -_dot("...ij,...jk->...ik", t_ii,
+                     _dot("...ij,...jk->...ik", rows, t, _F32), _F32)
+        zeros = jnp.zeros(t.shape[:-1] + (block,), _F32)
+        t = jnp.concatenate([jnp.concatenate([t, zeros], -1),
+                             jnp.concatenate([left, t_ii], -1)], -2)
+    return t
 
 
-def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
-                             initial_state=None,
-                             output_final_state: bool = False):
+def _unit_lower_inverse_fwd(a):
+    t = _unit_lower_inverse(a)
+    return t, t
+
+
+def _unit_lower_inverse_bwd(t, dt):
+    da = -_dot("...ji,...jk->...ik", t,
+               _dot("...ij,...kj->...ik", dt, t, _F32), _F32)
+    return (jnp.tril(da, -1),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
     """q, k [B, L, H, d_k] (k L2-normalised and q scaled as the caller
     wants them), v [B, L, H, d_v], g and beta [B, L, H] -> o [B, L, H,
-    d_v] in v's dtype (module docstring). ``initial_state`` [B, H, d_k,
-    d_v] is the state before position 0 (zeros where None); with
-    ``output_final_state`` the state after the last position comes back
-    too, float32. ``chunk`` must divide L."""
+    d_v] in v's dtype (module docstring), from a zero state. ``chunk``
+    must divide L."""
     b, length, h, d_k = q.shape
     d_v = v.shape[-1]
     if length % chunk:
@@ -112,7 +138,7 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     a = jnp.where(row[:, None] > row[None, :],
                   beta[..., None] * _dot("...id,...jd->...ij", k, k, dtype)
                   * gamma, 0.0)
-    t = _unit_lower_inverse(a, dtype)
+    t = _unit_lower_inverse(a.astype(_F32))
     w = _dot("...ij,...jd->...id", t,
              k * (beta * jnp.exp(log_decay))[..., None], dtype)
     u = _dot("...ij,...jd->...id", t, v * beta[..., None], dtype)
@@ -130,12 +156,10 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
                  + _dot("...ci,...cj->...ij", k_n, written, dtype))
         return state, out.astype(dtype)
 
-    if initial_state is None:
-        initial_state = jnp.zeros((b, h, d_k, d_v), _F32)
     by_chunk = [jnp.moveaxis(x, 2, 0) for x in
                 (w, u, q_decayed, qk, k_decayed, last)]
-    state, out = jax.lax.scan(step, initial_state.astype(_F32), by_chunk)
+    _, out = jax.lax.scan(step, jnp.zeros((b, h, d_k, d_v), _F32), by_chunk)
     # [N, B, H, C, d_v] -> [B, L, H, d_v]
     out = jnp.moveaxis(out, 0, 2).reshape(b, h, length, d_v)
     out = jnp.moveaxis(out, 1, 2)
-    return (out, state) if output_final_state else out
+    return out

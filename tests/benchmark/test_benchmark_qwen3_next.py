@@ -4,6 +4,7 @@ hand counts and the initialised model, the per-layer readers on a
 made-up reduction, the reference's faults against bfloat16 rounding, and
 the cell end to end under the rehearsal switch."""
 
+import importlib
 import json
 import os
 import subprocess
@@ -34,6 +35,11 @@ WIDTHS = ("hidden_size", "head_dim", "moe_intermediate_size",
 READERS = (train_delta_rule_device_ms, train_delta_rule_roofline,
            train_linear_mixer_device_ms, train_attention_gated_roofline,
            moe_held_load_over_expected)
+# accepted metrics whose ``workloads`` this cell was appended to
+APPENDED = ("train_attention_device_ms", "train_moe_device_ms",
+            "train_moe_routing_device_ms", "train_moe_experts_roofline",
+            "moe_expert_load_max_over_mean", "moe_assignments_dropped",
+            "train_remat_device_ms")
 
 
 def _load(*parts):
@@ -184,6 +190,8 @@ def ctx(monkeypatch, config, data):
             _row("layer_*/linear_mixer/out_proj", 4.0),
             _row("layer_*/attention/attention_flash", 16.0),
             _row("layer_*/moe/moe_shared/shared/w1", 8.0),
+            _row("layer_*/moe/moe_route/router", 2.0),
+            _row("layer_*/moe/moe_experts", 6.0),
             _row("optimizer", 100.0),
         ]}
     monkeypatch.setattr(scope_reduce, "for_cell", lambda ctx: reduced)
@@ -195,7 +203,11 @@ def ctx(monkeypatch, config, data):
         "trace": {"busy_s": 1.0},
         "moe": {"moe_assignments_held": {
             f"layer_{i}/moe": [160 * 64] * 32
-            for i in range(4)}}}
+            for i in range(4)},
+            "moe_assignments_dropped": {
+                f"layer_{i}/moe": [0] for i in range(4)},
+            "moe_expert_assignments": {
+                f"layer_{i}/moe": [160 * 64] * 32 for i in range(4)}}}
 
 
 def test_readers_on_a_reduction(ctx):
@@ -212,6 +224,22 @@ def test_readers_on_a_reduction(ctx):
     assert 0 < train_attention_gated_roofline.read(ctx) < 100
     # 160 held assignments an expert and step over 64 steps: balanced
     assert moe_held_load_over_expected.read(ctx) == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("name", APPENDED)
+def test_accepted_metrics_list_the_cell_and_read_it(ctx, name):
+    """The expert layer's, the remat's and the attention dispatcher's
+    accepted readers read this cell's program too: appended at the end
+    of their ``workloads``, nothing else of the entry changed."""
+    entry, = [m for m in _load("BENCHMARK.json")["per_layer"]
+              if m["name"] == name]
+    assert entry["workloads"][-1] == CELL
+    assert CELL not in entry["workloads"][:-1]
+    value = importlib.import_module(
+        f"benchmark.layer_metrics.{name}").read(ctx)
+    assert value is not None and value >= 0
+    if name.endswith("_roofline"):
+        assert value < 100
 
 
 def test_readers_find_nothing_on_a_program_without_the_scopes(

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.ops.delta_rule import (
-    causal_short_conv, chunked_gated_delta_rule)
+    _unit_lower_inverse, causal_short_conv, chunked_gated_delta_rule)
 from benchmark.reference.qwen3_next import delta_rule
 
 
@@ -52,30 +52,90 @@ def test_chunked_form_is_the_recurrence(length, chunk, heads):
         assert _rel(got, want) < 1e-5
 
 
-def test_state_in_and_out_match_the_recurrence():
-    """A state carried in, and the last state out."""
-    q, k, v, g, beta = _inputs(128, 2)
-    state = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 16, 12))
-    with jax.default_matmul_precision("highest"):
-        out, last = chunked_gated_delta_rule(
-            q, k, v, g, beta, chunk=32, initial_state=state,
-            output_final_state=True)
-        want, want_last = delta_rule(q, k, v, g, beta, state)
-    assert _rel(out, want) < 1e-5 and _rel(last, want_last) < 1e-5
-
-
 def test_no_decay_and_no_write_leave_the_state_alone():
-    """g -> 0 and beta -> 0: nothing decays and nothing is written, so
-    the state comes out as it went in and every position reads it."""
-    q, k, v, g, beta = _inputs(96, 2)
-    state = jax.random.normal(jax.random.PRNGKey(2), (2, 2, 16, 12))
+    """g -> 0 and beta -> 0 after a first stretch: nothing decays and
+    nothing is written, so every later position reads the state the
+    first stretch left (the recurrence's last state there)."""
+    q, k, v, g, beta = _inputs(128, 2)
+    g = g.at[:, 48:].set(0.0)
+    beta = beta.at[:, 48:].set(0.0)
     with jax.default_matmul_precision("highest"):
-        out, last = chunked_gated_delta_rule(
-            q, k, v, jnp.zeros_like(g), jnp.zeros_like(beta), chunk=32,
-            initial_state=state, output_final_state=True)
-        read = jnp.einsum("rlhk,rhkv->rlhv", q, state)
-    np.testing.assert_allclose(last, state, rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(out, read, rtol=1e-5, atol=1e-5)
+        out = chunked_gated_delta_rule(q, k, v, g, beta, chunk=32)
+        _, state = delta_rule(*(a[:, :48] for a in (q, k, v, g, beta)))
+        read = jnp.einsum("rlhk,rhkv->rlhv", q[:, 48:], state)
+    np.testing.assert_allclose(out[:, 48:], read, rtol=1e-5, atol=1e-5)
+
+
+def _aligned_weak_decay(length=256, heads=2, d_k=32, d_v=16, cos=0.75):
+    """The regime of trained heads: decay near 0 (g ~ -1e-3), beta near
+    0.9, and L2-normed keys that share one direction per head (cosine
+    cos^2 = 0.56 between two of them)."""
+    rng = np.random.default_rng(7)
+    common = rng.normal(size=(heads, d_k))
+    common /= np.linalg.norm(common, axis=-1, keepdims=True)
+    k = rng.normal(size=(1, length, heads, d_k))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    k = cos * common + np.sqrt(1 - cos ** 2) * k
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    q = rng.normal(size=(1, length, heads, d_k)) / np.sqrt(d_k)
+    v = rng.normal(size=(1, length, heads, d_v))
+    g = -1e-3 * np.abs(rng.normal(size=(1, length, heads)))
+    beta = 0.9 + 0.05 * np.tanh(rng.normal(size=(1, length, heads)))
+    return tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta))
+
+
+@pytest.mark.parametrize("dtype,bound", [("float32", 1e-5),
+                                         ("bfloat16", 0.02)])
+def test_weak_decay_and_aligned_keys_stay_on_the_recurrence(dtype, bound):
+    """Chunks of 64 where (I + A)^-1 as a power series cancels
+    catastrophically (its terms reach ~1e9; PR 40's first form read
+    3.5e3 here in float32, inf in bfloat16): forward substitution stays
+    on the recurrence, in float32 to 1e-5 and with the program's
+    bfloat16 operands within 0.02 (bfloat16-rounded inputs alone move
+    the recurrence by ~0.004)."""
+    args = _aligned_weak_decay()
+    with jax.default_matmul_precision("highest"):
+        want = delta_rule(*args)[0]
+        low = [a.astype(dtype) for a in args[:3]] + list(args[3:])
+        got = chunked_gated_delta_rule(*low, chunk=64)
+    assert jnp.all(jnp.isfinite(got.astype(jnp.float32)))
+    assert _rel(got.astype(jnp.float32), want) < bound
+
+
+def test_weak_decay_and_aligned_keys_gradients_match():
+    """Every input's gradient in that regime, float32, to 1e-5."""
+    args = _aligned_weak_decay(length=128)
+
+    def loss(f):
+        return lambda *a: jnp.sum(jnp.sin(f(*a)))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda *a: chunked_gated_delta_rule(
+            *a, chunk=64)), argnums=range(5))(*args)
+        want = jax.grad(loss(lambda *a: delta_rule(*a)[0]),
+                        argnums=range(5))(*args)
+    for got_i, want_i in zip(got, want):
+        assert _rel(got_i, want_i) < 1e-5
+
+
+@pytest.mark.parametrize("size", [16, 64, 24])
+def test_unit_lower_inverse_is_the_inverse(size):
+    """Forward substitution (blocks of 16, or one block where 16 does
+    not divide the chunk) against numpy's inverse, and its gradient
+    against JAX's through ``jnp.linalg.inv``."""
+    rng = np.random.default_rng(size)
+    a = jnp.asarray(np.tril(rng.uniform(-1, 1, (3, size, size)), -1),
+                    jnp.float32)
+    eye = jnp.eye(size)
+    with jax.default_matmul_precision("highest"):
+        got = _unit_lower_inverse(a)
+        want = np.linalg.inv(np.asarray(eye + a, np.float64))
+        weights = jnp.asarray(rng.normal(size=(size, size)), jnp.float32)
+        grad = jax.grad(lambda x: jnp.sum(_unit_lower_inverse(x) * weights))
+        want_grad = jax.grad(
+            lambda x: jnp.sum(jnp.linalg.inv(eye + x) * weights))
+        assert _rel(got, want) < 1e-5
+        assert _rel(grad(a), jnp.tril(want_grad(a), -1)) < 1e-5
 
 
 def test_chunk_must_divide_the_length():
