@@ -472,7 +472,8 @@ def test_hybrid_decoder_step_fits_with_its_optimizer_state(one_chip):
     the next-token loss's gradient: the flash kernels run at heads of
     256 with 8 query heads a KV head in one forward and one backward
     kernel, the delta rule is a loop under its scope with no [L, L]
-    tensor, and the gradient's temporaries fit beside the parameters and
+    tensor and its triangular solve a kernel with the chunks on the
+    lanes, and the gradient's temporaries fit beside the parameters and
     Adam's two moments under the compiler's 15.75 GB."""
     from analytics_zoo_tpu.models.text import hybrid_decoder_lm as hybrid
     from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
@@ -507,6 +508,15 @@ def test_hybrid_decoder_step_fits_with_its_optimizer_state(one_chip):
     assert len(flash) == 2
     assert all("bf16[2,8192,256]" in line for line in flash)   # K/V: 2
     assert "/linear_mixer/delta_rule/while/body" in text
+    # (I + A)^-1 once a GDN layer a forward: three layers, each in the
+    # forward and in the backward's second forward (the layer's remat)
+    solves = [line for line in text.splitlines()
+              if "tpu_custom_call" in line
+              and "/linear_mixer/delta_rule/" in line]
+    assert len(solves) == 6
+    assert all("f32[64,64,4096]" in line for line in solves)
+    # nothing left of the XLA substitution's lane-sparse [16, 16] blocks
+    assert "f32[1,32,128,4,16,16]" not in text
     # no heads' [L, L] scores (the convolution's [1, L, 8,192 channels]
     # is no such tensor)
     assert not re.search(r"\[(1,)?(2|16|32),8192,8192\]", text)

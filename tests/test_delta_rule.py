@@ -118,13 +118,14 @@ def test_weak_decay_and_aligned_keys_gradients_match():
         assert _rel(got_i, want_i) < 1e-5
 
 
+@pytest.mark.parametrize("chunks", [(1,), (3,), (2, 65)])
 @pytest.mark.parametrize("size", [16, 64, 24])
-def test_unit_lower_inverse_is_the_inverse(size):
-    """Forward substitution (blocks of 16, or one block where 16 does
-    not divide the chunk) against numpy's inverse, and its gradient
-    against JAX's through ``jnp.linalg.inv``."""
+def test_unit_lower_inverse_is_the_inverse(size, chunks):
+    """The kernel's forward substitution against numpy's float64
+    inverse, and its gradient against JAX's through ``jnp.linalg.inv``,
+    for chunk counts that do not fill the lane block (1, 3, 130)."""
     rng = np.random.default_rng(size)
-    a = jnp.asarray(np.tril(rng.uniform(-1, 1, (3, size, size)), -1),
+    a = jnp.asarray(np.tril(rng.uniform(-1, 1, chunks + (size, size)), -1),
                     jnp.float32)
     eye = jnp.eye(size)
     with jax.default_matmul_precision("highest"):
@@ -134,8 +135,31 @@ def test_unit_lower_inverse_is_the_inverse(size):
         grad = jax.grad(lambda x: jnp.sum(_unit_lower_inverse(x) * weights))
         want_grad = jax.grad(
             lambda x: jnp.sum(jnp.linalg.inv(eye + x) * weights))
+        assert got.shape == a.shape and got.dtype == jnp.float32
         assert _rel(got, want) < 1e-5
         assert _rel(grad(a), jnp.tril(want_grad(a), -1)) < 1e-5
+
+
+def test_unit_lower_inverse_where_decay_is_weak_and_keys_aligned():
+    """The kernel on the A of ``_aligned_weak_decay``'s chunks of 64,
+    where a power series cancels: float32 substitution against numpy's
+    float64 inverse of the same float32 A, to 1e-5."""
+    _, k, _, g, beta = (np.asarray(x, np.float64)
+                        for x in _aligned_weak_decay())
+    chunk = 64
+
+    def chunks(x):                      # [1, L, H, ...] -> [H, N, C, ...]
+        x = x[0].reshape((-1, chunk) + x.shape[2:])
+        return np.moveaxis(x, 2, 0)
+
+    k, g, beta = chunks(k), np.cumsum(chunks(g), -1), chunks(beta)
+    a = np.tril(beta[..., None] * (k @ np.swapaxes(k, -1, -2))
+                * np.exp(g[..., :, None] - g[..., None, :]), -1)
+    a = a.astype(np.float32)
+    want = np.linalg.inv(np.eye(chunk) + a.astype(np.float64))
+    # the series' terms A^k reach ~1e9 here; T's entries stay within 1
+    assert np.abs(np.linalg.matrix_power(a, 16)).max() > 1e6
+    assert _rel(_unit_lower_inverse(jnp.asarray(a)), want) < 1e-5
 
 
 def test_chunk_must_divide_the_length():
