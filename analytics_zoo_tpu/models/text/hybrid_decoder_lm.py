@@ -17,7 +17,8 @@ layer is rematerialised for the backward pass and keeps
 ``sparse_decoder_lm.KEPT_NAMES``: on an attention layer the flash
 kernel's two results, q, k, v, the query product, the gate and the
 output projection, on every layer the shared expert's pre-activations;
-the Gated DeltaNet's scan runs again in the second forward.
+the Gated DeltaNet's chunk recurrence runs again in the second
+forward, which writes the states its backward kernel reads.
 """
 
 from __future__ import annotations

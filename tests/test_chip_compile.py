@@ -471,10 +471,11 @@ def test_hybrid_decoder_step_fits_with_its_optimizer_state(one_chip):
     (32 of 512 experts, 18,992 vocabulary rows), L8192, compiled with
     the next-token loss's gradient: the flash kernels run at heads of
     256 with 8 query heads a KV head in one forward and one backward
-    kernel, the delta rule is a loop under its scope with no [L, L]
-    tensor and its triangular solve a kernel with the chunks on the
-    lanes, and the gradient's temporaries fit beside the parameters and
-    Adam's two moments under the compiler's 15.75 GB."""
+    kernel, the delta rule has no [L, L] tensor, its triangular solve
+    is a kernel with the chunks on the lanes and its chunk recurrence a
+    kernel pair with no loop of XLA's, and the gradient's temporaries
+    fit beside the parameters and Adam's two moments under the
+    compiler's 15.75 GB."""
     from analytics_zoo_tpu.models.text import hybrid_decoder_lm as hybrid
     from analytics_zoo_tpu.models.text.sparse_decoder_lm import (
         next_token_loss)
@@ -507,14 +508,23 @@ def test_hybrid_decoder_step_fits_with_its_optimizer_state(one_chip):
              if "tpu_custom_call" in line and "/attention_flash/" in line]
     assert len(flash) == 2
     assert all("bf16[2,8192,256]" in line for line in flash)   # K/V: 2
-    assert "/linear_mixer/delta_rule/while/body" in text
+    # the chunk recurrence is kernels, not a loop of XLA's
+    assert not re.search(r"/linear_mixer/delta_rule/[^\"]*while", text)
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line
+               and "/linear_mixer/delta_rule/" in line]
     # (I + A)^-1 once a GDN layer a forward: three layers, each in the
     # forward and in the backward's second forward (the layer's remat)
-    solves = [line for line in text.splitlines()
-              if "tpu_custom_call" in line
-              and "/linear_mixer/delta_rule/" in line]
+    solves = [line for line in kernels if "f32[64,64,4096]" in line]
     assert len(solves) == 6
-    assert all("f32[64,64,4096]" in line for line in solves)
+    # the recurrence: the forward without the states, the second forward
+    # with the float32 state entering each chunk, and the backward
+    recurrences = [line for line in kernels
+                   if "bf16[1,32,128,64,128]" in line]
+    assert len(recurrences) == 9 and len(kernels) == 15
+    states = [line for line in recurrences
+              if "f32[1,32,128,128,128]" in line]
+    assert len(states) == 6       # written by three, read by three
     # nothing left of the XLA substitution's lane-sparse [16, 16] blocks
     assert "f32[1,32,128,4,16,16]" not in text
     # no heads' [L, L] scores (the convolution's [1, L, 8,192 channels]
