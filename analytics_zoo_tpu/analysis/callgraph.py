@@ -77,15 +77,12 @@ CTX_HOTPATH = "hotpath"
 # stages (the threads that must never stall on device results) and the
 # inference engine's async dispatch entry
 _HOT_STAGE_METHODS = {
-    "ServingWorker": {"process_one_batch", "_decode_stage",
-                      "_dispatch_group", "_predict_group",
-                      "_run_pipelined"},
+    "ServingWorker": {"_decode_stage", "_dispatch_group", "run"},
     "InferenceModel": {"predict_async"},
 }
 # the finalize seam: materializing device results here is the design
-# (the pipelined engine's third stage exists to absorb that sync)
-_FINALIZE_SEAM = {"_finalize_one", "_finalize_record",
-                  "_finalize_inner", "finalize_loop"}
+# (the engine's third stage exists to absorb that sync)
+_FINALIZE_SEAM = {"_finalize_record", "_finalize_inner", "finalize_loop"}
 _HOT_DECL = "ZOOLINT_HOT_PATH"
 
 _JIT_WRAPPERS = {"jit", "pmap", "shard_map", "partial"}

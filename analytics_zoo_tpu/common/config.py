@@ -49,10 +49,9 @@ _DEFAULTS: Dict[str, Any] = {
     # ladder so it never introduces a new XLA shape.
     "zoo.serving.batch_timeout_min_ms": 1.0,
     "zoo.serving.batch_max_size": 0,
-    # pipelined serving engine: decode -> assemble/dispatch -> finalize
-    # run as overlapped stages with up to pipeline.depth dispatched
-    # batches in flight; false restores the synchronous per-batch loop
-    "zoo.serving.pipeline.enabled": True,
+    # serving engine: decode -> assemble/dispatch -> finalize run as
+    # overlapped stages with up to pipeline.depth dispatched batches in
+    # flight
     "zoo.serving.pipeline.depth": 2,
     # launcher default when the YAML omits http.port; 0 = pick a free
     # port (the reference FrontEndApp pinned 10020 -- set that here to
@@ -300,7 +299,6 @@ _SPECS: Dict[str, tuple] = {
     "zoo.serving.batch_timeout_ms": ("float", 0, None),
     "zoo.serving.batch_timeout_min_ms": ("float", 0, None),
     "zoo.serving.batch_max_size": ("int", 0, None),
-    "zoo.serving.pipeline.enabled": ("bool",),
     "zoo.serving.pipeline.depth": ("int", 1, None),
     "zoo.serving.http_port": ("int", 0, 65535),
     "zoo.serving.supervisor.enabled": ("bool",),

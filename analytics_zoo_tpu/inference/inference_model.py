@@ -360,7 +360,7 @@ class InferenceModel:
         caller can submit the next batch (overlapping its host->device
         transfer with this batch's compute) before fetching these
         outputs with ``np.asarray(...)[:n]``. The serving worker's
-        pipelined mode is built on this."""
+        engine is built on this."""
         if self._apply_fn is None:
             raise RuntimeError("no model loaded")
         # canonicalize 64-bit host inputs (JSON ints/floats) to the

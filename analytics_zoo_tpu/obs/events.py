@@ -101,7 +101,7 @@ EVENT_TYPES: Dict[str, str] = {
     "frontend_start": "HTTP frontend listening (fields: address)",
     "frontend_stop": "HTTP frontend stopped",
     "serving_launch": "launcher assembled a deployment "
-                      "(fields: queue, pipelined, http, shard_mode)",
+                      "(fields: queue, http, shard_mode)",
     "shard_attached": "a serving shard plan committed the model onto "
                       "a device mesh (fields: mode, axis, devices, "
                       "recipe, quantized_collectives)",

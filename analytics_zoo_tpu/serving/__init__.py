@@ -28,10 +28,7 @@ from analytics_zoo_tpu.serving.queues import (  # noqa: F401
     DirQueue,
     MemQueue,
 )
-from analytics_zoo_tpu.serving.batcher import (  # noqa: F401
-    AdaptiveBatcher,
-    MicroBatcher,
-)
+from analytics_zoo_tpu.serving.batcher import AdaptiveBatcher  # noqa: F401
 from analytics_zoo_tpu.serving.worker import ServingWorker  # noqa: F401
 from analytics_zoo_tpu.serving.generation import (  # noqa: F401
     ContinuousBatcher,

@@ -1,4 +1,4 @@
-"""Micro-batchers with bounded backpressure.
+"""Micro-batcher with bounded backpressure.
 
 The analog of ``ClusterServingInference`` batching
 (ref: zoo/.../serving/engine/ClusterServingInference.scala:33-160 --
@@ -6,22 +6,22 @@ groups up to ``batchSize`` requests per inference call; Flink supplied
 backpressure upstream, here the bounded input queue does, SURVEY.md
 section 7 "hard parts: serving ... our batcher must implement it").
 
-Two policies:
+:class:`AdaptiveBatcher` closes a batch on size OR deadline, with both
+knobs adapted to observed queue depth (the batch-assembly policy result
+of arXiv:2605.25645: size *and* deadline dominate serving efficiency):
 
-- :class:`MicroBatcher` -- the fixed size/timeout policy: close a batch
-  on ``batch_size`` reached or ``timeout_ms`` after the first item.
-- :class:`AdaptiveBatcher` -- size OR deadline close with both knobs
-  adapted to observed queue depth (the batch-assembly policy result of
-  arXiv:2605.25645: size *and* deadline dominate serving efficiency):
+* **deadline tightens when the queue is shallow** -- waiting the full
+  linger for stragglers that are not coming only adds latency, so the
+  linger shrinks toward ``min_timeout_ms`` as depth drops;
+* **the cap grows when backlog builds** -- enough waiting requests to
+  fill a larger device bucket means a bigger batch amortizes
+  per-dispatch overhead and drains the backlog; grown caps are
+  snapped to the power-of-two bucket ladder of
+  ``InferenceModel.predict`` so no new XLA shapes are introduced.
 
-  * **deadline tightens when the queue is shallow** -- waiting the full
-    linger for stragglers that are not coming only adds latency, so the
-    linger shrinks toward ``min_timeout_ms`` as depth drops;
-  * **the cap grows when backlog builds** -- enough waiting requests to
-    fill a larger device bucket means a bigger batch amortizes
-    per-dispatch overhead and drains the backlog; grown caps are
-    snapped to the power-of-two bucket ladder of
-    ``InferenceModel.predict`` so no new XLA shapes are introduced.
+With ``min_timeout_ms == timeout_ms`` and ``max_batch_size <=
+batch_size`` (or a queue without a depth) it is the fixed policy: close
+on ``batch_size`` reached or ``timeout_ms`` after the first item.
 """
 
 from __future__ import annotations
@@ -51,41 +51,7 @@ def _bucket(n: int) -> int:
     return b
 
 
-class MicroBatcher:
-    """Pulls items from a queue-like (``get(timeout)``), groups up to
-    ``batch_size`` within ``timeout_ms`` of the first item."""
-
-    def __init__(self, queue, batch_size: int = 8,
-                 timeout_ms: float = 5.0):
-        self.queue = queue
-        self.batch_size = batch_size
-        self.timeout_ms = timeout_ms
-
-    def next_batch(self, wait_timeout: Optional[float] = 1.0
-                   ) -> List[Any]:
-        chaos_point("pull")  # queue-stall / crash injection seam
-        first = self.queue.get(timeout=wait_timeout)
-        if first is None:
-            return []
-        batch = [first]
-        deadline = time.time() + self.timeout_ms / 1000.0
-        while len(batch) < self.batch_size:
-            remaining = deadline - time.time()
-            if remaining <= 0:
-                break
-            item = self.queue.get(timeout=remaining)
-            if item is None:
-                break
-            batch.append(item)
-        _M_CLOSES.labels(reason="size" if len(batch) >= self.batch_size
-                         else "deadline").inc()
-        return batch
-
-    def stats(self) -> Dict[str, Any]:
-        return {}
-
-
-class AdaptiveBatcher(MicroBatcher):
+class AdaptiveBatcher:
     """Deadline/size micro-batcher whose cap and linger track queue
     depth (policy described in the module docstring).
 
@@ -104,8 +70,9 @@ class AdaptiveBatcher(MicroBatcher):
                  timeout_ms: float = 5.0,
                  min_timeout_ms: Optional[float] = None,
                  max_batch_size: Optional[int] = None):
-        super().__init__(queue, batch_size=batch_size,
-                         timeout_ms=timeout_ms)
+        self.queue = queue
+        self.batch_size = batch_size
+        self.timeout_ms = timeout_ms
         self.min_timeout_ms = (timeout_ms * 0.2
                                if min_timeout_ms is None
                                else min(min_timeout_ms, timeout_ms))

@@ -870,7 +870,6 @@ class HttpFrontend:
         }
         if worker is not None:
             payload["served"] = worker.served
-            payload["pipelined"] = worker.pipelined
         if gen is not None:
             payload["generation_served"] = gen.served
         return (200 if alive and not self._draining else 503), payload

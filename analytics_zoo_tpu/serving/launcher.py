@@ -33,8 +33,6 @@ Config schema (defaults in parentheses)::
       max_batch_size: 0                  # backlog growth cap (0 = 4x batch_size
                                          # bucket); growth stays on the ladder
       top_n: null                        # classes/scores of top-N
-      pipelined: null                    # null = zoo.serving.pipeline.enabled;
-                                         # false restores the synchronous engine
       pipeline_depth: 2                  # in-flight predict batches
                                          # (1 disables overlap)
       warm_batch_sizes: [1, 8]           # pre-compiled buckets (uses the
@@ -400,7 +398,6 @@ def launch(config: Dict[str, Any], model: Any = None) -> ServingApp:
             timeout_ms=params.get("timeout_ms"),
             top_n=params.get("top_n"),
             pipeline_depth=params.get("pipeline_depth"),
-            pipelined=params.get("pipelined"),
             min_timeout_ms=params.get("min_timeout_ms"),
             max_batch_size=params.get("max_batch_size"))
         from analytics_zoo_tpu.inference.inference_model import (
@@ -598,7 +595,6 @@ def launch(config: Dict[str, Any], model: Any = None) -> ServingApp:
     emit_event(
         "serving_launch", "serving",
         queue=str(data.get("queue") or "memory"),
-        pipelined=worker.pipelined if worker is not None else False,
         http=bool(http.get("enabled", True)),
         shard_mode=(shard_plan.label if shard_plan is not None
                     else "off"),

@@ -8,7 +8,7 @@ key silently drops a deadline on the floor and a prefix the frontend
 cannot map turns a structured rejection into a generic 500, and both
 only surface under load.
 
-Reserved wire keys (AZT1/npz blob tensor names; see
+Reserved wire keys (AZT1 blob tensor names; see
 ``queues._encode``):
 
 - ``__uri__``       request id, the reply-correlation key

@@ -5,7 +5,7 @@ The client API mirrors the reference's ``InputQueue``/``OutputQueue``
 tensors; dequeue reads the result stream). Backends:
 
 - ``MemQueue``: in-process deque (single-process serving, tests);
-- ``DirQueue``: a spool directory; each item is one ``.npz`` file,
+- ``DirQueue``: a spool directory; each item is one ``.item`` file,
   consumers claim atomically with ``os.rename`` -- cross-process safe
   with no broker, and items survive crashes (the durability Redis
   provided in the reference).
@@ -14,7 +14,6 @@ tensors; dequeue reads the result stream). Backends:
 from __future__ import annotations
 
 import collections
-import io
 import os
 import struct
 import threading
@@ -47,15 +46,10 @@ _M_DEQ = _REG.counter(
     "zoo_serving_dequeue_total",
     "Results drained from the serving output queue")
 
-# Wire format. v1 was np.savez (one zip archive per request): simple,
-# but the zip machinery costs ~260 us per request round-trip -- it was
-# the single largest host cost of the serving cycle (measured on the
-# ISSUE-1 pipeline bench; see BENCH_NOTES.md). v2 ("AZT1") frames raw
-# ndarray buffers with a dtype/shape header: ~15 us round-trip, no
-# pickle surface, and decode still accepts v1 blobs (zip magic) so
-# spooled items from older deployments keep draining.
+# Wire format "AZT1": raw ndarray buffers behind a dtype/shape header
+# -- ~15 us a request round-trip and no pickle surface, where a zip
+# archive per request cost ~260 us (BENCH_NOTES.md).
 _MAGIC = b"AZT1"
-_ZIP_MAGIC = b"PK"  # np.savez container (legacy v1 blobs)
 
 
 def _encode(uri: str, payload: Dict[str, np.ndarray],
@@ -155,8 +149,8 @@ def _decode_raw(blob: bytes) -> Dict[str, np.ndarray]:
         n = 1
         for s in shape:
             n *= s
-        # .copy(): frombuffer views are read-only; requests keep the
-        # writable-array contract the npz decoder gave user hooks
+        # .copy(): frombuffer views are read-only; user hooks may
+        # write to request arrays in place
         out[name] = np.frombuffer(
             blob, dtype=dtype, count=n,
             offset=off).reshape(shape).copy()
@@ -179,18 +173,13 @@ def _decode_traced(blob: bytes) -> Tuple[str, Dict[str, np.ndarray],
 
 
 def _decode_to_dict(blob: bytes) -> Dict[str, np.ndarray]:
-    """Framing dispatch, THE one place the blob container format is
-    recognized: AZT1 raw-buffer framing, or the legacy np.savez (zip)
-    container -- both -> {name: array}. Every decoder (predict,
-    generation) goes through here, so a future framing change has one
-    home."""
-    if blob[:4] == _MAGIC:
-        return _decode_raw(blob)
-    if not blob.startswith(_ZIP_MAGIC):
-        raise ValueError("not a serving wire blob (neither AZT1 nor "
-                         "legacy npz framing)")
-    with np.load(io.BytesIO(blob), allow_pickle=False) as z:  # legacy v1
-        return {k: z[k] for k in z.files}
+    """Framing check, THE one place the blob container format is
+    recognized: AZT1 raw-buffer framing -> {name: array}. Every decoder
+    (predict, generation) goes through here, so a future framing
+    change has one home."""
+    if blob[:4] != _MAGIC:
+        raise ValueError("not a serving wire blob (no AZT1 framing)")
+    return _decode_raw(blob)
 
 
 def _request_meta(z: Dict[str, np.ndarray]

@@ -380,12 +380,11 @@ class Supervisor:
         # thundering herd when N hosts restart off the same outage
         _M_RESTARTS.labels(reason=reason).inc()
         # reap the old run: for a crash the thread is already dead and
-        # stop() just joins + flushes; for a wedge it times out and we
+        # stop() just joins; for a wedge it times out and we
         # abandon the thread -- its per-run stop event is now set, so
         # if it ever wakes it exits instead of double-serving
         self.worker.stop(join_timeout=1.0)
         self.worker._thread = None
-        self.worker._inflight.clear()  # stale sync-engine records
         requeued = self._requeue()
         emit_event("worker_restart", "serving", reason=reason,
                    restarts=self.restarts,

@@ -4,23 +4,17 @@ The analog of the Flink inference task (ref: zoo/.../serving/engine/
 FlinkInference.scala:32-80 -- per-TM singleton InferenceModel fed by
 micro-batches from the Redis source; batching logic in
 engine/ClusterServingInference.scala:33-160). The TPU redesign runs one
-worker loop per serving host, in one of two modes:
-
-- **pipelined** (default, ``zoo.serving.pipeline.enabled``): an
-  explicitly staged engine. A *decode* stage (its own thread, image
-  decode fanned out over the shared thread pool) pulls micro-batches
-  via :class:`AdaptiveBatcher` and feeds an *assembly* stage that
-  stacks shape-compatible requests into padded, bucket-ladder device
-  batches and dispatches them through the non-blocking
-  ``InferenceModel.predict_async`` -- JAX's async dispatch keeps up to
-  ``pipeline_depth`` batches in flight -- while a *finalize* stage on a
-  third thread drains completed results in dispatch order. Decode of
-  batch k+1 therefore overlaps device compute of batch k and result
-  fetch/postprocess/push of batch k-1 (the stage overlap BigDL 2.0's
-  Cluster Serving gets from the Flink dataflow, arXiv:2204.01715).
-- **synchronous** (the escape hatch): one pull -> decode -> predict ->
-  finalize cycle at a time on the caller's thread, still with
-  ``pipeline_depth`` async dispatches in flight between cycles.
+explicitly staged engine per serving host. A *decode* stage (its own
+thread, image decode fanned out over the shared thread pool) pulls
+micro-batches via :class:`AdaptiveBatcher` and feeds an *assembly*
+stage that stacks shape-compatible requests into padded, bucket-ladder
+device batches and dispatches them through the non-blocking
+``InferenceModel.predict_async`` -- JAX's async dispatch keeps up to
+``pipeline_depth`` batches in flight -- while a *finalize* stage on a
+third thread drains completed results in dispatch order. Decode of
+batch k+1 therefore overlaps device compute of batch k and result
+fetch/postprocess/push of batch k-1 (the stage overlap BigDL 2.0's
+Cluster Serving gets from the Flink dataflow, arXiv:2204.01715).
 
 Results never reorder: the in-flight window is a FIFO and finalize is
 single-threaded, so responses leave in dispatch order. Every stage is
@@ -30,7 +24,6 @@ queue-depth / batch-occupancy / in-flight gauges.
 
 from __future__ import annotations
 
-import collections
 import os
 import queue as _pyqueue
 import threading
@@ -45,7 +38,7 @@ from analytics_zoo_tpu.obs.events import emit as emit_event
 from analytics_zoo_tpu.obs.flight import get_inflight
 from analytics_zoo_tpu.obs.metrics import get_registry
 from analytics_zoo_tpu.obs.tracing import get_tracer
-from analytics_zoo_tpu.serving.batcher import AdaptiveBatcher, MicroBatcher
+from analytics_zoo_tpu.serving.batcher import AdaptiveBatcher
 from analytics_zoo_tpu.serving.chaos import chaos_point
 from analytics_zoo_tpu.serving.protocol import (
     CIRCUIT_PREFIX, DEADLINE_PREFIX, ERROR_KEY, INVALID_PREFIX,
@@ -59,7 +52,6 @@ logger = get_logger(__name__)
 # path through these stage methods must reach a reply, error-reply,
 # requeue, or ownership hand-off -- the static twin of the ledger
 ZOOLINT_REPLY_OBLIGATED = (
-    "ServingWorker._predict_group",
     "ServingWorker._finalize_record",
 )
 
@@ -263,9 +255,6 @@ class ServingWorker:
         logits instead of the raw output (ref: PostProcessing topN).
       pipeline_depth: bounded in-flight window -- how many dispatched
         batches may await finalize (None reads config).
-      pipelined: True runs the staged decode/assemble/finalize engine;
-        False the synchronous loop; None reads
-        ``zoo.serving.pipeline.enabled``.
     """
 
     def __init__(self, model, input_queue, output_queue,
@@ -276,7 +265,6 @@ class ServingWorker:
                  top_n: Optional[int] = None,
                  timer: Optional[Timer] = None,
                  pipeline_depth: Optional[int] = None,
-                 pipelined: Optional[bool] = None,
                  min_timeout_ms: Optional[float] = None,
                  max_batch_size: Optional[int] = None,
                  breaker=None):
@@ -292,24 +280,13 @@ class ServingWorker:
             max_batch_size = int(cfg.get("zoo.serving.batch_max_size", 0))
         if pipeline_depth is None:
             pipeline_depth = int(cfg.get("zoo.serving.pipeline.depth", 2))
-        if pipelined is None:
-            pipelined = bool(cfg.get("zoo.serving.pipeline.enabled", True))
         self.model = model
         self._in = getattr(input_queue, "queue", input_queue)
         self._out_q = output_queue
-        self.pipelined = bool(pipelined)
-        if self.pipelined:
-            self.batcher = AdaptiveBatcher(
-                self._in, batch_size=batch_size, timeout_ms=timeout_ms,
-                min_timeout_ms=min_timeout_ms,
-                max_batch_size=max_batch_size or None)
-        else:
-            # the escape hatch restores the WHOLE pre-pipeline engine,
-            # fixed size/timeout batching included -- an operator
-            # disabling the pipeline gets the proven old path, not a
-            # half-new one
-            self.batcher = MicroBatcher(self._in, batch_size=batch_size,
-                                        timeout_ms=timeout_ms)
+        self.batcher = AdaptiveBatcher(
+            self._in, batch_size=batch_size, timeout_ms=timeout_ms,
+            min_timeout_ms=min_timeout_ms,
+            max_batch_size=max_batch_size or None)
         self.input_fn = input_fn
         self.output_fn = output_fn
         self.top_n = top_n
@@ -332,9 +309,8 @@ class ServingWorker:
         # flight (predict_async), so batch n+1's host->device transfer
         # overlaps batch n's device compute + result fetch; 1 disables
         self.pipeline_depth = max(1, int(pipeline_depth))
-        self._inflight: collections.deque = collections.deque()
-        # live handle on the pipelined engine's in-flight window (for
-        # metrics); set for the duration of a pipelined run
+        # live handle on the engine's in-flight window (for metrics);
+        # set for the duration of a run
         self._inflight_q: Optional[_pyqueue.Queue] = None
         # resilience hooks (ISSUE-5) -- all None/absent-cheap when off:
         # * ledger: a Supervisor attaches a RequestLedger so the
@@ -375,9 +351,9 @@ class ServingWorker:
         self._drain = threading.Event()
         self.heartbeat = time.monotonic()
         # decode stage's own heartbeat: None while no decode thread is
-        # running (sync engine, bounded runs after their decode loop
-        # finished) -- the supervisor only reads it when set, so a
-        # finished decode loop cannot read as a wedge
+        # running (bounded runs after their decode loop finished) --
+        # the supervisor only reads it when set, so a finished decode
+        # loop cannot read as a wedge
         self.heartbeat_decode: Optional[float] = None
 
     def _count_served(self, n: int) -> None:
@@ -399,54 +375,6 @@ class ServingWorker:
         except Exception as e:
             logger.warning("input ack for %d request(s) failed: %s",
                            len(tuple(uris)), e)
-
-    # ------------------------------------------------- synchronous loop --
-    def process_one_batch(self, wait_timeout: float = 1.0) -> int:
-        """One pull->predict->push cycle (the synchronous engine);
-        returns requests served."""
-        self.heartbeat = time.monotonic()
-        with self.timer.timing("batch_wait"):
-            blobs = self.batcher.next_batch(wait_timeout=wait_timeout)
-        if not blobs:
-            n = 0
-            while self._inflight:  # idle: drain pipelined batches
-                n += self._finalize_one()
-            self._count_served(n)
-            return n
-        items, bad_images, decode_s = self._decode_stage(blobs)
-        n_failed = 0
-        for uri, reply, msg in bad_images:
-            logger.warning("serving: %s", msg)
-            self._push_error(uri, reply, msg)
-            n_failed += 1
-        groups = self._group_compatible(items)
-        # the decode stage is shared by every signature group of this
-        # cycle: apportion it by group size so a group's "service"
-        # metric neither double-counts earlier groups' decode+prep
-        # time nor charges a 1-item group a 127-item group's decode
-        self._decode_per_item = decode_s / max(1, len(items))
-        n = n_failed
-        for group in groups:
-            group, expired = self._split_expired(group, "dispatch")
-            for uri, reply, msg in expired:
-                self._push_error(uri, reply, msg)
-            n += len(expired)
-            if not group:
-                continue
-            try:
-                n += self._predict_group(group)
-            except Exception as e:  # input_fn/output_fn bugs must not
-                logger.exception(  # kill the serving thread
-                    "serving batch failed: %s", e)
-                for item in group:
-                    self._push_error(item[0], item[2], str(e))
-                n += len(group)
-        # finalize the oldest in-flight batches beyond the pipeline
-        # depth (idle cycles drain the rest -- see the early return)
-        while len(self._inflight) >= self.pipeline_depth:
-            n += self._finalize_one()
-        self._count_served(n)
-        return n
 
     # ------------------------------------------------------- stages -----
     def _decode_stage(self, blobs) -> Tuple[List, List, float]:
@@ -638,28 +566,6 @@ class ServingWorker:
         return (_BATCH, uris, replies, preds, n, prep_s, traces,
                 deadlines)
 
-    def _predict_group(self, group) -> int:
-        rec = self._dispatch_group(group)
-        if rec[0] == _ERRORS:
-            for uri, reply, msg in rec[1]:
-                self._push_error(uri, reply, msg)
-            return len(rec[1])
-        self._inflight.append(rec)
-        return 0  # counted when finalized
-
-    def _finalize_one(self) -> int:
-        """Materialize the oldest in-flight batch and push its results
-        (async dispatch errors surface here). The pop is race-guarded:
-        after a wedge restart an abandoned run's drain can briefly
-        overlap the new run on this deque (deque ops are atomic, the
-        check-then-pop is not) -- losing the race must cost nothing,
-        not an IndexError that kills a serving thread."""
-        try:
-            rec = self._inflight.popleft()
-        except IndexError:
-            return 0
-        return self._finalize_record(rec)
-
     def _finalize_record(self, rec) -> int:
         """Finalize stage for one in-flight record. Never raises:
         push-path failures (broker down, spool disk full) must not kill
@@ -790,19 +696,21 @@ class ServingWorker:
                     self._push_error(uri, reply, str(e))
         return len(uris)
 
-    # ---------------------------------------------- pipelined engine ----
-    def _run_pipelined(self, max_batches: Optional[int],
-                       wait_timeout: float,
-                       stop_ev: threading.Event,
-                       drain_ev: Optional[threading.Event] = None) -> int:
-        """The staged engine: decode thread -> assembly/dispatch (this
-        thread) -> finalize thread, bounded by ``pipeline_depth``
-        dispatched batches in flight. A bounded run returns only after
-        every request it pulled is answered. ``stop_ev`` is THIS run's
-        stop event (captured, not ``self._stop``): a supervisor
-        restart hands the next run a fresh event, so an abandoned
-        wedged thread that wakes later sees its own set event and
-        exits instead of double-serving."""
+    # ---------------------------------------------------------- engine --
+    def run(self, max_batches: Optional[int] = None,
+            wait_timeout: float = 0.05) -> int:
+        """Serve until stopped (or ``max_batches`` pull cycles); returns
+        total requests served in this call. The staged engine: decode
+        thread -> assembly/dispatch (this thread) -> finalize thread,
+        bounded by ``pipeline_depth`` dispatched batches in flight. A
+        bounded run returns only after every request it pulled is
+        answered. The run captures THIS run's stop and drain events
+        (not ``self._stop`` on each check): a supervisor restart hands
+        the next run fresh events, so an abandoned wedged thread that
+        wakes later sees its own set event and exits instead of
+        double-serving."""
+        stop_ev = self._stop
+        drain_ev = self._drain
         decoded_q: _pyqueue.Queue = _pyqueue.Queue(
             maxsize=max(2, self.pipeline_depth))
         inflight_q: _pyqueue.Queue = _pyqueue.Queue(
@@ -823,7 +731,7 @@ class ServingWorker:
             pulled = 0
             try:
                 while not stop_ev.is_set() and not abort.is_set():
-                    if drain_ev is not None and drain_ev.is_set():
+                    if drain_ev.is_set():
                         # draining: stop pulling; the sentinel below
                         # flushes everything already in the pipeline
                         # through dispatch + finalize, then the run
@@ -961,34 +869,6 @@ class ServingWorker:
         return served_box[0]
 
     # ------------------------------------------------------- lifecycle --
-    def run(self, max_batches: Optional[int] = None,
-            wait_timeout: float = 0.05) -> int:
-        """Serve until stopped (or ``max_batches`` pull cycles); returns
-        total requests served in this call."""
-        stop_ev = self._stop  # capture: this RUN's stop event -- see
-        # _run_pipelined's docstring for the restart semantics
-        drain_ev = self._drain  # same per-run capture
-        if self.pipelined:
-            return self._run_pipelined(max_batches, wait_timeout,
-                                       stop_ev, drain_ev)
-        total = 0
-        batches = 0
-        while not stop_ev.is_set() and not drain_ev.is_set():
-            total += self.process_one_batch(wait_timeout=wait_timeout)
-            batches += 1
-            if max_batches is not None and batches >= max_batches:
-                break
-        # a bounded run returns only after everything it pulled is
-        # answered (pipelined batches must not linger past the call).
-        # Identity-gated: after a wedge restart this may be an
-        # ABANDONED run waking up -- the deque now belongs to the new
-        # run, whose own drain answers these records
-        while self._inflight and self._stop is stop_ev:
-            n = self._finalize_one()
-            self._count_served(n)
-            total += n
-        return total
-
     def serve_forever(self) -> None:
         try:
             self.run()
@@ -1025,8 +905,6 @@ class ServingWorker:
         if thread.is_alive():
             return False
         self._thread = None
-        while self._inflight:  # sync-engine leftovers
-            self._count_served(self._finalize_one())
         return True
 
     def start(self) -> "ServingWorker":
@@ -1041,7 +919,7 @@ class ServingWorker:
         self._thread = threading.Thread(target=self.serve_forever,
                                         daemon=True)
         self._thread.start()
-        emit_event("worker_start", "serving", pipelined=self.pipelined,
+        emit_event("worker_start", "serving",
                    batch_size=self.batcher.batch_size,
                    pipeline_depth=self.pipeline_depth)
         return self
@@ -1054,17 +932,14 @@ class ServingWorker:
             thread.join(join_timeout)
             if thread.is_alive():
                 # the worker thread is still draining (e.g. a slow
-                # first compile); it owns the in-flight window --
-                # draining here would race its pops. KEEP the handle so
-                # a retried stop() (or start()) still sees the live
-                # thread.
+                # first compile); it owns the in-flight window. KEEP
+                # the handle so a retried stop() (or start()) still
+                # sees the live thread.
                 logger.warning("serving worker still busy after %.1fs; "
                                "in-flight batches drain on its thread",
                                join_timeout)
                 return
             self._thread = None
-        while self._inflight:  # flush: accepted requests must answer
-            self._count_served(self._finalize_one())
 
     # --------------------------------------------------------- outputs --
     def _push(self, uri: str, reply: Optional[str],
@@ -1121,12 +996,11 @@ class ServingWorker:
     # --------------------------------------------------------- metrics --
     def metrics(self) -> Dict[str, Any]:
         inflight_q = self._inflight_q  # read once: the worker thread
-        # clears this attribute when a pipelined run exits
+        # clears this attribute when a run exits
         pipe: Dict[str, Any] = {
-            "enabled": self.pipelined,
             "depth": self.pipeline_depth,
             "inflight": (inflight_q.qsize() if inflight_q is not None
-                         else len(self._inflight)),
+                         else 0),
             "batcher": self.batcher.stats(),
         }
         try:

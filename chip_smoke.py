@@ -19,7 +19,7 @@ device line below comes from the process that held it):
 - train     ``BERTSQuAD`` at its published width, L384, batch 32, two
             passes over the same 16 seeded batches through ``model.fit``
 - serve     ResNet-18 ``ImageClassifier`` saved and served through
-            ``serving.launcher.launch`` (pipelined engine), JPEG
+            ``serving.launcher.launch``, JPEG
             requests against a direct ``InferenceModel.predict``
 - generate  the ``generation:`` plane of the same launcher, streamed
             tokens against the model's teacher-forced full forward
@@ -362,15 +362,13 @@ def phase_serve(watch, n_requests=192, batch=128, image_size=224,
             # decoded JPEGs arrive as uint8 (normalized on device);
             # growth pinned to the warmed ladder's top
             "params": {"batch_size": batch, "max_batch_size": batch,
-                       "timeout_ms": 2.0, "pipelined": True,
+                       "timeout_ms": 2.0,
                        "warm_example": np.zeros(
                            (1, image_size, image_size, 3), np.uint8)},
             "http": {"enabled": False},
         })
         warm_s = time.perf_counter() - t0
         try:
-            check(app.worker.pipelined, "launcher did not build the "
-                                        "pipelined engine")
             before = watch.snapshot()
             t0 = time.perf_counter()
             for i, jpeg in enumerate(jpegs):
@@ -417,7 +415,7 @@ def phase_serve(watch, n_requests=192, batch=128, image_size=224,
     check(err <= SERVE_TOL,
           f"served vs direct predict error {err:.4f} > {SERVE_TOL}")
     return {"model": f"ImageClassifier/{backbone}", "dtype": "bfloat16",
-            "engine": "pipelined", "requests": n_requests,
+            "requests": n_requests,
             "answered_once": n_requests, "error_replies": 0,
             "max_batch": batch, "warm_up_s": round(warm_s, 2),
             "served_s": round(served_s, 2),

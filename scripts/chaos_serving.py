@@ -13,7 +13,7 @@ explicitly destroy (dropped replies) is answered exactly once -- as a
 result or a structured error -- without operator action, across
 however many supervisor restarts the schedule forces.
 
-Prints one JSON line (the perf_serving_pipeline.py convention):
+Prints one JSON line:
   {"requests", "answered", "ok", "errors", "deadline_exceeded",
    "duplicates", "unanswered", "restarts", "injected", "elapsed_s",
    "rps", "seed", "spec", "recovered"}
@@ -131,7 +131,7 @@ def main():
         chaos.parse_spec(args.spec), seed=args.seed))
     worker = ServingWorker(model, in_q, out_q,
                            batch_size=args.batch_size, timeout_ms=2.0,
-                           max_batch_size=32, pipelined=True)
+                           max_batch_size=32)
     sup = Supervisor(worker, poll_interval_s=0.05,
                      heartbeat_timeout_s=2.0, backoff_base_s=0.02,
                      backoff_max_s=0.5, seed=args.seed)

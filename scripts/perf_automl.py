@@ -20,7 +20,7 @@ failure):
   (fixed arch + lr-only variation must not split);
 - **no fallback**: no trial escaped to the sequential rescue path.
 
-Prints ONE JSON line (the perf_serving_pipeline.py convention).
+Prints ONE JSON line.
 CPU-rig caveats in BENCH_NOTES.md: absolute trials/sec is hardware-
 dependent; the parity gates and the vectorized-vs-pool ratio are the
 committed signal (AUTOML_r01.json).

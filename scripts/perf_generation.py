@@ -21,7 +21,7 @@ name:
   an optional cache-free re-prefill-per-token baseline
   (``--with-reprefill``).
 
-Prints ONE JSON line (the perf_serving_pipeline.py convention) and
+Prints ONE JSON line and
 exits nonzero when any correctness gate fails. CPU host-device rig:
 absolute numbers are hardware-dependent; the correctness gates and the
 continuous-vs-naive ratio are the committed signal (GEN_r01.json,

@@ -225,7 +225,7 @@ class TestRedisStreamQueue:
         for i in range(8):
             assert prod.put(_encode(f"r{i}", {"x": np.ones(2)}))
         w = ServingWorker(Model(), in_q, out_q, batch_size=4,
-                          timeout_ms=2.0, pipelined=True)
+                          timeout_ms=2.0)
         w.start()
         deadline = time.time() + 20
         while len(broker._results) < 8 and time.time() < deadline:
@@ -475,8 +475,7 @@ class TestDrain:
         for i in range(n):
             assert in_q.enqueue(f"d{i}", x=np.ones(2, np.float32))
         w = ServingWorker(_SlowModel(delay_s), in_q, out_q,
-                          batch_size=batch_size, timeout_ms=1.0,
-                          pipelined=True)
+                          batch_size=batch_size, timeout_ms=1.0)
         return w, in_q, out_q
 
     def test_drain_completes_within_deadline(self):

@@ -314,24 +314,16 @@ class TestPostmortem:
     def test_worker_crash_writes_bundle(self, tmp_path):
         """Acceptance: kill a serving worker with an injected exception
         -> a postmortem bundle appears containing last-N events, a
-        registry snapshot, and the in-flight request ids."""
+        registry snapshot, and the in-flight request ids.
+
+        The engine answers every dispatched batch before its driver
+        thread unwinds, so the thread whose death loses requests is
+        the finalize stage: a fatal fault there leaves req-crash
+        dispatched and never answered."""
+        from analytics_zoo_tpu.serving import chaos
         from analytics_zoo_tpu.serving.queues import (
-            OutputQueue, _encode)
+            InputQueue, OutputQueue)
         from analytics_zoo_tpu.serving.worker import ServingWorker
-
-        class PoisonQueue:
-            """Yields one good request, then fails like a dead broker."""
-
-            def __init__(self, blobs):
-                self._blobs = list(blobs)
-
-            def get(self, timeout=None):
-                if self._blobs:
-                    return self._blobs.pop(0)
-                raise RuntimeError("injected broker failure")
-
-            def __len__(self):
-                return len(self._blobs)
 
         class SlowModel:
             def predict(self, x):
@@ -339,15 +331,15 @@ class TestPostmortem:
 
         rec = FlightRecorder(out_dir=str(tmp_path), max_events=64)
         rec.install()
+        chaos.install(chaos.ChaosInjector(
+            chaos.parse_spec("crash:finalize:at=1")))
+        worker = None
         try:
-            q = PoisonQueue(
-                [_encode("req-crash", {"x": np.ones(3, np.float32)})])
-            # sync engine, batch_size=1 (one get per cycle),
-            # pipeline_depth=4: req-crash stays dispatched-but-
-            # unfinalized when cycle 2's pull hits the poison
+            q = InputQueue()
+            assert q.enqueue("req-crash", x=np.ones(3, np.float32))
             worker = ServingWorker(
                 SlowModel(), q, OutputQueue(), batch_size=1,
-                timeout_ms=1.0, pipelined=False, pipeline_depth=4)
+                timeout_ms=1.0, pipeline_depth=4)
             worker.start()
             deadline = time.monotonic() + 10
             bundle = None
@@ -366,14 +358,17 @@ class TestPostmortem:
             with open(os.path.join(bundle, "manifest.json")) as f:
                 manifest = json.load(f)
             assert manifest["reason"] == "thread_exception"
-            assert manifest["exception"]["type"] == "RuntimeError"
-            assert "injected broker failure" in \
+            assert manifest["exception"]["type"] == "ChaosCrash"
+            assert "injected crash at finalize" in \
                 manifest["exception"]["message"]
             with open(os.path.join(bundle, "events.jsonl")) as f:
-                types = [json.loads(ln)["type"] for ln in f
-                         if ln.strip()]
+                events = [json.loads(ln) for ln in f if ln.strip()]
+            types = [e["type"] for e in events]
             assert "worker_start" in types
-            assert "worker_crash" in types
+            crashes = [e for e in events
+                       if e["type"] == "uncaught_exception"]
+            assert crashes and crashes[-1]["fields"]["thread"] \
+                == "serving-finalize"
             with open(os.path.join(bundle, "metrics.json")) as f:
                 snap = json.load(f)
             assert "zoo_serving_requests_total" in snap
@@ -381,6 +376,9 @@ class TestPostmortem:
                 inflight = json.load(f)
             assert "req-crash" in inflight["request_ids"]
         finally:
+            if worker is not None:
+                worker.stop()
+            chaos.uninstall()
             rec.uninstall()
             get_inflight().clear()
 
@@ -399,7 +397,7 @@ class TestPostmortem:
         for i in range(6):
             in_q.enqueue(f"ok-{i}", x=np.ones(2, np.float32))
         worker = ServingWorker(Echo(), in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, pipelined=True)
+                               timeout_ms=2.0)
         worker.run(max_batches=3, wait_timeout=0.1)
         assert not any(u.startswith("ok-")
                        for u in get_inflight().snapshot())

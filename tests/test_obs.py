@@ -385,7 +385,7 @@ class TestEndToEndTracing:
             assert tid is not None
             assert in_q.enqueue("r1", x=np.ones(3, np.float32))
         worker = ServingWorker(_EchoModel(), in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, pipelined=True)
+                               timeout_ms=2.0)
         worker.run(max_batches=1, wait_timeout=0.2)
         uri, tensors = out_q.dequeue(timeout=2)
         assert uri == "r1"
@@ -411,12 +411,14 @@ class TestEndToEndTracing:
         in_q, out_q = InputQueue(), OutputQueue()
         in_q.enqueue("r1", x=np.ones(3, np.float32))
         worker = ServingWorker(_EchoModel(), in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, pipelined=True)
+                               timeout_ms=2.0)
         worker.run(max_batches=1, wait_timeout=0.2)
         assert out_q.dequeue(timeout=2) is not None
         assert tracing.get_tracer().spans() == []
 
     def test_sync_engine_also_traces(self, tracing_on):
+        """A depth-1 window (one batch in flight, no dispatch overlap)
+        still records every stage span of a traced request."""
         from analytics_zoo_tpu.serving.queues import (
             InputQueue, OutputQueue)
         from analytics_zoo_tpu.serving.worker import ServingWorker
@@ -425,8 +427,7 @@ class TestEndToEndTracing:
         with tracing.maybe_trace("client_request") as tid:
             in_q.enqueue("r1", x=np.ones(3, np.float32))
         worker = ServingWorker(_EchoModel(), in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, pipelined=False,
-                               pipeline_depth=1)
+                               timeout_ms=2.0, pipeline_depth=1)
         worker.run(max_batches=2, wait_timeout=0.2)
         assert out_q.dequeue(timeout=2) is not None
         names = {s["name"] for s in tracing.get_tracer().spans(tid)}
@@ -521,7 +522,7 @@ class TestHttpObservability:
         for i in range(12):
             in_q.enqueue(f"r{i}", x=np.ones(3, np.float32))
         worker = ServingWorker(_EchoModel(), in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, pipelined=True)
+                               timeout_ms=2.0)
         worker.run(max_batches=6, wait_timeout=0.1)
         reg = get_registry()
         assert reg.get("zoo_serving_inflight_batches_items").value == 0

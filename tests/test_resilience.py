@@ -170,7 +170,7 @@ class TestChaosHarness:
         chaos.install(ChaosInjector(parse_spec("drop:push:p=1.0")))
         in_q, out_q = _fill(6)
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=2,
-                               timeout_ms=1.0, pipelined=True)
+                               timeout_ms=1.0)
         served = worker.run(max_batches=6, wait_timeout=0.02)
         assert served == 6              # the engine accounted for all
         assert out_q.dequeue_all() == []  # ...but every reply was shed
@@ -239,15 +239,16 @@ class TestCircuitBreaker:
         model = _FlakyModel()
         br = CircuitBreaker(threshold=2, cooldown_s=0.15)
         in_q, out_q = _fill(4)
+        # max_batch_size=2: one pull is one batch of two, never grown
         worker = ServingWorker(model, in_q, out_q, batch_size=2,
-                               timeout_ms=1.0, pipelined=False,
+                               timeout_ms=1.0, max_batch_size=2,
                                breaker=br)
-        worker.process_one_batch(wait_timeout=0.02)  # fail #1
-        worker.process_one_batch(wait_timeout=0.02)  # fail #2 -> open
+        worker.run(max_batches=1, wait_timeout=0.02)  # fail #1
+        worker.run(max_batches=1, wait_timeout=0.02)  # fail #2 -> open
         assert br.state == "open"
         calls = model.calls
         _fill(2, in_q=in_q)
-        worker.process_one_batch(wait_timeout=0.02)
+        worker.run(max_batches=1, wait_timeout=0.02)
         assert model.calls == calls, "open breaker still dispatched"
         results = dict(out_q.dequeue_all())
         fast_failed = [v for v in results.values()
@@ -256,9 +257,7 @@ class TestCircuitBreaker:
         model.failing = False
         time.sleep(0.2)  # past cooldown: next dispatch is the probe
         _fill(2, in_q=in_q)
-        worker.process_one_batch(wait_timeout=0.02)
-        while worker._inflight:
-            worker._finalize_one()
+        worker.run(max_batches=1, wait_timeout=0.02)
         assert br.state == "closed"
         assert worker.metrics()["breaker"]["state"] == "closed"
 
@@ -294,7 +293,7 @@ class TestSupervisor:
     def _supervised(self, model, in_q, out_q, **worker_kw):
         worker = ServingWorker(model, in_q, out_q, batch_size=4,
                                timeout_ms=1.0, max_batch_size=4,
-                               pipelined=True, **worker_kw)
+                               **worker_kw)
         sup = Supervisor(worker, poll_interval_s=0.03,
                          heartbeat_timeout_s=30.0,
                          backoff_base_s=0.01, backoff_max_s=0.05,
@@ -361,8 +360,7 @@ class TestSupervisor:
         seq0 = get_event_log().tail()[-1]["seq"]
         in_q, out_q = _fill(8)
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=4,
-                               timeout_ms=1.0, max_batch_size=4,
-                               pipelined=True)
+                               timeout_ms=1.0, max_batch_size=4)
         sup = Supervisor(worker, poll_interval_s=0.03,
                          heartbeat_timeout_s=0.25,
                          backoff_base_s=0.01, backoff_max_s=0.05,
@@ -399,7 +397,7 @@ class TestSupervisor:
         seq0 = get_event_log().tail()[-1]["seq"]
         in_q, out_q = _fill(2)
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=2,
-                               timeout_ms=1.0, pipelined=True)
+                               timeout_ms=1.0)
         sup = Supervisor(worker, poll_interval_s=0.02,
                          heartbeat_timeout_s=30.0,
                          backoff_base_s=0.005, backoff_max_s=0.01,
@@ -426,8 +424,7 @@ class TestSupervisor:
             parse_spec("crash:dispatch:every=1")))
         in_q, out_q = _fill(4)
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=4,
-                               timeout_ms=1.0, max_batch_size=4,
-                               pipelined=True)
+                               timeout_ms=1.0, max_batch_size=4)
         sup = Supervisor(worker, poll_interval_s=0.02,
                          heartbeat_timeout_s=30.0,
                          backoff_base_s=0.005, backoff_max_s=0.01,
@@ -454,8 +451,7 @@ class TestSupervisor:
         seq0 = get_event_log().tail()[-1]["seq"]
         in_q, out_q = _fill(4)
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=4,
-                               timeout_ms=1.0, max_batch_size=4,
-                               pipelined=True)
+                               timeout_ms=1.0, max_batch_size=4)
         sup = Supervisor(worker, poll_interval_s=0.03,
                          heartbeat_timeout_s=0.25,
                          backoff_base_s=0.01, backoff_max_s=0.05,
@@ -499,7 +495,7 @@ class TestDeadlines:
         in_q.queue.put(blob)
         time.sleep(0.08)  # everything is now past its 30ms budget
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=4,
-                               timeout_ms=1.0, pipelined=True)
+                               timeout_ms=1.0)
         worker.run(max_batches=4, wait_timeout=0.02)
         results = dict(out_q.dequeue_all())
         assert len(results) == 4
@@ -512,7 +508,7 @@ class TestDeadlines:
         _fill(4, in_q=in_q)
         out_q = OutputQueue()
         worker = ServingWorker(_AsyncEcho(), in_q, out_q, batch_size=4,
-                               timeout_ms=1.0, pipelined=True)
+                               timeout_ms=1.0)
         worker.run(max_batches=4, wait_timeout=0.02)
         results = dict(out_q.dequeue_all())
         assert len(results) == 4
@@ -631,28 +627,23 @@ class TestDisabledEquivalence:
         assert worker.breaker is None and worker.ledger is None
 
     def test_pipelined_and_sync_identical_with_resilience_off(self):
-        """The PR-1 equivalence contract survives this PR: same
-        stream, both engines, identical replies, all knobs default."""
+        """All knobs default: every request of the stream is answered
+        once with exactly the model's own answer for it."""
         rng = np.random.RandomState(11)
         stream = [(f"q{i:03d}", rng.randn(2).astype(np.float32))
                   for i in range(12)]
-
-        def run(pipelined):
-            in_q, out_q = InputQueue(), OutputQueue()
-            for uri, x in stream:
-                assert in_q.enqueue(uri, x=x)
-            worker = ServingWorker(_AsyncEcho(), in_q, out_q,
-                                   batch_size=4, timeout_ms=2.0,
-                                   pipelined=pipelined)
-            assert worker.run(max_batches=20, wait_timeout=0.02) \
-                == len(stream)
-            return dict(out_q.dequeue_all())
-
-        sync_out, pipe_out = run(False), run(True)
-        assert sorted(sync_out) == sorted(pipe_out)
-        for uri in sync_out:
-            np.testing.assert_array_equal(sync_out[uri]["output"],
-                                          pipe_out[uri]["output"])
+        in_q, out_q = InputQueue(), OutputQueue()
+        for uri, x in stream:
+            assert in_q.enqueue(uri, x=x)
+        worker = ServingWorker(_AsyncEcho(), in_q, out_q,
+                               batch_size=4, timeout_ms=2.0)
+        assert worker.run(max_batches=20, wait_timeout=0.02) \
+            == len(stream)
+        out = dict(out_q.dequeue_all())
+        assert sorted(out) == [uri for uri, _ in stream]
+        for uri, x in stream:
+            np.testing.assert_array_equal(out[uri]["output"],
+                                          np.asarray(x, np.float64) * 2.0)
 
 
 # ------------------------------------------------- manager (satellite) --

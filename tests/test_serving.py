@@ -17,9 +17,9 @@ import pytest
 
 import analytics_zoo_tpu.serving as serving
 from analytics_zoo_tpu.inference.inference_model import InferenceModel
-from analytics_zoo_tpu.serving import (DirQueue, HttpFrontend, InputQueue,
-                                       MemQueue, MicroBatcher, OutputQueue,
-                                       ServingWorker)
+from analytics_zoo_tpu.serving import (AdaptiveBatcher, DirQueue,
+                                       HttpFrontend, InputQueue, MemQueue,
+                                       OutputQueue, ServingWorker)
 
 
 class _TinyNet(nn.Module):
@@ -42,7 +42,8 @@ def tiny_model():
 def test_serving_package_imports():
     # round-1 regression: serving/__init__ referenced missing modules
     for name in ("InputQueue", "OutputQueue", "DirQueue", "MemQueue",
-                 "MicroBatcher", "ServingWorker", "HttpFrontend", "Timer"):
+                 "AdaptiveBatcher", "ServingWorker", "HttpFrontend",
+                 "Timer"):
         assert hasattr(serving, name)
 
 
@@ -92,13 +93,19 @@ def test_dir_queue_concurrent_consumers(tmp_path):
 
 
 def test_micro_batcher_groups_and_timeout():
+    """The fixed policy: no growth, linger floor == linger -- close at
+    batch_size or timeout_ms after the first item."""
     q = MemQueue()
     for i in range(5):
         q.put(bytes([i]))
-    b = MicroBatcher(q, batch_size=3, timeout_ms=50)
+    b = AdaptiveBatcher(q, batch_size=3, timeout_ms=50, min_timeout_ms=50,
+                        max_batch_size=3)
     assert len(b.next_batch()) == 3
     assert len(b.next_batch()) == 2
     assert b.next_batch(wait_timeout=0.01) == []
+    stats = b.stats()
+    assert (stats["close_size"], stats["close_deadline"]) == (1, 1)
+    assert stats["last_cap"] == 3 and stats["last_linger_ms"] == 50
 
 
 def test_worker_end_to_end_dirqueue(tmp_path, tiny_model):
@@ -373,8 +380,7 @@ class TestCompressedImageIngestion:
         worker = ServingWorker(MeanModel(), in_q, out_q, batch_size=4)
         raw = self._jpeg_bytes(seed=3)
         assert in_q.enqueue_image("req-1", raw)
-        worker.process_one_batch(wait_timeout=0.5)
-        worker.process_one_batch(wait_timeout=0.1)  # drain pipeline
+        assert worker.run(max_batches=1, wait_timeout=0.5) == 1
         uri, result = out_q.dequeue(timeout=2.0)
         assert uri == "req-1"
         from PIL import Image
@@ -416,8 +422,7 @@ class TestCompressedImageIngestion:
         good = self._jpeg_bytes(seed=9)
         assert in_q.enqueue("bad-1", image=corrupt)
         assert in_q.enqueue_image("good-1", good)
-        worker.process_one_batch(wait_timeout=0.5)
-        worker.process_one_batch(wait_timeout=0.1)
+        assert worker.run(max_batches=1, wait_timeout=0.5) == 2
         results = {}
         for _ in range(2):
             item = out_q.dequeue(timeout=2.0)

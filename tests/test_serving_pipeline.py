@@ -3,8 +3,8 @@
 Covers the ISSUE-1 tentpole contract: stage overlap (decode of batch
 k+1 while batch k is in flight), the adaptive batcher's three policy
 behaviors (size close, tightened-deadline close, backlog cap growth on
-the bucket ladder), no result loss/reordering at the in-flight cap, and
-bit-identical outputs between the pipelined and synchronous paths.
+the bucket ladder), and no result loss/reordering at the in-flight
+cap.
 """
 
 import threading
@@ -13,7 +13,6 @@ import time
 import numpy as np
 import pytest
 
-from analytics_zoo_tpu.common.config import get_config
 from analytics_zoo_tpu.serving.batcher import AdaptiveBatcher
 from analytics_zoo_tpu.serving.queues import InputQueue, MemQueue, OutputQueue
 from analytics_zoo_tpu.serving.worker import ServingWorker
@@ -104,15 +103,17 @@ class TestWireCodec:
         assert t["x"].shape == (4, 3)
 
     def test_legacy_npz_blobs_still_decode(self):
+        """AZT1 is the only framing: an np.savez (zip) blob is refused
+        like any other outside input that is not AZT1."""
         import io
 
         from analytics_zoo_tpu.serving.queues import _decode_full
 
         buf = io.BytesIO()
         np.savez(buf, __uri__=np.asarray("old"), x=np.arange(3))
-        u, t, r = _decode_full(buf.getvalue())
-        assert u == "old" and r is None
-        np.testing.assert_array_equal(t["x"], [0, 1, 2])
+        assert buf.getvalue()[:2] == b"PK"
+        with pytest.raises(ValueError, match="AZT1"):
+            _decode_full(buf.getvalue())
 
     def test_garbage_and_object_dtype_rejected(self):
         from analytics_zoo_tpu.serving.queues import _decode_full, _encode
@@ -126,7 +127,7 @@ class TestWireCodec:
         from analytics_zoo_tpu.serving.queues import _decode_full, _encode
 
         _, t, _ = _decode_full(_encode("w", {"x": np.arange(4.0)}))
-        t["x"][0] = 9.0  # user hooks may mutate in place (npz parity)
+        t["x"][0] = 9.0  # user hooks may mutate in place
         assert t["x"][0] == 9.0
 
 
@@ -264,7 +265,7 @@ class TestPipelinedEngine:
         in_q, out_q = _fill(2)
         worker = ServingWorker(model, in_q, out_q, batch_size=1,
                                timeout_ms=1.0, max_batch_size=1,
-                               pipeline_depth=1, pipelined=True)
+                               pipeline_depth=1)
         worker.start()
         try:
             deadline = time.time() + 10
@@ -305,7 +306,7 @@ class TestPipelinedEngine:
         in_q, out_q = _fill(n)
         worker = ServingWorker(model, in_q, out_q, batch_size=4,
                                timeout_ms=2.0, max_batch_size=16,
-                               pipeline_depth=2, pipelined=True)
+                               pipeline_depth=2)
         worker.start()
         try:
             deadline = time.time() + 30
@@ -326,67 +327,11 @@ class TestPipelinedEngine:
                                        [2.0 * i, 2.0 * i])
         assert worker.metrics()["pipeline"]["depth"] == 2
 
-    def test_pipelined_and_sync_paths_identical_outputs(self):
-        """Acceptance: the same request stream produces the same
-        responses through both engines: the same requests answered,
-        each reply equal to float32 rounding. Bit equality is not the
-        contract: the two engines batch the same requests into
-        different padded shapes, and XLA's ``Dense`` for another batch
-        shape may round the last bit differently."""
-        import flax.linen as nn
-        import jax
-
-        from analytics_zoo_tpu.inference.inference_model import (
-            InferenceModel)
-
-        class Net(nn.Module):
-            @nn.compact
-            def __call__(self, x):
-                return nn.Dense(3)(x)
-
-        module = Net()
-        variables = module.init(jax.random.PRNGKey(0),
-                                np.zeros((1, 4), np.float32))
-        model = InferenceModel().load_flax(module, variables=variables)
-        rng = np.random.RandomState(7)
-        stream = [(f"q{i:03d}", rng.randn(4).astype(np.float32))
-                  for i in range(20)]
-
-        def run(pipelined):
-            in_q, out_q = InputQueue(), OutputQueue()
-            for uri, x in stream:
-                assert in_q.enqueue(uri, x=x)
-            worker = ServingWorker(model, in_q, out_q, batch_size=4,
-                                   timeout_ms=2.0,
-                                   pipelined=pipelined)
-            served = worker.run(max_batches=30, wait_timeout=0.02)
-            assert served == len(stream)
-            return dict(out_q.dequeue_all())
-
-        sync_out = run(False)
-        pipe_out = run(True)
-        assert sorted(sync_out) == sorted(pipe_out)
-        for uri in sync_out:
-            np.testing.assert_allclose(sync_out[uri]["output"],
-                                       pipe_out[uri]["output"],
-                                       rtol=1e-6, atol=1e-6)
-
-    def test_config_escape_hatch_restores_sync_path(self):
-        cfg = get_config()
-        cfg.set("zoo.serving.pipeline.enabled", False)
-        try:
-            w = ServingWorker(_AsyncEcho(), InputQueue(), OutputQueue())
-            assert w.pipelined is False
-        finally:
-            cfg.unset("zoo.serving.pipeline.enabled")
-        w2 = ServingWorker(_AsyncEcho(), InputQueue(), OutputQueue())
-        assert w2.pipelined is True  # default: pipelined engine
-
     def test_bounded_run_answers_everything_it_pulled(self):
         model = _AsyncEcho()
         in_q, out_q = _fill(10)
         worker = ServingWorker(model, in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, pipelined=True)
+                               timeout_ms=2.0)
         served = worker.run(max_batches=12, wait_timeout=0.02)
         assert served == 10
         assert len(dict(out_q.dequeue_all())) == 10
@@ -400,7 +345,7 @@ class TestPipelinedEngine:
 
         in_q, out_q = _fill(3)
         worker = ServingWorker(Broken(), in_q, out_q, batch_size=8,
-                               timeout_ms=1.0, pipelined=True)
+                               timeout_ms=1.0)
         worker.run(max_batches=3, wait_timeout=0.02)
         results = dict(out_q.dequeue_all())
         assert len(results) == 3
@@ -411,13 +356,12 @@ class TestPipelinedEngine:
         model = _AsyncEcho()
         in_q, out_q = _fill(20)
         worker = ServingWorker(model, in_q, out_q, batch_size=4,
-                               timeout_ms=2.0, max_batch_size=16,
-                               pipelined=True)
+                               timeout_ms=2.0, max_batch_size=16)
         worker.run(max_batches=20, wait_timeout=0.02)
         m = worker.metrics()
         assert m["served"] == 20
         pipe = m["pipeline"]
-        assert pipe["enabled"] and pipe["depth"] >= 1
+        assert pipe["depth"] >= 1
         assert pipe["batcher"]["batches"] >= 1
         assert pipe["batcher"]["mean_occupancy"] > 0
         stages = m["stages"]

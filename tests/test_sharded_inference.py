@@ -227,8 +227,7 @@ class TestServingSurface:
         in_q, out_q = InputQueue(), OutputQueue()
         for i in range(n):
             assert in_q.enqueue(f"s{i}", x=xs[i])
-        worker = ServingWorker(model, in_q, out_q, batch_size=8,
-                               pipelined=True)
+        worker = ServingWorker(model, in_q, out_q, batch_size=8)
         worker.start()
         got = {}
         import time

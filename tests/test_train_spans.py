@@ -344,7 +344,7 @@ def test_no_new_config_key_or_fit_argument():
 
     from analytics_zoo_tpu.common import config
 
-    assert len(config._DEFAULTS) == 102
+    assert len(config._DEFAULTS) == 101
     assert list(inspect.signature(Estimator.fit).parameters) == [
         "self", "data", "batch_size", "epochs", "validation_data",
         "validation_trigger", "checkpoint_dir", "checkpoint_trigger",
